@@ -255,11 +255,11 @@ class InferenceService(Resource):
         for rev in ("predictor", "canary"):
             spec = self.spec.get(rev)
             if spec is not None:
-                dev = str(spec.get("device", "auto"))
-                if dev not in ("auto", "default", "cpu"):
+                dev = str(spec.get("device", "default"))
+                if dev not in ("default", "cpu"):
                     raise ValidationError(
                         f"spec.{rev}.device",
-                        f"{dev!r} not one of auto/default/cpu")
+                        f"{dev!r} not one of default/cpu")
                 sp = spec.get("speculative")
                 if sp is not None:
                     if not isinstance(sp, dict):

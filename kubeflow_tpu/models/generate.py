@@ -1,7 +1,6 @@
 """Autoregressive generation for TransformerLM: jitted KV-cache prefill
 + a lax.scan decode loop (ONE device dispatch per generate call, not one
-per token — on a tunneled/remote accelerator that is the difference
-between milliseconds and seconds per request).
+per token).
 
 The train-time params are reused verbatim; only the config flips to
 ``decode=True`` (attention keeps per-layer KV caches sized max_seq_len).

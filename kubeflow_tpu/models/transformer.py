@@ -31,10 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-# Installs the jax API-drift shims (jax.shard_map / set_mesh /
-# get_abstract_mesh) this module reaches lazily below.
-from ..parallel import mesh as _mesh_compat  # noqa: F401
-
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -474,6 +470,17 @@ def _lora_apply(mdl, cfg, name, y, inp, lora, adapter_ids):
     return y + delta.reshape(y.shape).astype(y.dtype)
 
 
+def attention_path(cfg: TransformerConfig, seq_len: int) -> str:
+    """The attention implementation a training or prefill forward over
+    ``seq_len`` tokens takes — "ring", "flash" or "dense". The one rule
+    ``Attention`` dispatches on; runners log it, so which kernel ran is
+    read off the worker's log."""
+    if cfg.cp > 1:
+        # Context-parallel: the only seq-sharded kernel.
+        return "ring"
+    return "flash" if Attention(cfg)._use_flash(seq_len) else "dense"
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -564,10 +571,11 @@ class Attention(nn.Module):
         _probe("attn_k", k)
         _probe("attn_v", v)
 
-        if cfg.decode:
+        path = "decode" if cfg.decode else attention_path(cfg, S)
+        if path == "decode":
             out = self._decode_attend(q, k, v, positions, block_tables,
                                       write_locations)
-        elif cfg.cp > 1:
+        elif path == "ring":
             # Context-parallel path: seq sharded over "ctx", heads over
             # "model" (each head attends independently, so tp composes),
             # exact causal ring attention rotating K/V between neighbours.
@@ -581,19 +589,14 @@ class Attention(nn.Module):
             out = jax.shard_map(
                 functools.partial(ring_attention, axis_name=AXIS_CTX),
                 in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
-        elif self._use_flash(S):
-            import functools
+        elif path == "flash":
+            from ..ops import flash_attention as fa
 
-            from ..ops.flash_attention import (
-                flash_attention_apply, flash_attention_fwd)
-
-            # Off-TPU (forced via attn_impl="flash", e.g. tests) the
-            # kernels run in pallas interpret mode — same code path,
-            # reference semantics.
-            interpret = jax.default_backend() != "tpu"
-            fwd = functools.partial(flash_attention_fwd, interpret=interpret)
-            apply = functools.partial(flash_attention_apply,
-                                      interpret=interpret)
+            # The kernels compile for the backend or the run fails:
+            # nothing here falls back to the Pallas interpreter (a test
+            # on CPU sets fa.INTERPRET itself; the VMA tracker rejects
+            # the interpreted kernel's dynamic slices inside shard_map,
+            # hence check_vma below).
             mesh = jax.sharding.get_abstract_mesh()
             if not mesh.empty:
                 # Under GSPMD a pallas call must be per-shard: batch rides
@@ -602,17 +605,12 @@ class Attention(nn.Module):
                 from jax.sharding import PartitionSpec as P
 
                 spec = P(AXIS_DATA, None, AXIS_MODEL, None)
-                # check_vma only on real TPU lowering: in interpret mode
-                # the kernels run as jax ops inside shard_map and the
-                # VMA tracker rejects their internal dynamic_slices
-                # (same known wart parallel/pipeline.py works around);
-                # the untracked lowering is what the grad-parity tests
-                # check.
-                o, lse = jax.shard_map(fwd, in_specs=(spec, spec, spec),
-                                       out_specs=(spec, spec),
-                                       check_vma=not interpret)(q, k, v)
+                o, lse = jax.shard_map(
+                    fa.flash_attention_fwd, in_specs=(spec, spec, spec),
+                    out_specs=(spec, spec),
+                    check_vma=not fa.INTERPRET)(q, k, v)
             else:
-                o, lse = fwd(q, k, v)
+                o, lse = fa.flash_attention_fwd(q, k, v)
             # Tagged OUTSIDE the shard_map so remat policies see the
             # names: "save_flash" keeps the kernel's O(B·S·H·D) output
             # and its log-sum-exp rows — the linear-in-S residuals that
@@ -625,10 +623,12 @@ class Attention(nn.Module):
             lse = checkpoint_name(lse, "flash_lse")
             if not mesh.empty:
                 out = jax.shard_map(
-                    apply, in_specs=(spec, spec, spec, spec, spec),
-                    out_specs=spec, check_vma=not interpret)(q, k, v, o, lse)
+                    fa.flash_attention_apply,
+                    in_specs=(spec, spec, spec, spec, spec),
+                    out_specs=spec,
+                    check_vma=not fa.INTERPRET)(q, k, v, o, lse)
             else:
-                out = apply(q, k, v, o, lse)
+                out = fa.flash_attention_apply(q, k, v, o, lse)
         else:
             # Dense causal attention (XLA fuses the softmax chain).
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)

@@ -87,7 +87,7 @@ class _Revision:
 
     def __init__(self, name: str, model_name: str, model_dir: str,
                  workdir: str, batcher: Optional[dict],
-                 device: str = "auto", role: str = "predictor",
+                 device: str = "default", role: str = "predictor",
                  graph: Optional[dict] = None,
                  container: Optional[dict] = None,
                  speculative: Optional[dict] = None,
@@ -477,6 +477,9 @@ class _Revision:
         return [f"127.0.0.1:{r.port}" for r in self.replicas if r.ready]
 
     def teardown(self) -> None:
+        """Stop every replica and REAP it: a replica holds its chip
+        until the process is gone, and whatever the plane starts next
+        needs that chip."""
         for r in self.replicas:
             if r.proc.poll() is None:
                 r.proc.terminate()
@@ -486,6 +489,7 @@ class _Revision:
                 time.sleep(0.05)
             if r.proc.poll() is None:
                 r.proc.kill()
+                r.proc.wait()
         self.replicas.clear()
 
 
@@ -672,7 +676,7 @@ class InferenceServiceController(Controller):
                     spec_storage_uri(spec),
                     os.path.join(self.home, "storage-cache"))
             batcher = spec.get("batcher")
-            device = str(spec.get("device", "auto"))
+            device = str(spec.get("device", "default"))
             speculative = spec.get("speculative")
             quantization = spec.get("quantization")
             prefill_chunk = spec.get("prefillChunkTokens")

@@ -5,5 +5,3 @@ well — and pallas where a hand-written kernel beats the fusion:
 flash attention (ops/flash_attention.py) keeps the O(S^2) score matrix
 out of HBM entirely, which matters from mid-size sequence lengths up.
 """
-
-from .flash_attention import flash_attention  # noqa: F401

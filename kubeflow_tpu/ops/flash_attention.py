@@ -22,7 +22,7 @@ D in {64, 128}):
 
 Inputs are [B, S, H, D] (the model's layout); q is expected pre-scaled
 (the model multiplies by 1/sqrt(D) already). Compute is fp32 regardless
-of input dtype. `interpret=True` runs the same kernels on CPU (tests).
+of input dtype.
 """
 
 from __future__ import annotations
@@ -37,6 +37,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# Run the kernels in the Pallas interpreter instead of compiling them.
+# A constant of the program: only tests on a backend without Mosaic set
+# it (monkeypatch) — on a TPU the kernels compile or the run fails.
+INTERPRET = False
+
 
 def _pick_block(s: int, want: int = 256) -> int:
     b = min(want, s)
@@ -48,10 +53,7 @@ def _pick_block(s: int, want: int = 256) -> int:
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying the caller's varying-mesh-axes set, so
     the kernels also work inside shard_map (check_vma)."""
-    try:
-        vma = jax.typeof(like).vma
-    except AttributeError:  # older jax
-        vma = ()
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -96,7 +98,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
     lse_ref[0, 0] = (m + jnp.log(den))[:, None]
 
 
-def _fwd(q, k, v, *, block_q: int, block_k: int, interpret: bool
+def _fwd(q, k, v, *, block_q: int, block_k: int
          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     B, H, S, D = q.shape
     grid = (B, H, S // block_q)
@@ -115,7 +117,7 @@ def _fwd(q, k, v, *, block_q: int, block_k: int, interpret: bool
             _sds((B, H, S, D), q.dtype, q),
             _sds((B, H, S, 1), jnp.float32, q),
         ],
-        interpret=interpret,
+        interpret=INTERPRET,
     )(q, k, v)
     return o, lse
 
@@ -194,7 +196,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
 
-def _bwd(block_q, block_k, interpret, res, do):
+def _bwd(block_q, block_k, res, do):
     q, k, v, o, lse = res
     B, H, S, D = q.shape
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -213,7 +215,7 @@ def _bwd(block_q, block_k, interpret, res, do):
         in_specs=[qb, full, full, qb, qv, qv],
         out_specs=qb,
         out_shape=_sds((B, H, S, D), q.dtype, q),
-        interpret=interpret,
+        interpret=INTERPRET,
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -224,7 +226,7 @@ def _bwd(block_q, block_k, interpret, res, do):
         out_specs=[kb, kb],
         out_shape=[_sds((B, H, S, D), k.dtype, q),
                    _sds((B, H, S, D), v.dtype, q)],
-        interpret=interpret,
+        interpret=INTERPRET,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -250,17 +252,17 @@ def _bwd(block_q, block_k, interpret, res, do):
 # the recompute re-runs the forward kernel to rebuild (o, lse).
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash_apply(q, k, v, o, lse, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_apply(q, k, v, o, lse, block_q, block_k):
     return o
 
 
-def _flash_apply_fwd(q, k, v, o, lse, block_q, block_k, interpret):
+def _flash_apply_fwd(q, k, v, o, lse, block_q, block_k):
     return o, (q, k, v, o, lse)
 
 
-def _flash_apply_bwd(block_q, block_k, interpret, res, do):
-    dq, dk, dv = _bwd(block_q, block_k, interpret, res, do)
+def _flash_apply_bwd(block_q, block_k, res, do):
+    dq, dk, dv = _bwd(block_q, block_k, res, do)
     _, _, _, o, lse = res
     # The (o, lse) inputs are precomputed constants of the differentiated
     # path (stop_gradient'd at the producer); their cotangents are dead.
@@ -271,8 +273,7 @@ _flash_apply.defvjp(_flash_apply_fwd, _flash_apply_bwd)
 
 
 def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                        block_q: int = 256, block_k: int = 256,
-                        interpret: bool = False
+                        block_q: int = 256, block_k: int = 256
                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Raw forward kernel: [B, S, H, D] -> (o [B, S, H, D],
     lse [B, S, H, 1] fp32). No gradient flows through this call — pair it
@@ -282,14 +283,14 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     bk = _pick_block(S, block_k)
     q, k, v = (jax.lax.stop_gradient(x).transpose(0, 2, 1, 3)
                for x in (q, k, v))                  # [B,H,S,D]
-    o, lse = _fwd(q, k, v, block_q=bq, block_k=bk, interpret=interpret)
+    o, lse = _fwd(q, k, v, block_q=bq, block_k=bk)
     return o.transpose(0, 2, 1, 3), lse.transpose(0, 2, 1, 3)
 
 
 def flash_attention_apply(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                           o: jnp.ndarray, lse: jnp.ndarray, *,
-                          block_q: int = 256, block_k: int = 256,
-                          interpret: bool = False) -> jnp.ndarray:
+                          block_q: int = 256, block_k: int = 256
+                          ) -> jnp.ndarray:
     """Attention output given the precomputed (o, lse) of
     flash_attention_fwd. Numerically returns ``o``; gradients to q/k/v
     run the flash backward kernels against the given residuals."""
@@ -297,20 +298,17 @@ def flash_attention_apply(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     bq = _pick_block(S, block_q)
     bk = _pick_block(S, block_k)
     qt, kt, vt, ot = (x.transpose(0, 2, 1, 3) for x in (q, k, v, o))
-    out = _flash_apply(qt, kt, vt, ot, lse.transpose(0, 2, 1, 3),
-                       bq, bk, interpret)
+    out = _flash_apply(qt, kt, vt, ot, lse.transpose(0, 2, 1, 3), bq, bk)
     return out.transpose(0, 2, 1, 3)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                    block_q: int = 256, block_k: int = 256,
-                    interpret: bool = False) -> jnp.ndarray:
+                    block_q: int = 256, block_k: int = 256) -> jnp.ndarray:
     """Causal attention, [B, S, H, D] in/out. q must be pre-scaled by
     1/sqrt(D) (matching models/transformer.py's convention)."""
-    o, lse = flash_attention_fwd(q, k, v, block_q=block_q, block_k=block_k,
-                                 interpret=interpret)
+    o, lse = flash_attention_fwd(q, k, v, block_q=block_q, block_k=block_k)
     return flash_attention_apply(q, k, v, o, lse, block_q=block_q,
-                                 block_k=block_k, interpret=interpret)
+                                 block_k=block_k)
 
 
 def supported(seq_len: int, head_dim: int) -> bool:

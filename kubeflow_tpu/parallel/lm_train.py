@@ -123,7 +123,7 @@ class LMTrainLoop:
         # same numbers scrape-style. MFU uses the utils.flops
         # convention (model FLOPs, remat recompute not credited)
         # against the attached chip's published peak, over every chip
-        # in this loop's mesh.
+        # in this loop's mesh; a device without one gets no MFU.
         obs = default_registry()
         self._obs_step = obs.histogram(
             "kfx_train_step_seconds",
@@ -154,14 +154,20 @@ class LMTrainLoop:
         self._obs_step.observe(seconds / n_steps, n=n_steps,
                                **self._obs_labels)
         from ..utils.flops import (
-            mfu, transformer_train_flops_per_token)
+            PEAK_FLOPS, mfu, transformer_train_flops_per_token)
 
+        # MFU is a device metric: only where the mesh's chips have a
+        # published peak (never on the CPU backend).
+        peak = PEAK_FLOPS.get(self.mesh.devices.flat[0].device_kind)
+        if peak is None:
+            return
         if self._flops_per_token is None:
             self._flops_per_token = transformer_train_flops_per_token(
                 self.cfg, seq_len)
         self._obs_mfu.set(
             round(mfu(n_tokens / seconds, self._flops_per_token,
-                      n_chips=self.mesh.size), 6), **self._obs_labels)
+                      n_chips=self.mesh.size, peak=peak), 6),
+            **self._obs_labels)
 
     # -- state --------------------------------------------------------------
     def _init_fn(self, rng):
@@ -312,10 +318,9 @@ class LMTrainLoop:
                    ) -> Tuple[LMTrainState, float, float]:
         """Run a sequence of token batches with ONE host sync at the end.
 
-        train_step() syncs (device_get) per step, which on a remote /
-        tunneled device stalls the pipeline for a full round trip each
-        step; here all steps are dispatched back-to-back and only the
-        final loss is fetched."""
+        train_step() syncs (device_get) per step, which drains the
+        dispatch queue each step; here all steps are dispatched
+        back-to-back and only the final loss is fetched."""
         compiled_this_call = self._train_step is None
         if compiled_this_call:
             self._train_step = self._build_train_step()

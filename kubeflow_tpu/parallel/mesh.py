@@ -16,94 +16,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-# ---------------------------------------------------------------------------
-# jax API-drift compat: the model/parallel code is written against the
-# current mesh API (jax.set_mesh / jax.shard_map / jax.sharding.
-# get_abstract_mesh / jax.lax.pcast). On older jax the same machinery
-# exists under different names — a thread-local mesh context entered via
-# ``with mesh:`` and jax.experimental.shard_map — so thin shims keep one
-# call surface. Installed once at import; modules that reach these
-# attributes lazily (models/transformer.py) import this module first.
-# ---------------------------------------------------------------------------
-
-# True when this jax ships the current mesh API natively; False means
-# the shims below are in force (tests gate a few strict numeric-parity
-# assertions on this — the shimmed GSPMD path reduces in a slightly
-# different order).
-JAX_NATIVE_MESH_API = hasattr(jax, "set_mesh") and hasattr(jax, "shard_map")
-
-
-def _thread_local_mesh() -> Mesh:
-    from jax._src import mesh as _mesh_lib
-
-    return _mesh_lib.thread_resources.env.physical_mesh
-
-
-def _compat_shard_map(f, *, mesh=None, in_specs, out_specs, **kw):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if mesh is None:
-        mesh = _thread_local_mesh()
-        if mesh.empty:
-            raise ValueError(
-                "jax.shard_map (compat): no mesh passed and no mesh "
-                "context active — wrap the call in jax.set_mesh(mesh)")
-    # The new VMA tracker flag maps onto the old replication check; old
-    # jax has no pcast/varying machinery, so tracking stays off (the
-    # shimmed jax.lax.pcast is an identity for the same reason).
-    kw.pop("check_vma", None)
-    kw.pop("check_rep", None)
-    # New API: axis_names = the axes to go manual over; old API spells
-    # the same thing as the complement, auto=<the rest of the mesh>.
-    axis_names = kw.pop("axis_names", None)
-    if axis_names:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False, **kw)
-
-
-def _install_jax_compat() -> None:
-    if not hasattr(jax, "shard_map"):
-        jax.shard_map = _compat_shard_map
-    if not hasattr(jax, "set_mesh"):
-        # ``with jax.set_mesh(m):`` — the Mesh itself is the context
-        # manager that installs the thread-local mesh on old jax.
-        jax.set_mesh = lambda mesh: mesh
-    if not hasattr(jax.sharding, "get_abstract_mesh"):
-        # The thread-local physical mesh carries the same surface the
-        # call sites use (.empty, .shape mapping).
-        jax.sharding.get_abstract_mesh = _thread_local_mesh
-    if not hasattr(jax.lax, "pcast"):
-        jax.lax.pcast = lambda x, *a, **kw: x
-    if not hasattr(jax.lax, "axis_size"):
-        # Old jax: core.axis_frame(name) IS the static size of a bound
-        # manual axis.
-        from jax._src import core as _core
-
-        jax.lax.axis_size = lambda name: _core.axis_frame(name)
-
-
-def _install_partitionable_prng() -> None:
-    """Sharding-invariant PRNG (jax_threefry_partitionable).
-
-    Older jax defaults this OFF, which makes random draws inside jit
-    depend on the output sharding: initialising the SAME model with the
-    SAME seed on meshes with different dp produced different
-    fsdp-sharded params (measured 0.4 max-abs divergence on the tiny
-    config) — which is what actually broke the cross-plan parity tests
-    blamed on "GSPMD reduction order", and would equally break a
-    checkpoint-free plan-resharding comparison. Newer jax already
-    defaults True; forcing it makes init plan-invariant everywhere."""
-    try:
-        if not jax.config.jax_threefry_partitionable:
-            jax.config.update("jax_threefry_partitionable", True)
-    except AttributeError:  # a jax without the flag: nothing to do
-        pass
-
-
-_install_jax_compat()
-_install_partitionable_prng()
-
 AXIS_STAGE = "stage"   # pipeline (pp)
 AXIS_DATA = "data"     # batch (dp) + fsdp param shards + experts (ep)
 AXIS_CTX = "ctx"       # context parallelism (cp): sequence via ring attention

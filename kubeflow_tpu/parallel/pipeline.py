@@ -151,14 +151,10 @@ class PipelinedLMTrainLoop(LMTrainLoop):
         p = params
         in_specs = (P(), P(AXIS_STAGE), P(), P(), P())
         # Hybrid-manual (manual over "stage", auto over data/model) is
-        # what makes dp/tp/fsdp inside a stage keep riding GSPMD — but
-        # older XLA cannot lower it (PartitionId / mixed manual-subgroup
-        # fatals). When every non-stage axis is trivial there is nothing
-        # for the auto half to do, so go manual over the WHOLE mesh:
-        # identical numerics, and the classic full-manual lowering every
-        # jax supports. This is what lets the pipeline-parity tests (and
-        # a pipeline-only JAXJob) run on the compat-shimmed jax instead
-        # of skipping.
+        # what makes dp/tp/fsdp inside a stage keep riding GSPMD. When
+        # every non-stage axis is trivial there is nothing for the auto
+        # half to do, so go manual over the WHOLE mesh: identical
+        # numerics, and the classic full-manual lowering.
         plan = self.plan
         axis_names = ({AXIS_STAGE} if plan.dp > 1 or plan.tp > 1
                       else set(self.mesh.axis_names))

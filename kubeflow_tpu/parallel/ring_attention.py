@@ -78,10 +78,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # VMA check (the loop makes them varying): over every manual axis the
     # inputs vary over (e.g. data/ctx/model when called from the model's
     # sharded attention), not just the ring axis.
-    try:
-        vma = tuple(jax.typeof(q).vma) or (axis_name,)
-    except AttributeError:  # older jax: ring axis only
-        vma = (axis_name,)
+    vma = tuple(jax.typeof(q).vma) or (axis_name,)
     vary = lambda x: jax.lax.pcast(x, vma, to="varying")
     num0 = vary(jnp.zeros((B, S, H, D), jnp.float32))
     m0 = vary(jnp.full((B, H, S), NEG_INF, jnp.float32))

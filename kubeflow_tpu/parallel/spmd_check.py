@@ -97,29 +97,10 @@ def _build_loop(variant: str, n_devices: int):
         # devices, stage 0 IS process 0 and stage 1 IS process 1 — the
         # GPipe activation ppermutes cross the process boundary every
         # tick. n_layers=2 / pp=2 -> one layer per stage.
-        from .mesh import JAX_NATIVE_MESH_API
         from .pipeline import PipelinedLMTrainLoop
 
-        if JAX_NATIVE_MESH_API:
-            tp = 2 if n_devices % 4 == 0 else 1
-            mesh, plan = make_mesh(n_devices, pp=2, tp=tp, fsdp=True)
-        else:
-            # Hybrid manual/auto (dp/tp inside a stage) does not lower
-            # on compat-shimmed jax: go stage-only full-manual on a
-            # 2-device mesh, ONE DEVICE PER PROCESS where the run
-            # spans processes — the stage-boundary ppermutes (the
-            # transfer this variant exists to exercise) still cross
-            # the process boundary.
-            import jax
-
-            per_proc = {}
-            for d in jax.devices():
-                per_proc.setdefault(d.process_index, d)
-            if len(per_proc) >= 2:
-                devs = [per_proc[k] for k in sorted(per_proc)][:2]
-            else:
-                devs = jax.devices()[:2]
-            mesh, plan = make_mesh(2, pp=2, devices=devs)
+        tp = 2 if n_devices % 4 == 0 else 1
+        mesh, plan = make_mesh(n_devices, pp=2, tp=tp, fsdp=True)
         return PipelinedLMTrainLoop(TransformerConfig(**kw), mesh, plan, hp)
     else:
         raise ValueError(f"unknown variant {variant!r}; have {VARIANTS}")

@@ -101,29 +101,52 @@ def initialize_distributed() -> int:
     return pid
 
 
-def enable_compile_cache() -> None:
-    """Persistent XLA compilation cache: repeat jobs (HPO trials, restarts,
-    benches) skip the 10-40s compile entirely.
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-    Accelerator backends only. On XLA:CPU a cache HIT of the
-    donated-buffer train step corrupts the heap (malloc_consolidate
-    aborts / segfaults — reproducibly: fresh compile runs fine, the
-    next process deserializing that entry dies), which turned every
-    checkpoint-resume into a crash loop under the chaos soak. CPU
-    compiles are ~1s here, so the cache bought nothing where it was
-    unsafe."""
+
+def enable_compile_cache() -> None:
+    """Persistent XLA compilation cache for every process that compiles
+    (workers and serving replicas call this before they import jax,
+    which reads the variable at import): repeat jobs, restarts and
+    replica starts skip the compile.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX uses it by itself and
+    nothing is set here. Otherwise the cache lives at one fixed,
+    git-ignored directory inside the checkout — the path is part of the
+    cache key, so it is neither under ``$HOME`` nor a temp name — and is
+    exported through the environment, so child processes agree.
+
+    Not on the CPU backend: there a cache HIT of the donated-buffer
+    train step corrupts the heap (malloc_consolidate aborts / segfaults
+    — a fresh compile runs fine, the next process deserializing that
+    entry dies), which turned every checkpoint-resume into a crash loop
+    under the chaos soak, and CPU compiles are ~1s anyway."""
+    if os.environ.get(COMPILE_CACHE_ENV) or \
+            os.environ.get("JAX_PLATFORMS", "") == "cpu":
+        return
+    if "jax" in sys.modules:
+        raise RuntimeError(
+            "enable_compile_cache() must run before the first jax import")
+    os.environ[COMPILE_CACHE_ENV] = compile_cache_dir()
+
+
+def compile_cache_dir() -> str:
+    """Where this checkout's processes keep compiled programs."""
+    from kubeflow_tpu.utils.proc import PKG_PARENT
+
+    return os.environ.get(COMPILE_CACHE_ENV) or os.path.join(
+        PKG_PARENT, ".kfx_cache", "jax")
+
+
+def device_report() -> dict:
+    """What JAX says this process holds — the triple every entry point
+    prints about itself, so that a CPU fallback is read off the log
+    instead of assumed away."""
     import jax
 
-    if jax.default_backend() == "cpu":
-        return
-    cache_dir = os.environ.get("KFX_JAX_CACHE") or os.path.join(
-        os.path.expanduser("~"), ".kfx", "jax_cache")
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:  # cache is an optimisation, never fatal
-        pass
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
 
 
 def main(argv=None) -> int:
@@ -132,6 +155,7 @@ def main(argv=None) -> int:
     from kubeflow_tpu.runtime.lifetime import install_parent_watch
 
     install_parent_watch()
+    enable_compile_cache()
     # runner.init: interpreter start -> backend ready (rendezvous, jax
     # import, XLA client, model/state init, checkpoint restore — the
     # Checkpointer constructor pays the multi-second orbax import, so
@@ -147,8 +171,6 @@ def main(argv=None) -> int:
             initialize_distributed()
 
         import jax  # after distributed init
-
-        enable_compile_cache()
 
         from kubeflow_tpu.profiling import maybe_start_profiler_server
 

@@ -488,11 +488,9 @@ class GangManager:
         self._gangs: Dict[str, Gang] = {}
 
     def slice_capacity(self) -> int:
-        """Total chips of the emulated slice this runtime launches gangs
-        onto (one replica process == one chip) — the capacity model the
-        cluster scheduler (sched/) admits against. Discovery order:
-        KFX_SLICE_CHIPS, the virtual-mesh XLA device-count flag, host
-        cores with a generous floor."""
+        """Total chips of the slice this runtime launches gangs onto —
+        the capacity model the cluster scheduler (sched/) admits
+        against (sched.slice_capacity has the discovery order)."""
         from ..sched import slice_capacity
 
         return slice_capacity()

@@ -4,12 +4,10 @@ One ``Scheduler`` instance per control plane is the single admission
 point between a workload controller deciding "this job needs a gang"
 and ``gang.spawn`` actually forking processes. It owns:
 
-  * the **capacity model** — the emulated slice's total chip count
-    (discovered from the gang runtime: ``KFX_SLICE_CHIPS``, the
-    ``--xla_force_host_platform_device_count`` virtual-mesh flag, or
-    the host core count) minus the chips reserved by admitted gangs.
-    One replica process == one chip, matching the process-per-chip
-    emulation everywhere else in kfx;
+  * the **capacity model** — the slice's total chip count (see
+    ``slice_capacity``: the host's TPU device nodes, or an emulated
+    slice when the plane runs on the CPU) minus the chips reserved by
+    admitted gangs;
   * **gang all-or-nothing admission** — a job's full replica set is
     reserved atomically or not at all; a gang can never half-start on
     capacity grounds (the spawn layer already guarantees the same for
@@ -44,6 +42,7 @@ aborts that cycle — the storm guard's failure path under test).
 from __future__ import annotations
 
 import dataclasses
+import glob
 import os
 import re
 import threading
@@ -69,13 +68,26 @@ _ADMITTED = "Admitted"
 DEFAULT_SLICE_CHIPS = 32
 
 
+def accelerator_chips() -> int:
+    """TPU chips this host exposes, counted from their device nodes
+    (``/dev/accel*``, or one numbered vfio group per chip as on v5e):
+    what a worker's ``jax.devices()`` will find, learned without
+    starting a backend here — a chip has one owner, and the plane must
+    never be it."""
+    return len(glob.glob("/dev/accel[0-9]*")) or \
+        len(glob.glob("/dev/vfio/[0-9]*"))
+
+
 def slice_capacity() -> int:
-    """Total schedulable chips of the emulated slice, discovered from
-    the gang runtime's environment: ``KFX_SLICE_CHIPS`` wins, then the
-    virtual-mesh ``--xla_force_host_platform_device_count`` XLA flag
-    (vmeshenv.py sets it), then the host core count with a generous
-    floor — the emulation runs one process per chip, so a small core
-    count oversubscribes gracefully rather than starving wide jobs."""
+    """Total schedulable chips. ``KFX_SLICE_CHIPS`` wins. A plane that
+    is not on the CPU (``JAX_PLATFORMS`` other than "cpu") has the
+    chips its host exposes: a job that asks for more stays queued as
+    Unschedulable instead of being handed virtual devices. On the CPU
+    (the tests, a laptop) the slice is emulated: the virtual-mesh
+    ``--xla_force_host_platform_device_count`` XLA flag (vmeshenv.py
+    sets it), else the host core count with a generous floor — the
+    emulation runs one process per chip, so a small core count
+    oversubscribes gracefully rather than starving wide jobs."""
     env = os.environ.get("KFX_SLICE_CHIPS", "")
     if env:
         try:
@@ -84,6 +96,10 @@ def slice_capacity() -> int:
                 return n
         except ValueError:
             pass
+    if os.environ.get("JAX_PLATFORMS", "") != "cpu":
+        chips = accelerator_chips()
+        if chips:
+            return chips
     m = re.search(r"--xla_force_host_platform_device_count=(\d+)",
                   os.environ.get("XLA_FLAGS", ""))
     if m:
@@ -582,7 +598,7 @@ class Scheduler:
                 if e.state == _QUEUED:
                     depth[e.namespace] = depth.get(e.namespace, 0) + 1
         reg.gauge("kfx_sched_capacity_chips",
-                  "Total schedulable chips of the emulated slice."
+                  "Total schedulable chips of the slice."
                   ).set(self.capacity)
         reg.gauge("kfx_sched_reserved_chips",
                   "Chips reserved by admitted gangs.").set(reserved)

@@ -1,3 +1,6 @@
-"""Serving (KFServing parity): model export, servers, InferenceService."""
+"""Serving (KFServing parity): model export, servers, InferenceService.
 
-from .export import export_params, load_exported  # noqa: F401
+Nothing is re-exported here: the control plane imports this package's
+router/autoscaler/storage modules and must stay free of JAX (a chip has one
+owner, and it is the replica, not the plane).
+"""

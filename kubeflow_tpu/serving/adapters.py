@@ -35,7 +35,7 @@ page-pool interaction, the ``engine.adapter_load`` chaos point and the
 sizing/HBM math.
 
 jax imports stay inside methods — the model server imports this module
-on its error-taxonomy path (via engine) before any device exists.
+on its error-classification path (via engine) before any device exists.
 """
 
 from __future__ import annotations

@@ -160,7 +160,7 @@ class LMPredictor(Predictor):
     batch rejection becomes bounded queueing (engine.max_queue)."""
 
     def __init__(self, model_dir: str, name: str = "",
-                 max_batch_size: int = 8, device: str = "auto",
+                 max_batch_size: int = 8, device: str = "default",
                  warm_buckets: Optional[Sequence[int]] = None):
         self.model_dir = model_dir
         self.name = name or "model"

@@ -61,7 +61,7 @@ process respawns, and the bench headline is one query. The
 artifact read during the swap.
 
 jax imports stay inside methods — the model server imports this module
-on its error-taxonomy path (via engine) before any device exists.
+on its error-classification path (via engine) before any device exists.
 """
 
 from __future__ import annotations

@@ -10,12 +10,17 @@ from __future__ import annotations
 
 from typing import Optional
 
-# Peak dense bf16 FLOP/s per chip by TPU generation (public specs).
+# Peak dense bf16 FLOP/s per chip, keyed by the ``device_kind`` JAX
+# reports (Google Cloud TPU documentation, per-generation spec tables).
+# The one table: a device that is not in it has no MFU.
 PEAK_FLOPS = {
-    "v4": 275e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v6e": 918e12,
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,
+    "TPU v5e": 197e12,
+    "TPU v5": 459e12,
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,
+    "TPU v6e": 918e12,
 }
 
 
@@ -40,17 +45,20 @@ def transformer_train_flops_per_token(cfg, seq_len: int) -> float:
     return 3.0 * transformer_fwd_flops_per_token(cfg, seq_len)
 
 
-def peak_flops_per_chip(default: float = PEAK_FLOPS["v5e"]) -> float:
-    """Peak bf16 FLOP/s of the attached chip (by device kind), so MFU is
-    computed against the right roofline."""
+def peak_flops_per_chip() -> float:
+    """Peak bf16 FLOP/s of the attached chip, so MFU is computed against
+    the right roofline. A device without a published peak (the CPU
+    included) is an error, never a default: a utilisation against
+    someone else's peak is not a measurement."""
     import jax
 
-    kind = jax.devices()[0].device_kind.lower()
-    for gen, peak in PEAK_FLOPS.items():
-        if gen in kind.replace(" ", "").replace("lite", "e"):
-            return peak
-    # "TPU v5 lite" (v5e) reports as e.g. "TPU v5 lite"; fall back.
-    return default
+    kind = jax.devices()[0].device_kind
+    try:
+        return PEAK_FLOPS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak FLOP/s for device kind {kind!r}: MFU is "
+            f"undefined on it (known: {sorted(PEAK_FLOPS)})") from None
 
 
 def mfu(tokens_per_s: float, flops_per_token: float,

@@ -502,6 +502,10 @@ class TestFleetMigrationE2E:
                             events.append(json.loads(ln[6:]))
                     lines = []
                     if events and events[-1].get("done"):
+                        # The router settles its counters between the
+                        # done frame and the terminal chunk: read to
+                        # end-of-stream before scraping them.
+                        resp.read()
                         break
                     n_tok = sum(1 for e in events if "token" in e)
                     if stats is None and n_tok >= 1:

@@ -353,12 +353,18 @@ class TestApiServerMetrics:
         assert rec and rec["count"] >= 1 and rec["p50_ms"] is not None
         server.cp.store.delete("JAXJob", "scrape-job")
 
-    def test_train_mfu_bridged_and_require_scrapeable(self, server):
+    def test_train_mfu_bridged_and_require_scrapeable(self, server,
+                                                      monkeypatch):
         """kfx_train_mfu{job,config} + kfx_train_step_seconds are
         recorded live into the process default registry by LMTrainLoop
         and bridged onto the plane's /metrics (MetricsRegistry
         add_external), so `scrape_metrics --require kfx_train_mfu` pins
-        the family in CI — the ISSUE-8 satellite contract."""
+        the family in CI — the ISSUE-8 satellite contract. MFU exists
+        only for a device with a published peak, so the test gives this
+        suite's CPU one."""
+        from kubeflow_tpu.utils import flops
+
+        monkeypatch.setitem(flops.PEAK_FLOPS, "cpu", 1e12)
         sys.path.insert(0, os.path.join(
             os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
             "scripts"))

@@ -8,6 +8,7 @@ job lifecycle is integration-tested against the in-memory store with real
 
 import json
 import os
+import subprocess
 import sys
 import time
 
@@ -25,6 +26,7 @@ from kubeflow_tpu.operators.training import (
 from kubeflow_tpu.runtime import rendezvous as rdv
 
 PY = sys.executable
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _job(kind, name, replicas_field, replica_map, run_policy=None, ns="default"):
@@ -64,6 +66,42 @@ ENV_DUMP = ("import json,os;"
             "print(json.dumps({k:v for k,v in os.environ.items()}))")
 
 
+def test_plane_process_never_imports_jax(tmp_path):
+    """A chip has one owner: the worker or the replica, never the plane
+    that starts them. A fresh interpreter builds a ControlPlane, runs a
+    JAXJob through it (admission, scheduler, gang spawn, status) and
+    touches the serving operator's status helpers — and must come out
+    without jax in sys.modules, let alone a backend. (This process
+    cannot be asked: its other tests use jax.)"""
+    script = f"""
+import sys
+from kubeflow_tpu.controlplane import ControlPlane
+from kubeflow_tpu.api.base import from_manifest
+job = from_manifest({{
+    "apiVersion": "kubeflow.org/v1", "kind": "JAXJob",
+    "metadata": {{"name": "nojax", "namespace": "default"}},
+    "spec": {{"parallelism": {{"tensor": 2}},
+             "jaxReplicaSpecs": {{"Worker": {{
+        "replicas": 1, "restartPolicy": "Never",
+        "template": {{"spec": {{"containers": [{{
+            "name": "main",
+            "command": [sys.executable, "-c", "print(1)"]}}]}}}}}}}}}}}})
+with ControlPlane(home={str(tmp_path / "kfx")!r}) as cp:
+    cp.apply([job])
+    done = cp.wait_for_job("JAXJob", "nojax", timeout=60)
+    assert done.has_condition("Succeeded"), done.conditions
+    from kubeflow_tpu.serving.engine import quant_mode_string
+    assert quant_mode_string("int8", "f32") == "w8"
+assert "jax" not in sys.modules, "the plane imported jax"
+print("plane_is_jax_free")
+"""
+    env = dict(os.environ, PYTHONPATH=REPO_ROOT)
+    out = subprocess.run([PY, "-c", script], env=env, capture_output=True,
+                         text=True, timeout=120, cwd=str(tmp_path))
+    assert out.returncode == 0 and "plane_is_jax_free" in out.stdout, \
+        out.stdout + out.stderr
+
+
 class TestEnvContracts:
     """Unit-level: what env does each kind inject? (SURVEY.md §4 key insight:
     the reference tests multi-worker logic at the env-injection level.)"""
@@ -90,6 +128,26 @@ class TestEnvContracts:
         a0 = hook(0)["*"][rdv.ENV_COORDINATOR]
         a1 = hook(1)["*"][rdv.ENV_COORDINATOR]
         assert a0.startswith("127.0.0.1:") and a0 != a1
+
+    def test_multichip_jaxjob_keeps_the_accelerator(self, tmp_path,
+                                                    monkeypatch):
+        """On a TPU host (the plane's JAX_PLATFORMS is not cpu) a job
+        that spans chips inherits the accelerator: the operator neither
+        pins the CPU platform nor hands it virtual devices. A job too
+        wide for the host is the scheduler's to refuse (test_sched)."""
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        monkeypatch.delenv("KFX_WORKER_PLATFORM", raising=False)
+        job = _job("JAXJob", "wide", "jaxReplicaSpecs",
+                   {"Worker": {"replicas": 1, "template": _tmpl("pass")}})
+        job.spec["parallelism"] = {"tensor": 2, "data": 2, "fsdp": True}
+        cp_ = ControlPlane(home=str(tmp_path / "h"))
+        ctrl = next(c for c in cp_.manager.controllers.values()
+                    if isinstance(c, JAXJobController))
+        (spec,), _ = ctrl.build_specs(job, str(tmp_path / "wd"))
+        cp_.stop()
+        assert spec.env["JAX_PLATFORMS"] == "tpu,cpu"
+        assert "XLA_FLAGS" not in spec.env
+        assert json.loads(spec.env["KFX_PARALLELISM"])["tensor"] == 2
 
     def test_tfjob_tf_config(self, tmp_path):
         job = _job("TFJob", "t", "tfReplicaSpecs", {

@@ -1,14 +1,14 @@
 """Pallas kernel tests (ops/): flash attention numerics vs the dense
 oracle, gradient parity, and the model-level attn_impl switch. On the
-CPU test mesh the kernels run in pallas interpret mode — identical code
-path, reference semantics."""
+CPU test mesh the tests ask for the Pallas interpreter themselves (the
+``interpret_flash`` fixture); the program never picks it."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-INTERP = jax.default_backend() != "tpu"
+pytestmark = pytest.mark.usefixtures("interpret_flash")
 
 
 def _dense(q, k, v):
@@ -32,8 +32,7 @@ class TestFlashAttention:
         q = _rand((B, S, H, D), 0, 1 / 8)
         k = _rand((B, S, H, D), 1)
         v = _rand((B, S, H, D), 2)
-        out = jax.jit(lambda q, k, v: flash_attention(
-            q, k, v, interpret=INTERP))(q, k, v)
+        out = jax.jit(flash_attention)(q, k, v)
         ref = _dense(q, k, v)
         assert float(jnp.max(jnp.abs(out - ref))) < 2e-2
 
@@ -46,8 +45,7 @@ class TestFlashAttention:
         v = _rand((B, S, H, D), 5)
 
         gf = jax.jit(jax.grad(
-            lambda q, k, v: jnp.sum(
-                flash_attention(q, k, v, interpret=INTERP) ** 2),
+            lambda q, k, v: jnp.sum(flash_attention(q, k, v) ** 2),
             argnums=(0, 1, 2)))(q, k, v)
         gd = jax.grad(
             lambda q, k, v: jnp.sum(_dense(q, k, v) ** 2),
@@ -65,7 +63,7 @@ class TestFlashAttention:
         q = _rand((B, S, H, D), 6, 1 / 8)
         k = _rand((B, S, H, D), 7)
         v = _rand((B, S, H, D), 8)
-        out = flash_attention(q, k, v, interpret=INTERP)
+        out = flash_attention(q, k, v)
         ref = _dense(q, k, v)
         assert float(jnp.max(jnp.abs(out - ref))) < 2e-2
 

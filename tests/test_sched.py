@@ -70,6 +70,20 @@ class TestCapacityModel:
         monkeypatch.delenv("XLA_FLAGS")
         assert slice_capacity() >= 1
 
+    def test_accelerator_host_capacity_is_its_chips(self, monkeypatch):
+        """Off the CPU the slice is the chips the host exposes (device
+        nodes — the plane never asks JAX), not an assumed 32; on the
+        CPU the nodes are not consulted."""
+        from kubeflow_tpu.sched import scheduler
+
+        monkeypatch.delenv("KFX_SLICE_CHIPS", raising=False)
+        monkeypatch.delenv("XLA_FLAGS", raising=False)
+        monkeypatch.setattr(scheduler, "accelerator_chips", lambda: 4)
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+        assert slice_capacity() == 4
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        assert slice_capacity() >= scheduler.DEFAULT_SLICE_CHIPS
+
     def test_priority_sources(self):
         assert job_priority(_job("a")) == 0
         assert job_priority(_job("b", prio=7)) == 7

@@ -1,0 +1,98 @@
+"""The main path's Pallas kernels compiled by the real TPU compiler for a
+described (not attached) v5e, at the head shapes the presets train at.
+
+Interpret mode (every other kernel test in this suite) cannot see what
+Mosaic refuses: misaligned slices, too much VMEM, an unpartitionable
+kernel. libtpu is installed here and compiles for a chip it is only told
+about, so these cost seconds and no chip time. Kernel-only: whole-step
+compiles stay in a builder's scratch (they take tens of seconds each).
+Nothing runs, so nothing here is a result or a speed.
+"""
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+TOPOLOGY = "v5e:2x2"
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name=TOPOLOGY)
+    except Exception as e:  # no libtpu, or it cannot describe the chip
+        pytest.skip(f"cannot describe a {TOPOLOGY} topology here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it off around these.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# (batch, heads, seq, head_dim): the K/V block of every kernel is the
+# WHOLE sequence and the lse block's last dimension is 1, so VMEM and
+# tiling are decided by S and D.
+SHAPES = {
+    "base-S2048": (8, 16, 2048, 64),
+    "large-S2048": (4, 16, 2048, 128),
+    "large-S4096": (2, 16, 4096, 128),
+}
+
+
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_flash_kernels_compile_for_v5e(one_chip, shape, direction):
+    from kubeflow_tpu.ops import flash_attention as fa
+
+    B, H, S, D = shape
+    bq = bk = fa._pick_block(S)
+    x = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16, sharding=one_chip)
+    row = jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32, sharding=one_chip)
+    if direction == "forward":
+        fn = lambda q, k, v: fa._fwd(q, k, v, block_q=bq, block_k=bk)
+        compiled = jax.jit(fn).lower(x, x, x).compile()
+        kernels = 1
+    else:  # dq and dkv are two kernels of one backward
+        fn = lambda q, k, v, o, lse, do: fa._bwd(
+            bq, bk, (q, k, v, o, lse), do)
+        compiled = jax.jit(fn).lower(x, x, x, x, row, x).compile()
+        kernels = 2
+    assert compiled.as_text().count("tpu_custom_call") == kernels
+
+
+def test_libtpu_registers_the_overlap_flags():
+    """``lm_runner --collective-overlap`` hands these to libtpu through
+    LIBTPU_INIT_ARGS; libtpu aborts on a flag it does not register (as
+    jaxlib does for XLA_FLAGS, where they used to be put). Flags are
+    read once per process, hence the child; it only describes a chip,
+    so it may load libtpu beside this process."""
+    from kubeflow_tpu.parallel.overlap import LIBTPU_ENV, OVERLAP_TPU_FLAGS
+
+    code = ("from jax.experimental import topologies\n"
+            f"topologies.get_topology_desc(platform='tpu', "
+            f"topology_name={TOPOLOGY!r})\n"
+            "print('libtpu_started')\n")
+    env = dict(os.environ, ALLOW_MULTIPLE_LIBTPU_LOAD="1",
+               **{LIBTPU_ENV: " ".join(OVERLAP_TPU_FLAGS)})
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    if "libtpu_started" not in out.stdout and \
+            "Unknown command line flag" not in out.stderr:
+        pytest.skip(f"cannot describe a {TOPOLOGY} topology here")
+    assert out.returncode == 0 and "libtpu_started" in out.stdout, \
+        out.stderr[-2000:]
