@@ -1,0 +1,10 @@
+"""CPU only, four virtual devices: set before anything imports jax."""
+
+import os
+import sys
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault(
+    "XLA_FLAGS", "--xla_force_host_platform_device_count=4")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
