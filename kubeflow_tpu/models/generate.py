@@ -64,6 +64,7 @@ def decode_config(cfg: TransformerConfig,
         max_seq_len=max_len or cfg.max_seq_len)
 
 
+@jax.named_scope("sample")  # the sampler's ops by name in a trace
 def _sample(logits: jnp.ndarray, rng, temperature, top_k) -> jnp.ndarray:
     """logits [B, V] -> token ids [B]. temperature/top_k are TRACED
     scalars (sampling knobs never trigger a recompile — they are

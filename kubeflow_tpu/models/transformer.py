@@ -695,7 +695,14 @@ class Attention(nn.Module):
         engine feeds the pending token + k draft proposals as one
         window and reads k+1 next-token distributions back; rejection
         rolls the cursor back and stamps the tail's position ids to
-        -1, no page copies)."""
+        -1, no page copies).
+
+        The parts carry ``jax.named_scope`` names — ``kv_write``,
+        ``kv_gather``, ``scores``, ``pv`` — under the module's own
+        ``attn`` scope (flax names every module call), so a profiler
+        trace or the compiled HLO attributes an op to its part by name
+        (``.../attn/attn._decode_attend/kv_gather/...``) and not by
+        fusion number."""
         cfg = self.cfg
         B, S, H, D = q.shape
         L = cfg.max_seq_len
@@ -736,50 +743,58 @@ class Attention(nn.Module):
             # mode="drop" discards the update.
             page = jnp.where(ok & (page >= 0), page, N)
             slot = jnp.where(ok, loc % P, 0)
-            if int8_kv:
-                # Quantize-on-write: round each token's K/V row to int8
-                # against its own max-abs scale. A zero row quantizes
-                # to zeros with scale 0 (dequant exact).
-                def q8(x):
-                    xf = x.astype(jnp.float32)
-                    s = jnp.max(jnp.abs(xf), axis=(-2, -1)) / 127.0
-                    q = jnp.clip(
-                        jnp.round(xf
-                                  / jnp.maximum(s, 1e-30)[..., None, None]),
-                        -127, 127).astype(jnp.int8)
-                    return q, s
-                kq, ks = q8(k)
-                vq, vs = q8(v)
-                ck.value = ck.value.at[page, slot].set(kq, mode="drop")
-                cv.value = cv.value.at[page, slot].set(vq, mode="drop")
-                ksc.value = ksc.value.at[page, slot].set(ks, mode="drop")
-                vsc.value = vsc.value.at[page, slot].set(vs, mode="drop")
-            else:
-                ck.value = ck.value.at[page, slot].set(
-                    k.astype(cfg.dtype), mode="drop")
-                cv.value = cv.value.at[page, slot].set(
-                    v.astype(cfg.dtype), mode="drop")
-            cpos.value = cpos.value.at[page, slot].set(pos, mode="drop")
+            with jax.named_scope("kv_write"):
+                if int8_kv:
+                    # Quantize-on-write: round each token's K/V row to
+                    # int8 against its own max-abs scale. A zero row
+                    # quantizes to zeros with scale 0 (dequant exact).
+                    def q8(x):
+                        xf = x.astype(jnp.float32)
+                        s = jnp.max(jnp.abs(xf), axis=(-2, -1)) / 127.0
+                        q = jnp.clip(
+                            jnp.round(
+                                xf / jnp.maximum(s, 1e-30)[..., None,
+                                                           None]),
+                            -127, 127).astype(jnp.int8)
+                        return q, s
+                    kq, ks = q8(k)
+                    vq, vs = q8(v)
+                    ck.value = ck.value.at[page, slot].set(
+                        kq, mode="drop")
+                    cv.value = cv.value.at[page, slot].set(
+                        vq, mode="drop")
+                    ksc.value = ksc.value.at[page, slot].set(
+                        ks, mode="drop")
+                    vsc.value = vsc.value.at[page, slot].set(
+                        vs, mode="drop")
+                else:
+                    ck.value = ck.value.at[page, slot].set(
+                        k.astype(cfg.dtype), mode="drop")
+                    cv.value = cv.value.at[page, slot].set(
+                        v.astype(cfg.dtype), mode="drop")
+                cpos.value = cpos.value.at[page, slot].set(
+                    pos, mode="drop")
             # Gather each row's logical view [L] through its table.
             # Unallocated blocks clamp to page 0 for K/V (their scores
             # are masked to exactly-0 probability via position -1, so
             # the garbage never contributes) and force position -1.
-            pt = jnp.clip(block_tables, 0, N - 1)        # [B, nblk]
-            if int8_kv:
-                # Dequant-on-gather: int8 entries x the per-token scale
-                # plane, in f32 (one multiply per gathered element),
-                # then the compute dtype.
-                gks = ksc.value[pt].reshape(B, L)[..., None, None]
-                gvs = vsc.value[pt].reshape(B, L)[..., None, None]
-                gk = (ck.value[pt].reshape(B, L, H, D).astype(jnp.float32)
-                      * gks).astype(cfg.dtype)
-                gv = (cv.value[pt].reshape(B, L, H, D).astype(jnp.float32)
-                      * gvs).astype(cfg.dtype)
-            else:
-                gk = ck.value[pt].reshape(B, L, H, D)
-                gv = cv.value[pt].reshape(B, L, H, D)
-            gp = jnp.where((block_tables >= 0)[..., None],
-                           cpos.value[pt], -1).reshape(B, L)
+            with jax.named_scope("kv_gather"):
+                pt = jnp.clip(block_tables, 0, N - 1)    # [B, nblk]
+                if int8_kv:
+                    # Dequant-on-gather: int8 entries x the per-token
+                    # scale plane, in f32 (one multiply per gathered
+                    # element), then the compute dtype.
+                    gks = ksc.value[pt].reshape(B, L)[..., None, None]
+                    gvs = vsc.value[pt].reshape(B, L)[..., None, None]
+                    gk = (ck.value[pt].reshape(B, L, H, D).astype(
+                        jnp.float32) * gks).astype(cfg.dtype)
+                    gv = (cv.value[pt].reshape(B, L, H, D).astype(
+                        jnp.float32) * gvs).astype(cfg.dtype)
+                else:
+                    gk = ck.value[pt].reshape(B, L, H, D)
+                    gv = cv.value[pt].reshape(B, L, H, D)
+                gp = jnp.where((block_tables >= 0)[..., None],
+                               cpos.value[pt], -1).reshape(B, L)
         else:
             ck = self.variable("cache", "cached_key",
                                lambda: jnp.zeros((B, L, H, D), cfg.dtype))
@@ -790,21 +805,27 @@ class Attention(nn.Module):
             cur = self.variable("cache", "cache_index",
                                 lambda: jnp.zeros((B,), jnp.int32))
             i = cur.value  # [B]
-            rows = jnp.arange(B, dtype=jnp.int32)[:, None]          # [B, 1]
-            at = i[:, None] + jnp.arange(S, dtype=jnp.int32)[None]  # [B, S]
-            ck.value = ck.value.at[rows, at].set(k.astype(cfg.dtype))
-            cv.value = cv.value.at[rows, at].set(v.astype(cfg.dtype))
-            cpos.value = cpos.value.at[rows, at].set(positions)
-            cur.value = i + S
+            with jax.named_scope("kv_write"):
+                rows = jnp.arange(B, dtype=jnp.int32)[:, None]  # [B, 1]
+                at = i[:, None] + jnp.arange(
+                    S, dtype=jnp.int32)[None]                   # [B, S]
+                ck.value = ck.value.at[rows, at].set(k.astype(cfg.dtype))
+                cv.value = cv.value.at[rows, at].set(v.astype(cfg.dtype))
+                cpos.value = cpos.value.at[rows, at].set(positions)
+                cur.value = i + S
             gk, gv, gp = ck.value, cv.value, cpos.value
 
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, gk)  # [B,H,S,L]
-        kp = gp[:, None, None, :]                      # [B,1,1,L]
-        qp = positions[:, None, :, None]               # [B,1,S,1]
-        mask = (kp >= 0) & (kp <= qp)
-        scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), -1)
-        return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(cfg.dtype), gv)
+        with jax.named_scope("scores"):
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, gk)  # [B,H,S,L]
+            kp = gp[:, None, None, :]                      # [B,1,1,L]
+            qp = positions[:, None, :, None]               # [B,1,S,1]
+            mask = (kp >= 0) & (kp <= qp)
+            scores = jnp.where(mask, scores,
+                               jnp.finfo(scores.dtype).min)
+            probs = jax.nn.softmax(scores.astype(jnp.float32), -1)
+        with jax.named_scope("pv"):
+            return jnp.einsum("bhqk,bkhd->bqhd",
+                              probs.astype(cfg.dtype), gv)
 
 
 class DenseFFN(nn.Module):

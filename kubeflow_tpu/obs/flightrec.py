@@ -8,7 +8,8 @@ request ids, pages free in both KV pools, speculation counters,
 iteration stall seconds, queue depth, preemption count) into a
 bounded ring. Each `Request` accumulates a small event trail (admit,
 prefill chunks, first token, preempt, retire); on retire the trail is
-folded into a latency breakdown ``{queue_wait_s, prefill_s, decode_s,
+folded into a latency breakdown ``{queue_wait_s, prefill_s,
+prefill_span_s, first_token_wait_s, prefill_iterations, decode_s,
 stalled_s, spec_accept}`` and pushed into a bounded recent-requests
 ring.
 
@@ -122,17 +123,36 @@ class FlightRecorder:
     @staticmethod
     def timing(req) -> dict:
         """Latency breakdown for one request, computable at any point
-        after retirement (and best-effort before)."""
+        after retirement (and best-effort before).
+
+        ``prefill_s`` (admission -> first token on the host) is split
+        where the engine spends it, from stamps the request carries:
+        ``prefill_span_s`` runs from admission to the enqueue of the
+        request's LAST prompt dispatch (its own chunks plus the
+        iterations it sat out between them, ``prefill_iterations`` in
+        all), ``first_token_wait_s`` from there to the first token
+        visible on the host: the decode chunk that samples it,
+        delivered at the chunk's end. Their sum is ``prefill_s``. A
+        request that never prefilled here (a KV import) has a span of
+        0. ``stalled_s`` sums prefill dispatch times the request
+        waited through while decoding; on a backend that dispatches
+        asynchronously (the TPU) those are enqueue times, and the
+        prefill's device time shows as a longer decode chunk."""
         t_done = req.t_done or time.monotonic()
         t_admit = req.t_admitted or t_done
         t_first = req.t_first or t_done
         queue_wait = max(0.0, t_admit - req.t_enqueue)
-        prefill = max(0.0, t_first - t_admit)
+        prefill = round(max(0.0, t_first - t_admit), 6)
+        t_enqueued = min(max(req.t_prefill_end, t_admit), t_first)
+        span = round(max(0.0, t_enqueued - t_admit), 6)
         decode = max(0.0, t_done - t_first)
         accept = (req.spec_acc / req.spec_prop) if req.spec_prop else None
         return {
             "queue_wait_s": round(queue_wait, 6),
-            "prefill_s": round(prefill, 6),
+            "prefill_s": prefill,
+            "prefill_span_s": span,
+            "first_token_wait_s": round(prefill - span, 6),
+            "prefill_iterations": int(req.prefill_iters),
             "decode_s": round(decode, 6),
             "stalled_s": round(float(req.stall_s), 6),
             "spec_accept": None if accept is None else round(accept, 4),

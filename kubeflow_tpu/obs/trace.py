@@ -23,6 +23,18 @@ into Dapper-style spans (PAPERS.md) so `kfx trace <job>` can answer
 The old flat-ID helpers (current_trace_id / ensure_trace / ...) are
 unchanged; ``span(...)`` keeps its PR-1 signature (trace scoping +
 optional histogram observation) and now records real spans.
+
+A process that owns an accelerator also puts its spans on the
+**profiler's clock**: it registers an annotation factory once
+(``set_annotation_factory(jax.profiler.TraceAnnotation)`` — this module
+never imports jax, the plane imports it) and from then on every
+``span()`` / ``start_span()`` opens that annotation beside the span it
+records, and ``annotate(name, **attrs)`` gives the annotation alone,
+for phases too fine to be worth a JSONL line. The spans then sit in any
+xplane trace of the process next to the device's operations, with no
+clock matching on the reader's side. With no profiler session active
+an annotation is a flag test; with no factory registered ``annotate``
+returns one shared null context.
 """
 
 from __future__ import annotations
@@ -46,6 +58,32 @@ COMPONENT_ENV = "KFX_COMPONENT"
 SPANS_DIRNAME = "spans"
 
 _tls = threading.local()
+
+# The bridge to the profiler's clock: a callable ``(name, **attrs) ->
+# context manager`` (jax.profiler.TraceAnnotation), set once by a
+# process that holds a device. None: spans go to the JSONL log only.
+_annotation_factory = None
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def set_annotation_factory(factory) -> None:
+    """Register (None: clear) the factory that ``span`` / ``start_span``
+    / ``annotate`` open beside what they record. Called by the code
+    that already imports jax (the decode engine, the LM train loop,
+    jax_runner); this module stays free of it."""
+    global _annotation_factory
+    _annotation_factory = factory
+
+
+def annotate(name: str, **attrs):
+    """A context manager that marks ``name`` (with ``attrs``) on the
+    calling thread in the profiler's trace, and nothing else: no span
+    record, no trace scoping. The shared null context when no factory
+    is registered."""
+    factory = _annotation_factory
+    if factory is None:
+        return _NO_ANNOTATION
+    return factory(name, **attrs)
 
 
 def new_trace_id() -> str:
@@ -108,7 +146,7 @@ class Span:
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start",
                  "duration", "status", "attrs", "started", "elapsed",
-                 "_prev_trace")
+                 "_prev_trace", "_annotation")
 
     def __init__(self, name: str, trace_id: str, parent_id: str = "",
                  ts: Optional[float] = None,
@@ -127,6 +165,7 @@ class Span:
         self.started = time.perf_counter()
         self.elapsed = 0.0
         self._prev_trace = ""
+        self._annotation = None  # the open profiler annotation, if any
 
     def to_record(self) -> Dict:
         rec = {"name": self.name, "trace": self.trace_id,
@@ -308,12 +347,18 @@ def start_span(name: str, trace_id: str = "", parent_id: str = "",
     sp._prev_trace = getattr(_tls, "trace_id", "")
     _tls.trace_id = tid
     _stack().append(sp)
+    if _annotation_factory is not None:
+        sp._annotation = _annotation_factory(name, **sp.attrs)
+        sp._annotation.__enter__()
     return sp
 
 
 def finish_span(sp: Span, status: str = "") -> Span:
     """Close a span: stamp duration/status, restore the thread context,
     append it to the process span log."""
+    if sp._annotation is not None:
+        sp._annotation.__exit__(None, None, None)
+        sp._annotation = None
     sp.elapsed = time.perf_counter() - sp.started
     sp.duration = max(time.time() - sp.start, 0.0)
     if status:

@@ -118,6 +118,7 @@ def _fwd(q, k, v, *, block_q: int, block_k: int
             _sds((B, H, S, 1), jnp.float32, q),
         ],
         interpret=INTERPRET,
+        name="kfx_flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -216,6 +217,7 @@ def _bwd(block_q, block_k, res, do):
         out_specs=qb,
         out_shape=_sds((B, H, S, D), q.dtype, q),
         interpret=INTERPRET,
+        name="kfx_flash_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -227,6 +229,7 @@ def _bwd(block_q, block_k, res, do):
         out_shape=[_sds((B, H, S, D), k.dtype, q),
                    _sds((B, H, S, D), v.dtype, q)],
         interpret=INTERPRET,
+        name="kfx_flash_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -25,6 +25,7 @@ import optax
 from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs import trace as obs_trace
 from ..obs.metrics import default_registry
 from ..models.transformer import (
     TransformerConfig,
@@ -116,6 +117,12 @@ class LMTrainLoop:
         self._state_shardings = None
         self._train_step = None
         self._eval_step = None
+        # Steps dispatched by this loop: the step number the profiler's
+        # trace carries (a resumed run counts from its resume).
+        self._dispatched = 0
+        # This process drives devices: its spans go into the profiler's
+        # trace too (obs/trace.py).
+        obs_trace.set_annotation_factory(jax.profiler.TraceAnnotation)
         # Step-time + MFU observability on the process registry (same
         # contract as training/loop.py's classifier TrainLoop): stdout
         # lines stay the collector interface, the registry gives
@@ -203,8 +210,11 @@ class LMTrainLoop:
     def init_state(self) -> LMTrainState:
         """Initialise directly into the sharded layout (no host round-trip;
         each device materialises only its shard)."""
+        def kfx_init_state(rng):  # the program's name in a trace
+            return self._init_fn(rng)
+
         with jax.set_mesh(self.mesh):
-            init = jax.jit(self._init_fn,
+            init = jax.jit(kfx_init_state,
                            out_shardings=self.state_shardings())
             return init(jax.random.PRNGKey(self.hp.seed))
 
@@ -244,7 +254,9 @@ class LMTrainLoop:
             # where CSE across iterations cannot happen anyway — the
             # guard only blocks optimisations (same tuning as the layer
             # stack's nn.remat in models/transformer.py).
-            ce_s, hit_s = jax.checkpoint(chunk, prevent_cse=False)(h_c)
+            with jax.named_scope("loss_chunk"):
+                ce_s, hit_s = jax.checkpoint(
+                    chunk, prevent_cse=False)(h_c)
             return (carry[0] + ce_s, carry[1] + hit_s), None
 
         init = (jnp.float32(0.0), jnp.float32(0.0))
@@ -280,7 +292,9 @@ class LMTrainLoop:
 
     # -- steps --------------------------------------------------------------
     def _build_train_step(self):
-        def step(state: LMTrainState, tokens):
+        # Named for the profiler's trace: the program is
+        # jit_kfx_train_step there, not one more jit_step.
+        def kfx_train_step(state: LMTrainState, tokens):
             (loss, acc), grads = jax.value_and_grad(
                 self._loss_fn, has_aux=True)(state.params, tokens)
             updates, opt_state = self.tx.update(grads, state.opt_state,
@@ -291,16 +305,18 @@ class LMTrainLoop:
             return new_state, loss, acc
 
         sh = self.state_shardings()
-        return jax.jit(step, in_shardings=(sh, self.batch_sharding),
+        return jax.jit(kfx_train_step,
+                       in_shardings=(sh, self.batch_sharding),
                        out_shardings=(sh, self.repl, self.repl),
                        donate_argnums=(0,))
 
     def _build_eval_step(self):
-        def step(params, tokens):
+        def kfx_eval_step(params, tokens):
             return self._loss_fn(params, tokens)
 
         sh = self.state_shardings()
-        return jax.jit(step, in_shardings=(sh.params, self.batch_sharding),
+        return jax.jit(kfx_eval_step,
+                       in_shardings=(sh.params, self.batch_sharding),
                        out_shardings=(self.repl, self.repl))
 
     # -- driving ------------------------------------------------------------
@@ -332,8 +348,14 @@ class LMTrainLoop:
                 seq_len = tokens.shape[1] - 1
                 n_tokens += tokens.shape[0] * seq_len
                 n_steps += 1
-                state, loss, acc = self._train_step(
-                    state, self.global_batch(tokens))
+                # Marks the step's DISPATCH on the host (the call
+                # returns before the device is done); the device time
+                # is the program's, jit_kfx_train_step.
+                with jax.profiler.StepTraceAnnotation(
+                        "kfx_train_step", step_num=self._dispatched):
+                    state, loss, acc = self._train_step(
+                        state, self.global_batch(tokens))
+                self._dispatched += 1
             if loss is None:
                 raise ValueError("train_many needs at least one batch")
         loss, acc = float(loss), float(acc)  # device sync before timing

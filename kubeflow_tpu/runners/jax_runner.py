@@ -172,6 +172,9 @@ def main(argv=None) -> int:
 
         import jax  # after distributed init
 
+        # From here on this process's spans are in its profiler traces
+        # too (obs/trace.py's bridge; `kfx profile` reads them).
+        obs_trace.set_annotation_factory(jax.profiler.TraceAnnotation)
         from kubeflow_tpu.profiling import maybe_start_profiler_server
 
         maybe_start_profiler_server()

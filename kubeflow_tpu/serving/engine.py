@@ -177,11 +177,12 @@ for ``EngineOverloaded`` on its own import path.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import OrderedDict, defaultdict, deque
 from typing import (Any, Callable, Dict, List, Optional, Sequence, Tuple,
                     Union)
 
@@ -345,7 +346,8 @@ class Request:
                  "trace_id", "span_id", "_event", "rid", "events",
                  "t_first", "stall_s", "preempts", "spec_prop",
                  "spec_acc", "_flight", "qos", "deadline", "on_token",
-                 "tenant", "meter_skip", "_usage")
+                 "tenant", "meter_skip", "_usage", "it_admitted",
+                 "t_prefill_end", "prefill_iters")
 
     _rid_counter = itertools.count(1)
 
@@ -402,6 +404,15 @@ class Request:
         self.rid = next(Request._rid_counter)
         self.events: List[dict] = []
         self.t_first = 0.0            # first generated token landed
+        # The TTFT split (obs/flightrec.py timing): the loop iteration
+        # of the first admission, and — stamped at every prompt
+        # dispatch until the first token lands — when the LAST prompt
+        # chunk was enqueued and how many iterations the prompt took
+        # by then. Kept on the request, not searched in ``events``: a
+        # trail cut by MAX_EVENTS still has them.
+        self.it_admitted = 0
+        self.t_prefill_end = 0.0
+        self.prefill_iters = 0
         self.stall_s = 0.0            # stall seconds while active
         self.preempts = 0
         self.spec_prop = 0            # draft tokens proposed for us
@@ -729,6 +740,9 @@ class DecodeEngine:
         from ..models.generate import decode_config
         from ..models.transformer import TransformerLM
 
+        # This process drives a device: its spans and the loop's phase
+        # marks go into the profiler's trace too (obs/trace.py).
+        obs_trace.set_annotation_factory(jax.profiler.TraceAnnotation)
         if n_slots < 1:
             raise ValueError("n_slots must be >= 1")
         if chunk_tokens < 1:
@@ -1060,6 +1074,13 @@ class DecodeEngine:
         # decode slots waited on this iteration — what the
         # kfx_lm_decode_stall_seconds histogram observes.
         self._iter_stall = 0.0
+        # Host seconds of the loop thread in the current iteration, by
+        # phase (exclusive: a nested phase pauses the one around it),
+        # and the stack of open phases as [name, since]. Flushed once
+        # an iteration into kfx_lm_engine_host_seconds_total{phase} and
+        # kfx_lm_engine_device_wait_seconds_total (loop thread only).
+        self._phase_s: Dict[str, float] = defaultdict(float)
+        self._phase_stack: List[List[Any]] = []
 
         # -- compiled executables (AOT, so a background warm populates
         # the same table the admission path reads — no jit-cache games)
@@ -1612,6 +1633,18 @@ class DecodeEngine:
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
             self._lora_tree(draft))
 
+    @staticmethod
+    def _named(fn, what: str):
+        """``fn`` under kfx's own name, which ``jax.jit`` gives the
+        compiled program: ``jit_run_kfx_<what>`` in a profiler trace,
+        in place of one ``jit_run`` for every program. The ``run_``
+        stays in front while benchmark/layer_metrics still picks the
+        engine's programs by ``jit_run`` and rank (decode_step_ms,
+        prefill_chunk_ms, decode_hbm_pct); the readers by name match
+        ``kfx_<what>`` with or without it."""
+        fn.__name__ = fn.__qualname__ = f"run_kfx_{what}"
+        return fn
+
     def _build(self, build_fn, *args):
         """Run one AOT build under the ``_building`` marker so the
         liveness heartbeat can tell "slow: compiling" from "stuck".
@@ -1687,7 +1720,8 @@ class DecodeEngine:
             self._lora_specs(),
             jax.ShapeDtypeStruct((1,), np.int32),
         )
-        return jax.jit(run, donate_argnums=donate).lower(*specs).compile()
+        return jax.jit(self._named(run, f"prefill_{P}"),
+                       donate_argnums=donate).lower(*specs).compile()
 
     def _decode(self):
         with self._exec_lock:
@@ -1786,7 +1820,8 @@ class DecodeEngine:
             self._lora_specs(),
             sds((B,), np.int32),      # adapter ids
         )
-        return jax.jit(run, donate_argnums=donate).lower(*specs).compile()
+        return jax.jit(self._named(run, "decode_chunk"),
+                       donate_argnums=donate).lower(*specs).compile()
 
     def _reset_fn(self, draft: bool = False):
         """Compiled page invalidation: sets cached position ids to -1
@@ -1817,7 +1852,8 @@ class DecodeEngine:
         specs = (self._cache_specs(draft),
                  jax.ShapeDtypeStruct((n,), np.bool_))
         fn = self._build(
-            jax.jit(run, donate_argnums=donate).lower(*specs).compile)
+            jax.jit(self._named(run, "kv_reset"),
+                    donate_argnums=donate).lower(*specs).compile)
         with self._exec_lock:
             if getattr(self, attr) is None:
                 setattr(self, attr, fn)
@@ -1855,8 +1891,10 @@ class DecodeEngine:
             return jax.tree_util.tree_unflatten(treedef, leaves)
 
         donate = (0,) if self._donate else ()
-        fn = self._build(jax.jit(run, donate_argnums=donate).lower(
-            self._cache_specs(draft)).compile)
+        fn = self._build(jax.jit(
+            self._named(run, "kv_quant_chaos"),
+            donate_argnums=donate).lower(
+                self._cache_specs(draft)).compile)
         with self._exec_lock:
             if getattr(self, attr) is None:
                 setattr(self, attr, fn)
@@ -1921,7 +1959,8 @@ class DecodeEngine:
         specs = (self._cache_specs(), sds((), np.int32),
                  sds((), np.int32), sds((), np.int32))
         fn = self._build(
-            jax.jit(run, donate_argnums=donate).lower(*specs).compile)
+            jax.jit(self._named(run, "kv_copy"),
+                    donate_argnums=donate).lower(*specs).compile)
         with self._exec_lock:
             if self._copy_exec is None:
                 self._copy_exec = fn
@@ -1975,7 +2014,8 @@ class DecodeEngine:
             self._lora_specs(draft=True),
             jax.ShapeDtypeStruct((1,), np.int32),
         )
-        return jax.jit(run, donate_argnums=donate).lower(*specs).compile()
+        return jax.jit(self._named(run, f"draft_prefill_{P}"),
+                       donate_argnums=donate).lower(*specs).compile()
 
     def _spec_step(self):
         with self._exec_lock:
@@ -2240,7 +2280,8 @@ class DecodeEngine:
             self._lora_specs(draft=True),
             sds((B,), np.int32),      # adapter ids
         )
-        return jax.jit(run, donate_argnums=donate).lower(*specs).compile()
+        return jax.jit(self._named(run, "spec_step"),
+                       donate_argnums=donate).lower(*specs).compile()
 
     def warm(self, buckets: Optional[Sequence[int]] = None) -> int:
         """Compile the hot step (the decode chunk, or the fused
@@ -2725,7 +2766,8 @@ class DecodeEngine:
 
         sds = jax.ShapeDtypeStruct
         specs = (self._cache_specs(), sds((), np.int32))
-        fn = self._build(jax.jit(run).lower(*specs).compile)
+        fn = self._build(jax.jit(
+            self._named(run, "kv_gather")).lower(*specs).compile)
         with self._exec_lock:
             if self._gather_exec is None:
                 self._gather_exec = fn
@@ -2751,7 +2793,8 @@ class DecodeEngine:
         specs = (self._cache_specs(), self._row_specs(),
                  sds((), np.int32))
         fn = self._build(
-            jax.jit(run, donate_argnums=donate).lower(*specs).compile)
+            jax.jit(self._named(run, "kv_scatter"),
+                    donate_argnums=donate).lower(*specs).compile)
         with self._exec_lock:
             if self._scatter_exec is None:
                 self._scatter_exec = fn
@@ -3376,65 +3419,142 @@ class DecodeEngine:
                 if self._stopped:
                     return
             try:
-                # KV-transfer control jobs first (export snapshots,
-                # import installs): they are slot-state surgery and
-                # must see a quiesced iteration boundary, exactly like
-                # admission.
-                self._service_control()
-                # Replica-side scale-to-zero: models idle past
-                # model_idle_s leave their weight slots at the
-                # iteration boundary (the timed park above keeps the
-                # sweep ticking on a fully-idle replica; the operator
-                # can also push :evict explicitly).
-                self._maybe_evict_idle()
-                # Decode-stall accounting: prefill dispatch time (a
-                # monolithic admission's, or this iteration's one
-                # prompt chunk) is observed as stall only when active
-                # decode slots existed to be stalled by it.
-                self._iter_stall = 0.0
-                had_active = bool(self._active.any())
-                self._admit_ready()
-                if self._active_count():
-                    self._maybe_wedge()
-                    # At most ONE prompt-chunk dispatch per iteration:
-                    # the chunked-prefill head-of-line bound.
-                    self._advance_prefill()
-                    if had_active and self._iter_stall > 0:
-                        self._reg().histogram(
-                            "kfx_lm_decode_stall_seconds",
-                            "Seconds active decode slots waited on a "
-                            "prefill dispatch, per engine iteration.",
-                            buckets=QUEUE_WAIT_BUCKETS).observe(
-                                self._iter_stall, model=self.name)
-                        # Attribute the stall to every active request
-                        # that waited through it — the ``stalled_s``
-                        # leg of the flight-recorder breakdown.
-                        if self.flight is not None:
-                            for slot, r in enumerate(self._slots):
-                                if r is not None and self._active[slot]:
-                                    r.stall_s += self._iter_stall
-                    if self.role == "prefill" \
-                            and self._peer_send is not None:
-                        # Disaggregation: ship every freshly-prefilled
-                        # slot's pages toward a decode peer BEFORE this
-                        # iteration's decode step — a successful
-                        # handoff never decodes a token here.
-                        self._handoff_ready()
-                    if bool(self._active.any()):
-                        self._decode_once()
-                if self.flight is not None:
-                    self._record_flight()
-                # The progress heartbeat: one completed iteration. A
-                # loop stuck inside a dispatch (or the wedge stall
-                # above) never reaches this line, so /healthz sees the
-                # timestamp go stale while slots are active.
-                self._iterations += 1
-                self._last_progress = time.monotonic()
+                with self._iteration():
+                    self._iterate()
             except Exception as e:     # a broken dispatch fails the
                 self._fail_inflight(e)  # requests, never the engine;
                 time.sleep(0.01)        # KeyboardInterrupt/SystemExit
                 #                         propagate (they are shutdown,
                 #                         not request failures)
+
+    def _iterate(self) -> None:
+        """One iteration of the loop, between two parks on the
+        condition variable. The loop thread marks what it is doing
+        phase by phase (``_phase``): control, admit, prefill.enqueue,
+        decode.enqueue, device_wait, deliver, bookkeeping."""
+        with self._phase("engine.control"):
+            # KV-transfer control jobs first (export snapshots,
+            # import installs): they are slot-state surgery and
+            # must see a quiesced iteration boundary, exactly like
+            # admission.
+            self._service_control()
+            # Replica-side scale-to-zero: models idle past
+            # model_idle_s leave their weight slots at the
+            # iteration boundary (the timed park in _loop keeps the
+            # sweep ticking on a fully-idle replica; the operator
+            # can also push :evict explicitly).
+            self._maybe_evict_idle()
+        # Decode-stall accounting: prefill dispatch time (a
+        # monolithic admission's, or this iteration's one
+        # prompt chunk) is observed as stall only when active
+        # decode slots existed to be stalled by it. On a backend
+        # that dispatches asynchronously (the TPU) that time is the
+        # ENQUEUE: the prefill's device time is paid inside the next
+        # decode chunk's engine.device_wait.
+        self._iter_stall = 0.0
+        had_active = bool(self._active.any())
+        with self._phase("engine.admit"):
+            self._admit_ready()
+        if self._active_count():
+            self._maybe_wedge()
+            # At most ONE prompt-chunk dispatch per iteration:
+            # the chunked-prefill head-of-line bound.
+            self._advance_prefill()
+            if had_active and self._iter_stall > 0:
+                with self._phase("engine.bookkeeping"):
+                    self._reg().histogram(
+                        "kfx_lm_decode_stall_seconds",
+                        "Seconds active decode slots waited on a "
+                        "prefill dispatch, per engine iteration (on "
+                        "an asynchronous backend: on its enqueue).",
+                        buckets=QUEUE_WAIT_BUCKETS).observe(
+                            self._iter_stall, model=self.name)
+                    # Attribute the stall to every active request
+                    # that waited through it — the ``stalled_s``
+                    # leg of the flight-recorder breakdown.
+                    if self.flight is not None:
+                        for slot, r in enumerate(self._slots):
+                            if r is not None and self._active[slot]:
+                                r.stall_s += self._iter_stall
+            if self.role == "prefill" \
+                    and self._peer_send is not None:
+                # Disaggregation: ship every freshly-prefilled
+                # slot's pages toward a decode peer BEFORE this
+                # iteration's decode step — a successful
+                # handoff never decodes a token here.
+                self._handoff_ready()
+            if bool(self._active.any()):
+                self._decode_once()
+        with self._phase("engine.bookkeeping"):
+            if self.flight is not None:
+                self._record_flight()
+            # The progress heartbeat: one completed iteration. A
+            # loop stuck inside a dispatch (or the wedge stall
+            # above) never reaches this line, so /healthz sees the
+            # timestamp go stale while slots are active.
+            self._iterations += 1
+            self._last_progress = time.monotonic()
+
+    @contextlib.contextmanager
+    def _iteration(self):
+        """One ``engine.iteration`` annotation round an iteration of
+        the loop (its number, the decoding and the prefilling slots as
+        it starts), and at its end the flush of the iteration's host
+        seconds by phase. Host time of the iteration under no named
+        phase (page budgeting, chaos draws) is phase ``other``, so the
+        family's sum is the loop thread's busy host time; the time it
+        blocked on the device is counted apart, and time parked on the
+        condition variable with nothing to do is in neither."""
+        acc = self._phase_s
+        acc.clear()
+        del self._phase_stack[:]
+        try:
+            with self._phase("engine.iteration", "other",
+                             iteration=self._iterations,
+                             active=int(self._active.sum()),
+                             prefilling=len(self._prefilling)):
+                yield
+        finally:
+            reg = self._reg()
+            wait = acc.pop("device_wait", 0.0)
+            host = reg.counter(
+                "kfx_lm_engine_host_seconds_total",
+                "Host seconds of the engine's loop thread by phase of "
+                "the iteration (device waits and idle parking "
+                "excluded).")
+            for phase, seconds in acc.items():
+                host.inc(seconds, model=self.name, phase=phase)
+            reg.counter(
+                "kfx_lm_engine_device_wait_seconds_total",
+                "Seconds the engine's loop thread blocked on the first "
+                "host read of a dispatch's outputs.").inc(
+                    wait, model=self.name)
+            reg.counter(
+                "kfx_lm_engine_iterations_total",
+                "Iterations of the engine's loop.").inc(
+                    1, model=self.name)
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, key: str = "", **attrs):
+        """Mark a phase of the iteration on the loop thread: a profiler
+        annotation ``name`` (obs/trace.py's bridge) and, from the same
+        two clock reads, its seconds summed under ``key`` (``name``
+        less its ``engine.`` by default). Exclusive: while a nested
+        phase is open the one around it does not accrue."""
+        stack, acc = self._phase_stack, self._phase_s
+        key = key or name[len("engine."):]
+        with obs_trace.annotate(name, **attrs):
+            now = time.perf_counter()
+            if stack:
+                acc[stack[-1][0]] += now - stack[-1][1]
+            stack.append([key, now])
+            try:
+                yield
+            finally:
+                now = time.perf_counter()
+                acc[key] += now - stack.pop()[1]
+                if stack:
+                    stack[-1][1] = now
 
     def _maybe_evict_idle(self) -> None:
         """The weight pool's idle sweep (loop thread, iteration
@@ -3531,7 +3651,8 @@ class DecodeEngine:
                 self._admitting = None
             if requeued:
                 break
-        self._touch_gauges()
+        with self._phase("engine.bookkeeping"):
+            self._touch_gauges()
 
     def _resolve_adapter(self, req: Request) -> int:
         """The request's adapter id for this admission: acquire (and
@@ -3728,10 +3849,16 @@ class DecodeEngine:
         tokens = np.zeros((1, P), np.int32)
         tokens[0, :len(tail)] = tail
         t_dispatch = time.monotonic()
+        # The span times the ENQUEUE of the prefill: on a backend that
+        # dispatches asynchronously (the TPU) the call returns at once
+        # and the program's device time is in the trace under its own
+        # name (kfx_prefill_<P>), paid by the host at the next
+        # engine.device_wait.
         with obs_trace.span("engine.admit", trace_id=req.trace_id,
                             parent_id=req.span_id, model=self.name,
                             slot=str(slot), bucket=str(bucket),
-                            prefix_tokens=str(matched)):
+                            prefix_tokens=str(matched)), \
+                self._phase("engine.prefill.enqueue"):
             try:
                 if cow is not None:
                     self._cache = cfn(self._cache,
@@ -3758,6 +3885,7 @@ class DecodeEngine:
         # A monolithic prefill is decode stall for every active slot —
         # the head-of-line blocking the chunked path exists to bound.
         self._iter_stall += time.monotonic() - t_dispatch
+        self._stamp_prefill(req)
         if cow is not None:
             # The COW source's pin was only for the copy window; the
             # slot keeps the private clone, not the source.
@@ -3869,6 +3997,7 @@ class DecodeEngine:
             return False
         req.counted = True
         req.t_admitted = time.monotonic()
+        req.it_admitted = self._iterations
         if self.flight is not None:
             self.flight.event(req, "admit", matched=matched, prompt=n)
         wait = req.t_admitted - req.t_enqueue
@@ -4062,11 +4191,13 @@ class DecodeEngine:
         tokens = np.zeros((1, P), np.int32)
         tokens[0, :length] = cur["full"][start:start + length]
         t_dispatch = time.monotonic()
+        # Like engine.admit, the span times the chunk's ENQUEUE.
         with obs_trace.span("engine.prefill_chunk",
                             trace_id=req.trace_id,
                             parent_id=req.span_id, model=self.name,
                             slot=str(slot), start=str(start),
-                            tokens=str(length)):
+                            tokens=str(length)), \
+                self._phase("engine.prefill.enqueue"):
             try:
                 self._cache, self._logbuf = fn(
                     self._params_for(slot), self._cache, self._logbuf,
@@ -4083,6 +4214,7 @@ class DecodeEngine:
                     self._abort_prefill(slot, e)
                 return
         self._iter_stall += time.monotonic() - t_dispatch
+        self._stamp_prefill(req)
         self._reg().counter(
             "kfx_lm_prefill_chunks_total",
             "Prompt-chunk prefill dispatches (chunked admission).").inc(
@@ -4094,6 +4226,14 @@ class DecodeEngine:
         self._register_prefix_pages(slot, cur, final=last)
         if last:
             self._finish_prefill(slot)
+
+    def _stamp_prefill(self, req: Request) -> None:
+        """A prompt dispatch of ``req`` was just enqueued: until its
+        first token lands, that is the end of its prefill span (the
+        TTFT split of the flight recorder's ``timing``)."""
+        if req.t_first == 0.0:
+            req.t_prefill_end = time.monotonic()
+            req.prefill_iters = self._iterations - req.it_admitted + 1
 
     def _late_prefix_match(self, slot: int, cur: Dict[str, Any]
                            ) -> bool:
@@ -4396,15 +4536,17 @@ class DecodeEngine:
                  if r is not None and self._active[s]
                  and self._pending[s] < 0]
         if fresh:
-            logbuf = np.asarray(self._logbuf)
+            with self._phase("engine.device_wait"):
+                logbuf = np.asarray(self._logbuf)  # waits on the prefill
             emitted0 = 0
-            for s in fresh:
-                req = self._slots[s]
-                tok, self._rngs[s] = self._sample_host(
-                    logbuf[s], req, self._rngs[s])
-                emitted0 += self._emit_host(s, [tok])
-                if self._slots[s] is not None:
-                    self._pending[s] = tok
+            with self._phase("engine.deliver"):
+                for s in fresh:
+                    req = self._slots[s]
+                    tok, self._rngs[s] = self._sample_host(
+                        logbuf[s], req, self._rngs[s])
+                    emitted0 += self._emit_host(s, [tok])
+                    if self._slots[s] is not None:
+                        self._pending[s] = tok
             if emitted0:
                 self._reg().counter(
                     "kfx_lm_generated_tokens_total",
@@ -4438,20 +4580,23 @@ class DecodeEngine:
         with obs_trace.span("engine.verify", trace_id=oldest.trace_id,
                             parent_id=oldest.span_id, model=self.name,
                             slots=str(n_active), k=str(k)) as sp:
-            out = self._spec_step()(
-                self.params, self.draft_params, self._cache,
-                self._draft_cache, np.ascontiguousarray(self._tables),
-                np.ascontiguousarray(self._draft_tables),
-                self._pending, self._pos, self._loc, self._max_loc,
-                spec_on, draft_live, self._active, self._rngs,
-                self._temp, self._topk, self._lora_tree(),
-                self._lora_tree(draft=True),
-                np.ascontiguousarray(self._aids))
+            with self._phase("engine.decode.enqueue"):
+                out = self._spec_step()(
+                    self.params, self.draft_params, self._cache,
+                    self._draft_cache,
+                    np.ascontiguousarray(self._tables),
+                    np.ascontiguousarray(self._draft_tables),
+                    self._pending, self._pos, self._loc, self._max_loc,
+                    spec_on, draft_live, self._active, self._rngs,
+                    self._temp, self._topk, self._lora_tree(),
+                    self._lora_tree(draft=True),
+                    np.ascontiguousarray(self._aids))
             (self._cache, self._draft_cache, rngs, D, A, bonus) = out
-            D = np.asarray(D)          # [B, k]
-            A = np.asarray(A)          # [B]
-            bonus = np.asarray(bonus)  # [B]
-            self._rngs = np.array(rngs)
+            with self._phase("engine.device_wait"):
+                D = np.asarray(D)          # [B, k]
+                A = np.asarray(A)          # [B]
+                bonus = np.asarray(bonus)  # [B]
+                self._rngs = np.array(rngs)
             sp.attrs["accepted"] = str(int(
                 sum(int(A[s]) for s in range(self.n_slots)
                     if spec_on[s])))
@@ -4464,43 +4609,49 @@ class DecodeEngine:
         proposed = int(np.sum(spec_on))
         accepted = 0
         emitted = 0
-        for slot in range(self.n_slots):
-            req = self._slots[slot]
-            if req is None or not self._active[slot]:
-                continue
-            a = int(A[slot])
-            if spec_on[slot]:
-                accepted += a
-                # Per-request speculation attribution (spec_accept in
-                # the flight-recorder breakdown).
-                req.spec_prop += k
-                req.spec_acc += a
-            toks = [int(t) for t in D[slot, :a]] + [int(bonus[slot])]
-            landed = self._emit_host(slot, toks)
-            emitted += landed
-            if self._slots[slot] is not None:
-                # Cursor advance = pending + accepted proposals now in
-                # both pools; the bonus becomes the new pending token.
-                self._pos[slot] += a + 1
-                self._loc[slot] += a + 1
-                self._pending[slot] = int(bonus[slot])
-        if proposed:
-            self._spec_proposed += proposed * k
-            self._spec_accepted += accepted
-            with self._spec_lock:
-                self._spec_window.append(
-                    (time.monotonic(), proposed * k, accepted))
-            reg.counter("kfx_lm_spec_proposed_total",
-                        "Draft tokens proposed to the verify dispatch."
-                        ).inc(proposed * k, model=self.name)
-            reg.counter("kfx_lm_spec_accepted_total",
-                        "Draft proposals the target model accepted."
-                        ).inc(accepted, model=self.name)
-        if emitted:
-            reg.counter("kfx_lm_generated_tokens_total",
-                        "Tokens generated since startup.").inc(
-                            emitted, model=self.name)
-        self._touch_gauges()
+        with self._phase("engine.deliver"):
+            for slot in range(self.n_slots):
+                req = self._slots[slot]
+                if req is None or not self._active[slot]:
+                    continue
+                a = int(A[slot])
+                if spec_on[slot]:
+                    accepted += a
+                    # Per-request speculation attribution (spec_accept
+                    # in the flight-recorder breakdown).
+                    req.spec_prop += k
+                    req.spec_acc += a
+                toks = [int(t) for t in D[slot, :a]] \
+                    + [int(bonus[slot])]
+                landed = self._emit_host(slot, toks)
+                emitted += landed
+                if self._slots[slot] is not None:
+                    # Cursor advance = pending + accepted proposals now
+                    # in both pools; the bonus becomes the new pending
+                    # token.
+                    self._pos[slot] += a + 1
+                    self._loc[slot] += a + 1
+                    self._pending[slot] = int(bonus[slot])
+        with self._phase("engine.bookkeeping"):
+            if proposed:
+                self._spec_proposed += proposed * k
+                self._spec_accepted += accepted
+                with self._spec_lock:
+                    self._spec_window.append(
+                        (time.monotonic(), proposed * k, accepted))
+                reg.counter(
+                    "kfx_lm_spec_proposed_total",
+                    "Draft tokens proposed to the verify dispatch."
+                    ).inc(proposed * k, model=self.name)
+                reg.counter(
+                    "kfx_lm_spec_accepted_total",
+                    "Draft proposals the target model accepted."
+                    ).inc(accepted, model=self.name)
+            if emitted:
+                reg.counter("kfx_lm_generated_tokens_total",
+                            "Tokens generated since startup.").inc(
+                                emitted, model=self.name)
+            self._touch_gauges()
 
     def _decode_once(self) -> None:
         if self.spec:
@@ -4517,57 +4668,64 @@ class DecodeEngine:
                             slots=str(n_active),
                             k=str(self.chunk_tokens)):
             if self._wpool is None:
-                out = self._decode()(
-                    self.params, self._cache, self._logbuf,
-                    np.ascontiguousarray(self._tables), self._pos,
-                    self._loc, self._active, self._produced,
-                    self._rngs, self._temp, self._topk, self._stop,
-                    self._max_new, self._lora_tree(),
-                    np.ascontiguousarray(self._aids))
+                with self._phase("engine.decode.enqueue"):
+                    out = self._decode()(
+                        self.params, self._cache, self._logbuf,
+                        np.ascontiguousarray(self._tables), self._pos,
+                        self._loc, self._active, self._produced,
+                        self._rngs, self._temp, self._topk, self._stop,
+                        self._max_new, self._lora_tree(),
+                        np.ascontiguousarray(self._aids))
                 (self._cache, self._logbuf, pos, loc, active,
                  produced, rngs, toks, emits) = out
-                # np.array (copy): admission mutates these rows in
-                # place, and a bare asarray of a jax output is a
-                # read-only view.
-                self._pos = np.array(pos)
-                self._loc = np.array(loc)
-                self._active = np.array(active)
-                self._produced = np.array(produced)
-                self._rngs = np.array(rngs)
-                toks = np.asarray(toks)    # [k, B]
-                emits = np.asarray(emits)  # [k, B] bool
+                # The first host read blocks until the chunk (and any
+                # prefill enqueued before it) has run on the device.
+                with self._phase("engine.device_wait"):
+                    # np.array (copy): admission mutates these rows in
+                    # place, and a bare asarray of a jax output is a
+                    # read-only view.
+                    self._pos = np.array(pos)
+                    self._loc = np.array(loc)
+                    self._active = np.array(active)
+                    self._produced = np.array(produced)
+                    self._rngs = np.array(rngs)
+                    toks = np.asarray(toks)    # [k, B]
+                    emits = np.asarray(emits)  # [k, B] bool
             else:
                 toks, emits = self._decode_grouped()
         reg = self._reg()
         reg.counter("kfx_lm_engine_chunks_total",
                     "Decode-chunk dispatches.").inc(1, model=self.name)
         emitted = 0
-        for slot, req in enumerate(self._slots):
-            if req is None or slot in self._prefilling:
-                # A mid-prefill slot rides the dispatch fully masked:
-                # inactive by design, not retired — finishing it here
-                # would return an empty completion.
-                continue
-            hits = np.flatnonzero(emits[:, slot])
-            fresh = [int(t) for t in toks[hits, slot]]
-            req.tokens.extend(fresh)
-            if req.on_token is not None:
-                for t in fresh:
-                    req._notify(t)
-            emitted += len(hits)
-            if len(hits) and req.t_first == 0.0:
-                req.t_first = time.monotonic()
-                if self.flight is not None:
-                    self.flight.event(req, "first_token")
-            if not self._active[slot]:
-                self._slots[slot] = None
-                self._release_slot(slot)
-                req._finish()
-        if emitted:
-            reg.counter("kfx_lm_generated_tokens_total",
-                        "Tokens generated since startup.").inc(
-                            emitted, model=self.name)
-        self._touch_gauges()
+        with self._phase("engine.deliver"):
+            for slot, req in enumerate(self._slots):
+                if req is None or slot in self._prefilling:
+                    # A mid-prefill slot rides the dispatch fully
+                    # masked: inactive by design, not retired —
+                    # finishing it here would return an empty
+                    # completion.
+                    continue
+                hits = np.flatnonzero(emits[:, slot])
+                fresh = [int(t) for t in toks[hits, slot]]
+                req.tokens.extend(fresh)
+                if req.on_token is not None:
+                    for t in fresh:
+                        req._notify(t)
+                emitted += len(hits)
+                if len(hits) and req.t_first == 0.0:
+                    req.t_first = time.monotonic()
+                    if self.flight is not None:
+                        self.flight.event(req, "first_token")
+                if not self._active[slot]:
+                    self._slots[slot] = None
+                    self._release_slot(slot)
+                    req._finish()
+        with self._phase("engine.bookkeeping"):
+            if emitted:
+                reg.counter("kfx_lm_generated_tokens_total",
+                            "Tokens generated since startup.").inc(
+                                emitted, model=self.name)
+            self._touch_gauges()
 
     def _decode_grouped(self):
         """One decode chunk across every active slot, in WEIGHT-POOL
@@ -4597,16 +4755,19 @@ class DecodeEngine:
                              np.bool_)
         for wid in wids:
             gmask = np.asarray(self._active & (self._wids == wid))
-            out = fn(
-                self._wpool.tree(wid), self._cache, self._logbuf,
-                np.ascontiguousarray(self._tables), self._pos,
-                self._loc, gmask, self._produced, self._rngs,
-                self._temp, self._topk, self._stop, self._max_new,
-                self._lora_tree(), np.ascontiguousarray(self._aids))
+            with self._phase("engine.decode.enqueue"):
+                out = fn(
+                    self._wpool.tree(wid), self._cache, self._logbuf,
+                    np.ascontiguousarray(self._tables), self._pos,
+                    self._loc, gmask, self._produced, self._rngs,
+                    self._temp, self._topk, self._stop, self._max_new,
+                    self._lora_tree(),
+                    np.ascontiguousarray(self._aids))
             (self._cache, self._logbuf, pos, loc, active, produced,
              rngs, toks, emits) = out
-            toks = np.asarray(toks)
-            emits = np.asarray(emits)
+            with self._phase("engine.device_wait"):
+                toks = np.asarray(toks)
+                emits = np.asarray(emits)
             # np.where allocates fresh writable arrays, preserving
             # the copy-before-mutation contract of the single-model
             # path.

@@ -39,6 +39,7 @@ import logging
 import os
 import queue
 import socket
+import sys
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -460,6 +461,35 @@ class ModelServer:
         # (kfx_spans_recorded_total) — proof request tracing is flowing.
         chaos.collect(reg)
         obs_trace.collect(reg)
+        self._collect_device_memory(reg)
+
+    def _collect_device_memory(self, reg: MetricsRegistry) -> None:
+        """Pull-time ``kfx_device_memory_bytes{kind=in_use|peak|limit}``
+        from the first local device's ``memory_stats()``: what the
+        replica holds on its chip (weights, KV pools, XLA's
+        temporaries), read when /metrics is scraped. Absent until a
+        model is ready (the scrape must not be what starts the
+        backend) and where the backend reports no statistics (the
+        CPU)."""
+        jax = sys.modules.get("jax")
+        if jax is None or not any(p.ready
+                                  for p in self.predictors.values()):
+            return
+        try:
+            stats = jax.local_devices()[0].memory_stats()
+        except RuntimeError:    # a backend without the query
+            return
+        if not stats:
+            return
+        gauge = reg.gauge(
+            "kfx_device_memory_bytes",
+            "Device memory of the replica's first local device, from "
+            "the backend's memory_stats().")
+        for kind, key in (("in_use", "bytes_in_use"),
+                          ("peak", "peak_bytes_in_use"),
+                          ("limit", "bytes_limit")):
+            if key in stats:
+                gauge.set(int(stats[key]), kind=kind)
 
     def _latency_summary(self) -> Dict[str, Dict[str, Optional[float]]]:
         """Server-reported per-model p50/p99 (ms) from the request

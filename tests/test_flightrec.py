@@ -53,6 +53,8 @@ class _FakeReq:
         self.t_admitted = 100.5
         self.t_first = 101.5
         self.t_done = 102.0
+        self.t_prefill_end = 100.9
+        self.prefill_iters = 2
         for k, v in kw.items():
             setattr(self, k, v)
 
@@ -110,6 +112,28 @@ class TestFlightRecorderUnit:
         assert t["spec_accept"] == pytest.approx(0.7)
         # No speculation -> None, never a divide-by-zero.
         assert FlightRecorder.timing(_FakeReq())["spec_accept"] is None
+
+    @pytest.mark.parametrize("stamps, span, wait, iters", [
+        # the last prompt chunk enqueued 0.4 s after admission
+        ({}, 0.4, 0.6, 2),
+        # never prefilled here (a KV import): the whole leg is the wait
+        ({"t_prefill_end": 0.0, "prefill_iters": 0}, 0.0, 1.0, 0),
+        # a stamp past the first token (a recompute after a preempt
+        # must not have moved it, but a clock edge could): clamped
+        ({"t_prefill_end": 101.7}, 1.0, 0.0, 2),
+        # third decimals that do not add up in binary
+        ({"t_admitted": 100.1000004, "t_prefill_end": 100.4000003,
+          "t_first": 101.2000007}, 0.3, 0.8, 2),
+    ], ids=["split", "imported", "clamped", "rounding"])
+    def test_timing_splits_prefill_where_the_engine_spends_it(
+            self, stamps, span, wait, iters):
+        t = FlightRecorder.timing(_FakeReq(**stamps))
+        assert t["prefill_span_s"] == pytest.approx(span, abs=2e-6)
+        assert t["first_token_wait_s"] == pytest.approx(wait, abs=2e-6)
+        assert t["prefill_iterations"] == iters
+        assert t["prefill_span_s"] + t["first_token_wait_s"] == \
+            pytest.approx(t["prefill_s"], abs=1e-9)
+        assert t["prefill_span_s"] >= 0 and t["first_token_wait_s"] >= 0
 
     def test_env_knobs(self, monkeypatch):
         monkeypatch.setenv("KFX_FLIGHT", "0")
@@ -197,6 +221,42 @@ class TestEngineFlight:
         t = last["timing"]
         assert t["queue_wait_s"] >= 0 and t["prefill_s"] > 0
         assert last["tokens"] == 6 and last["error"] is None
+
+    @pytest.mark.parametrize("prompt_len, chunks", [(40, 3), (5, 1)],
+                             ids=["three-chunks", "monolithic"])
+    def test_timing_counts_the_prompts_iterations(self, engine,
+                                                  prompt_len, chunks):
+        """40 tokens over 16-token chunks: admitted and first chunk in
+        one iteration, then one chunk an iteration; 5 tokens: one
+        monolithic dispatch. The split adds up to ``prefill_s`` and
+        rides the recent-requests ring (/debug/requests, the stream's
+        ``done`` event)."""
+        # (tokens no other test of this class sends: a prefix-cache
+        # hit would shorten the prompt's tail)
+        prompt = [(i * 7 + 3) % 61 + 2 for i in range(prompt_len)]
+        engine.generate([prompt], max_new_tokens=6)
+        t = engine.flight.requests()["requests"][-1]["timing"]
+        assert t["prefill_iterations"] == chunks
+        assert t["prefill_span_s"] + t["first_token_wait_s"] == \
+            pytest.approx(t["prefill_s"], abs=1e-6)
+        assert t["first_token_wait_s"] > 0 and t["prefill_span_s"] >= 0
+        if chunks > 1:
+            assert t["prefill_span_s"] > 0
+
+    def test_timing_split_survives_a_cut_event_trail(self, engine):
+        """The stamps live on the request: a trail already at
+        MAX_EVENTS when the prompt starts still gets its split."""
+        prompt = [(i * 11 + 5) % 59 + 2 for i in range(40)]
+        with engine._cond:   # the loop cannot admit it before this
+            req = engine.submit(prompt, max_new_tokens=4)
+            req.events.extend({"ev": "filler", "ts": 0.0}
+                              for _ in range(MAX_EVENTS))
+        assert len(req.result(60)) == 4
+        assert any(e["ev"] == "dropped" for e in req.events)
+        t = engine.flight.timing(req)
+        assert t["prefill_iterations"] >= 1
+        assert t["prefill_span_s"] + t["first_token_wait_s"] == \
+            pytest.approx(t["prefill_s"], abs=1e-6)
 
     def test_kfx_flight_0_disables_recorder(self, tiny_lm, monkeypatch):
         from kubeflow_tpu.serving.engine import DecodeEngine

@@ -269,6 +269,33 @@ class TestModelServer:
         status, body = _get(f"{base}/metrics?format=json")
         assert status == 200 and body["models"] == ["mnist"]
 
+    @pytest.mark.parametrize("stats, lines", [
+        # the CPU backend reports none: the family is absent
+        (None, []),
+        ({"bytes_in_use": 7, "peak_bytes_in_use": 9, "bytes_limit": 16,
+          "num_allocs": 3},
+         ['kfx_device_memory_bytes{kind="in_use"} 7',
+          'kfx_device_memory_bytes{kind="peak"} 9',
+          'kfx_device_memory_bytes{kind="limit"} 16']),
+        # a backend that reports only part of them
+        ({"bytes_in_use": 5},
+         ['kfx_device_memory_bytes{kind="in_use"} 5']),
+    ], ids=["none", "all", "partial"])
+    def test_device_memory_gauge_is_read_at_scrape_time(
+            self, server, monkeypatch, stats, lines):
+        import jax
+
+        class _Device:
+            def memory_stats(self):
+                return stats
+
+        monkeypatch.setattr(jax, "local_devices", lambda: [_Device()])
+        server.metrics.gauge("kfx_device_memory_bytes", "").clear()
+        text = server.metrics.render()
+        got = [l for l in text.splitlines()
+               if l.startswith("kfx_device_memory_bytes")]
+        assert sorted(got) == sorted(lines)
+
 
 class TestMicroBatcher:
     def test_concurrent_requests_batched(self, export_dir):

@@ -54,9 +54,9 @@ SHAPES = {
 }
 
 
-@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
-@pytest.mark.parametrize("direction", ["forward", "backward"])
-def test_flash_kernels_compile_for_v5e(one_chip, shape, direction):
+def _compile(one_chip, shape, direction):
+    """The flash kernels of one direction, compiled for the described
+    chip: (executable, how many kernels it holds)."""
     from kubeflow_tpu.ops import flash_attention as fa
 
     B, H, S, D = shape
@@ -65,14 +65,35 @@ def test_flash_kernels_compile_for_v5e(one_chip, shape, direction):
     row = jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32, sharding=one_chip)
     if direction == "forward":
         fn = lambda q, k, v: fa._fwd(q, k, v, block_q=bq, block_k=bk)
-        compiled = jax.jit(fn).lower(x, x, x).compile()
-        kernels = 1
-    else:  # dq and dkv are two kernels of one backward
-        fn = lambda q, k, v, o, lse, do: fa._bwd(
-            bq, bk, (q, k, v, o, lse), do)
-        compiled = jax.jit(fn).lower(x, x, x, x, row, x).compile()
-        kernels = 2
+        return jax.jit(fn).lower(x, x, x).compile(), 1
+    # dq and dkv are two kernels of one backward
+    fn = lambda q, k, v, o, lse, do: fa._bwd(
+        bq, bk, (q, k, v, o, lse), do)
+    return jax.jit(fn).lower(x, x, x, x, row, x).compile(), 2
+
+
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_flash_kernels_compile_for_v5e(one_chip, shape, direction):
+    compiled, kernels = _compile(one_chip, shape, direction)
     assert compiled.as_text().count("tpu_custom_call") == kernels
+
+
+@pytest.mark.parametrize("kernel, direction", [
+    ("kfx_flash_fwd", "forward"), ("kfx_flash_dq", "backward"),
+    ("kfx_flash_dkv", "backward")])
+def test_flash_kernels_carry_their_names_in_the_compiled_hlo(
+        one_chip, kernel, direction):
+    """What a profiler trace shows of a kernel is its instruction's
+    text: the name given to ``pl.pallas_call`` is the instruction's own
+    name and a scope of its ``op_name``, which is how the benchmark's
+    reader finds each kernel (not by operand count)."""
+    text = _compile(one_chip, SHAPES["large-S2048"], direction)[0].as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and f"/{kernel}/" in line]
+    assert len(calls) == 1, text[-3000:]
+    assert calls[0].lstrip().startswith(f"%{kernel}")
 
 
 def test_libtpu_registers_the_overlap_flags():
