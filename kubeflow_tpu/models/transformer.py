@@ -697,10 +697,22 @@ class Attention(nn.Module):
         rolls the cursor back and stamps the tail's position ids to
         -1, no page copies).
 
+        Which operand the paged layout streams is read off the shapes
+        (``attends_pool_in_place``): where the pool holds no more
+        slots than the batch's logical view (``B * L >= N * P``: every
+        decode and verify dispatch of an engine whose pool is no
+        larger than ``slots x blocks``), the queries are scored
+        against the pool where it lies, each row masked to the pages
+        its block table names, and no per-row view is built. Where the
+        pool is the larger (prefill, B = 1), each row's logical view
+        is gathered through its table. Same rule, same numbers up to
+        the order of the float32 sums.
+
         The parts carry ``jax.named_scope`` names — ``kv_write``,
-        ``kv_gather``, ``scores``, ``pv`` — under the module's own
-        ``attn`` scope (flax names every module call), so a profiler
-        trace or the compiled HLO attributes an op to its part by name
+        ``kv_gather`` (gathered form) or ``kv_member`` (in place),
+        ``scores``, ``pv`` — under the module's own ``attn`` scope
+        (flax names every module call), so a profiler trace or the
+        compiled HLO attributes an op to its part by name
         (``.../attn/attn._decode_attend/kv_gather/...``) and not by
         fusion number."""
         cfg = self.cfg
@@ -774,6 +786,22 @@ class Attention(nn.Module):
                         v.astype(cfg.dtype), mode="drop")
                 cpos.value = cpos.value.at[page, slot].set(
                     pos, mode="drop")
+            if attends_pool_in_place(B, L, N, P):
+                if int8_kv:
+                    # Dequant the pool where it lies: int8 entries x
+                    # the per-token scale plane, in f32, then the
+                    # compute dtype (what the gathered form does to
+                    # each row's view).
+                    with jax.named_scope("kv_dequant"):
+                        pk = (ck.value.astype(jnp.float32)
+                              * ksc.value[..., None, None]).astype(cfg.dtype)
+                        pv = (cv.value.astype(jnp.float32)
+                              * vsc.value[..., None, None]).astype(cfg.dtype)
+                else:
+                    pk, pv = ck.value, cv.value
+                return self._attend_pool(
+                    q, positions, block_tables, pk.reshape(N * P, H, D),
+                    pv.reshape(N * P, H, D), cpos.value)
             # Gather each row's logical view [L] through its table.
             # Unallocated blocks clamp to page 0 for K/V (their scores
             # are masked to exactly-0 probability via position -1, so
@@ -826,6 +854,47 @@ class Attention(nn.Module):
         with jax.named_scope("pv"):
             return jnp.einsum("bhqk,bkhd->bqhd",
                               probs.astype(cfg.dtype), gv)
+
+    def _attend_pool(self, q, positions, block_tables, pk, pv, kpos):
+        """Paged attention over the pool in place: ``pk``/``pv``
+        [N*P, H, D] are ALL of the pool's slots, ``kpos`` [N, P] their
+        cached position ids. A row sees a slot iff its page is in the
+        row's block table AND the slot's position id is live and not
+        ahead of the query — the gathered form's rule with membership
+        in the place of the gather. Membership is a [B, N] matrix, not
+        an owner per page (the prefix cache puts one page in several
+        rows' tables), and is not optional: a freed page keeps its
+        position ids until it is recycled, and other rows' pages are
+        live. Scores accumulate in float32; a fully masked row (an
+        inactive slot at position -1) stays finite."""
+        N, P = kpos.shape
+        with jax.named_scope("kv_member"):
+            pages = jnp.arange(N, dtype=block_tables.dtype)
+            member = (block_tables[:, :, None] == pages).any(1)  # [B, N]
+            kp = kpos.reshape(N * P)
+            qp = positions[:, None, :, None]                 # [B,1,S,1]
+            mask = (jnp.repeat(member, P, axis=1)[:, None, None, :]
+                    & (kp >= 0) & (kp <= qp))                # [B,1,S,NP]
+        with jax.named_scope("scores"):
+            scores = jnp.einsum("bqhd,khd->bhqk", q, pk,
+                                preferred_element_type=jnp.float32)
+            scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
+            probs = jax.nn.softmax(scores, -1)
+        with jax.named_scope("pv"):
+            return jnp.einsum("bhqk,khd->bqhd",
+                              probs.astype(self.cfg.dtype), pv)
+
+
+def attends_pool_in_place(batch: int, max_seq_len: int, kv_pages: int,
+                          page_size: int) -> bool:
+    """Whether paged decode attention scores the pool in place
+    (``kv_pages * page_size`` K/V positions a query row) or gathers
+    each row's logical view (``max_seq_len`` positions a row): in
+    place when the pool is no larger than the batch's logical view, so
+    the cheaper operand is the one streamed. Read off shapes that are
+    static when the program is traced; the engine reports the outcome
+    per program (``kfx_lm_attend_positions``)."""
+    return batch * max_seq_len >= kv_pages * page_size
 
 
 class DenseFFN(nn.Module):

@@ -1645,6 +1645,31 @@ class DecodeEngine:
         fn.__name__ = fn.__qualname__ = f"run_kfx_{what}"
         return fn
 
+    def _report_attend(self, program: str, batch: int,
+                       draft: bool = False) -> None:
+        """``kfx_lm_attend_positions{model,program}``: the K/V
+        positions every query row of a compiled program scores, set
+        when the program is built — the whole pool (``kv_pages x
+        page_size``) where the model attends it in place, the row's
+        gathered view (``max_seq_len``) where it gathers
+        (models/transformer.py ``attends_pool_in_place``; the choice
+        is per program, made from its shapes). Beside
+        ``kfx_lm_kv_pages_free`` it says when the in-place form pays
+        for an empty pool: its cost follows the pool's size, not the
+        live tokens."""
+        from ..models.transformer import attends_pool_in_place
+
+        cfg = self.draft_cfg if draft else self.cfg
+        in_place = attends_pool_in_place(
+            batch, cfg.max_seq_len, cfg.kv_pages, cfg.kv_page_size)
+        self._reg().gauge(
+            "kfx_lm_attend_positions",
+            "K/V positions a query row scores in a compiled program "
+            "(the pool's slots in place, max_seq_len gathered).").set(
+                cfg.kv_pages * cfg.kv_page_size if in_place
+                else cfg.max_seq_len,
+                model=self.name, program=program)
+
     def _build(self, build_fn, *args):
         """Run one AOT build under the ``_building`` marker so the
         liveness heartbeat can tell "slow: compiling" from "stuck".
@@ -1720,6 +1745,7 @@ class DecodeEngine:
             self._lora_specs(),
             jax.ShapeDtypeStruct((1,), np.int32),
         )
+        self._report_attend(f"prefill_{P}", 1)
         return jax.jit(self._named(run, f"prefill_{P}"),
                        donate_argnums=donate).lower(*specs).compile()
 
@@ -1820,6 +1846,7 @@ class DecodeEngine:
             self._lora_specs(),
             sds((B,), np.int32),      # adapter ids
         )
+        self._report_attend("decode_chunk", B)
         return jax.jit(self._named(run, "decode_chunk"),
                        donate_argnums=donate).lower(*specs).compile()
 
@@ -2014,6 +2041,7 @@ class DecodeEngine:
             self._lora_specs(draft=True),
             jax.ShapeDtypeStruct((1,), np.int32),
         )
+        self._report_attend(f"draft_prefill_{P}", 1, draft=True)
         return jax.jit(self._named(run, f"draft_prefill_{P}"),
                        donate_argnums=donate).lower(*specs).compile()
 
@@ -2280,6 +2308,8 @@ class DecodeEngine:
             self._lora_specs(draft=True),
             sds((B,), np.int32),      # adapter ids
         )
+        self._report_attend("spec_step", B)
+        self._report_attend("spec_step_draft", B, draft=True)
         return jax.jit(self._named(run, "spec_step"),
                        donate_argnums=donate).lower(*specs).compile()
 

@@ -407,6 +407,74 @@ class TestPagedKV:
 
 
 @pytest.fixture(scope="module")
+def small_pool_engine(tiny_lm):
+    """A pool smaller than its slots' logical view (8 pages of 16
+    against 4 slots x 64): the decode chunk attends the pool in place
+    under the page-membership mask, the prefill programs (one row: 64
+    < 128) gather — models/transformer.py ``attends_pool_in_place``."""
+    from kubeflow_tpu.obs.metrics import MetricsRegistry
+    from kubeflow_tpu.serving.engine import DecodeEngine
+
+    cfg, params = tiny_lm
+    eng = DecodeEngine(cfg, params, n_slots=4, chunk_tokens=4,
+                       name="lm-pool", kv_page_size=16, kv_pages=8,
+                       registry=MetricsRegistry())
+    yield eng
+    eng.close()
+
+
+class TestPoolInPlace:
+    """Greedy bytes of the in-place decode attention equal the one-shot
+    oracle's (float32, CPU), which has no pool to attend: other rows'
+    live pages, a page shared through the prefix cache and a recycled
+    page are each invisible to a row that does not hold them."""
+
+    def _oracle(self, tiny_lm, prompts, n):
+        from kubeflow_tpu.models.generate import LMGenerator
+
+        gen = LMGenerator(*tiny_lm)
+        return [gen.generate([p], max_new_tokens=n)[0] for p in prompts]
+
+    def test_mixed_lengths_over_several_chunks(self, tiny_lm,
+                                               small_pool_engine):
+        prompts = [[5, 9, 11, 3, 7], [2], list(range(1, 20)), [13, 14]]
+        out = small_pool_engine.generate(prompts, max_new_tokens=14)
+        assert out == self._oracle(tiny_lm, prompts, 14)
+        gauge = small_pool_engine._reg().gauge(
+            "kfx_lm_attend_positions", "")
+        assert gauge.value(model="lm-pool",
+                           program="decode_chunk") == 8 * 16
+        assert {v for lab, v in gauge.samples()
+                if lab["program"].startswith("prefill_")} == {64}
+
+    def test_a_prefix_cache_hit_shares_a_page_between_rows(
+            self, tiny_lm, small_pool_engine):
+        system = [(7 * i + 3) % 60 for i in range(36)]  # 2.25 pages
+        prompts = [system + [60 + i] for i in range(3)]
+        hits0 = small_pool_engine._prefix.hits
+        out = small_pool_engine.generate(prompts, max_new_tokens=10)
+        assert out == self._oracle(tiny_lm, prompts, 10)
+        assert small_pool_engine._prefix.hits - hits0 >= 2
+
+    def test_recycled_pages_leak_nothing(self, tiny_lm, small_pool_engine,
+                                         monkeypatch):
+        mgr, handed = small_pool_engine._mgr, []
+        alloc = mgr.alloc
+        monkeypatch.setattr(mgr, "alloc", lambda n: [
+            handed.append(p) or p for p in alloc(n)])
+        waves = [[[i + 1, i + 2, 40 + w] for i in range(4)]
+                 for w in range(3)]
+        waves.append([[(3 * i + w) % 60 for i in range(30)]
+                      for w in range(2)])
+        for prompts in waves:
+            out = small_pool_engine.generate(prompts, max_new_tokens=18)
+            assert out == self._oracle(tiny_lm, prompts, 18)
+        # More pages were handed out than the pool has: some came back.
+        assert len(handed) > mgr.n_pages
+        assert len(set(handed)) < len(handed)
+
+
+@pytest.fixture(scope="module")
 def chunked_engine(tiny_lm):
     """Module-scoped chunked-prefill engine: one-page (16-token)
     chunks over 16-token pages, so a 40-token prompt admits in 3
@@ -1054,6 +1122,19 @@ class TestSpeculative:
         assert out == [ref]
         st1 = eng.spec_stats()
         assert st1["accepted"] > st0["accepted"]  # speculating again
+
+    @pytest.mark.parametrize("program, positions", [
+        ("spec_step", 8 * 16), ("spec_step_draft", 8 * 16),
+        ("prefill_8", 64), ("draft_prefill_8", 64)])
+    def test_attend_positions_of_both_pools(self, spec_pool_engine,
+                                            program, positions):
+        """The fused step's verify window and draft steps (4 rows)
+        attend their 8-page pools in place; both models' prefill
+        programs (one row) gather ``max_seq_len``."""
+        spec_pool_engine.warm([8])
+        assert spec_pool_engine._reg().gauge(
+            "kfx_lm_attend_positions", "").value(
+                model="lm-sp", program=program) == positions
 
     def test_verify_span_and_metrics(self, spec_engine, tmp_path):
         """engine.verify lands in the span log under the submitting

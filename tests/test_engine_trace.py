@@ -159,15 +159,45 @@ def test_parked_time_is_in_no_counter(traced):
     assert reg_before == reg_after
 
 
+def _scoped(text, scope):
+    return any(scope in line for line in text.splitlines()
+               if "op_name=" in line)
+
+
 @pytest.mark.parametrize("scope", [
-    "/attn/", "/mlp/", "/lm_head/", "/kv_write/", "/kv_gather/",
+    "/attn/", "/mlp/", "/lm_head/", "/kv_write/", "/kv_member/",
     "/scores/", "/pv/", "/vmap(sample)/"])
 def test_the_decode_program_names_its_parts(traced, scope):
     """``op_name`` metadata of the compiled decode chunk: the module
     scopes flax gives (attn, mlp, lm_head) and the named scopes inside
     the decode attention and the sampler (the engine vmaps the sampler
-    over its slots, and a scope under ``vmap`` reads ``vmap(<scope>)``)."""
+    over its slots, and a scope under ``vmap`` reads ``vmap(<scope>)``).
+    The engine's default pool is its slots' logical view (4 x 64 = 16
+    x 16), so the chunk attends the pool in place: ``kv_member``, and
+    no gather."""
     text = traced["engine"]._decode().as_text()
     assert "jit(run_kfx_decode_chunk)" in text
-    assert any(scope in line for line in text.splitlines()
-               if "op_name=" in line)
+    assert _scoped(text, scope)
+    assert not _scoped(text, "/kv_gather/")
+
+
+@pytest.mark.parametrize("scope, there", [
+    ("/kv_gather/", True), ("/scores/", True), ("/pv/", True),
+    ("/kv_member/", False)])
+def test_a_prefill_program_still_gathers(traced, scope, there):
+    """One row's logical view (64) is smaller than the pool (256): a
+    prefill program gathers through its block table as it always did."""
+    text = traced["engine"]._prefill_for(16).as_text()
+    assert "jit(run_kfx_prefill_16)" in text
+    assert _scoped(text, scope) is there
+
+
+@pytest.mark.parametrize("program, positions", [
+    ("decode_chunk", 16 * 16), ("prefill_8", 64), ("prefill_16", 64)])
+def test_attend_positions_says_which_form_a_program_took(traced, program,
+                                                         positions):
+    """``kfx_lm_attend_positions{model,program}``: the pool's slots
+    (pages x page size) where the program attends in place, the row's
+    ``max_seq_len`` where it gathers."""
+    gauge = traced["engine"]._reg().gauge("kfx_lm_attend_positions", "")
+    assert gauge.value(model="lm-trace", program=program) == positions

@@ -309,9 +309,16 @@ class TestDisaggHandoff:
                 req.result(timeout=60)
             assert adopted
             assert adopted[0].result(timeout=60) == ref
-            assert donor._reg().counter(
-                "kfx_lm_kv_migrations_total").value(
-                    model="kv-pf-tier", reason="disagg") >= 1
+            # The handoff thread counts AFTER the loop thread has woken
+            # the waiter: where the peer finished first, nothing else
+            # stands between that wake-up and this read.
+            migrations = donor._reg().counter("kfx_lm_kv_migrations_total")
+            deadline = time.monotonic() + 10
+            while (migrations.value(model="kv-pf-tier", reason="disagg") < 1
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)
+            assert migrations.value(model="kv-pf-tier",
+                                    reason="disagg") >= 1
         finally:
             donor.close()
 

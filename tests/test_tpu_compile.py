@@ -96,6 +96,53 @@ def test_flash_kernels_carry_their_names_in_the_compiled_hlo(
     assert calls[0].lstrip().startswith(f"%{kernel}")
 
 
+def test_in_place_decode_attention_compiles_for_v5e(one_chip):
+    """One layer-step of the paged decode attention at the serving
+    cell's shapes (16 rows, 32 heads x 128, 288 pages of 32 under
+    ``max_seq_len`` 1536: 16 x 1536 >= 288 x 32, so the pool is
+    attended in place). The chip's compiler keeps it on the MXU (two
+    ``convolution``s), widens no copy of a pool to float32 and builds
+    no per-row view: its temporaries are the scores, not gigabytes."""
+    import flax.linen as nn
+
+    from kubeflow_tpu.models.transformer import (
+        Attention, TransformerConfig, attends_pool_in_place)
+
+    B, H, D, L, P, N = 16, 32, 128, 1536, 32, 288
+    assert attends_pool_in_place(B, L, N, P)
+    cfg = TransformerConfig(
+        vocab_size=256, d_model=H * D, n_heads=H, head_dim=D, n_layers=1,
+        d_ff=256, max_seq_len=L, dtype=jnp.bfloat16, decode=True,
+        kv_page_size=P, kv_pages=N)
+
+    class Attend(Attention):
+        @nn.compact
+        def __call__(self, *args):
+            return self._decode_attend(*args)
+
+    def layer_step(cache, *args):
+        out, vars_ = Attend(cfg).apply({"cache": cache}, *args,
+                                       mutable=["cache"])
+        return out, vars_["cache"]
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    pool = sds((N, P, H, D), jnp.bfloat16)
+    row = sds((B, 1, H, D), jnp.bfloat16)
+    ids = sds((B, 1), jnp.int32)
+    compiled = jax.jit(layer_step, donate_argnums=0).lower(
+        {"cached_key": pool, "cached_value": pool,
+         "cached_pos": sds((N, P), jnp.int32)},
+        row, row, row, ids, sds((B, L // P), jnp.int32), ids).compile()
+    text = compiled.as_text()
+    assert text.count(" convolution(") == 2, text[-3000:]
+    assert "kv_member" in text and "kv_gather" not in text
+    for widened in ("f32[9216,32,128]", "f32[288,32,32,128]",
+                    "[768,32,32,128]"):
+        assert widened not in text, widened
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 def test_libtpu_registers_the_overlap_flags():
     """``lm_runner --collective-overlap`` hands these to libtpu through
     LIBTPU_INIT_ARGS; libtpu aborts on a flag it does not register (as
