@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .transformer import TransformerConfig, TransformerLM
+from .transformer import TransformerConfig, TransformerLM, init_cache
 
 
 def pow2_bucket(n: int, cap: int) -> int:
@@ -123,10 +123,11 @@ class LMGenerator:
             pos = jnp.arange(prompt_pad, dtype=jnp.int32)[None, :]
             pos = jnp.where(pos < true_len[:, None], pos, -1)
             pos = jnp.broadcast_to(pos, tokens.shape)
-            # Prefill: cache vars materialise on first decode apply.
+            # Prefill into an empty cache (the layer scan carries it,
+            # so it is made out here, not by the first apply).
             logits, vars_ = model.apply(
-                {"params": params}, tokens, positions=pos,
-                mutable=["cache"])
+                {"params": params, "cache": init_cache(cfg, B)}, tokens,
+                positions=pos, mutable=["cache"])
             cache = vars_["cache"]
             # The next-token context is the LAST REAL prompt token's
             # logits, not the pad tail's.

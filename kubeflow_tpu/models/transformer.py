@@ -511,7 +511,8 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, block_tables=None,
-                 write_locations=None, lora=None, adapter_ids=None):
+                 write_locations=None, lora=None, adapter_ids=None,
+                 layer=0):
         cfg = self.cfg
         B, S, _ = x.shape
         if cfg.quant == "int8":
@@ -574,7 +575,7 @@ class Attention(nn.Module):
         path = "decode" if cfg.decode else attention_path(cfg, S)
         if path == "decode":
             out = self._decode_attend(q, k, v, positions, block_tables,
-                                      write_locations)
+                                      write_locations, layer)
         elif path == "ring":
             # Context-parallel path: seq sharded over "ctx", heads over
             # "model" (each head attends independently, so tp composes),
@@ -649,11 +650,21 @@ class Attention(nn.Module):
         return checkpoint_name(y, "attn_out")
 
     def _decode_attend(self, q, k, v, positions, block_tables=None,
-                       write_locations=None):
+                       write_locations=None, layer=0):
         """KV-cache attention. Two cache layouts behind one mask rule —
         per-slot validity is the cached position id (-1 = empty/pad),
         never the cache location, so both layouts stay exact for left-
         or right-padded prompts and greedy outputs agree byte-for-byte.
+
+        Every cache leaf holds ALL layers ([n_layers, ...], made by
+        ``init_cache``) and ``layer`` says which one this call is: the
+        layer scan carries the cache (``TransformerLM``), so a layer
+        writes its tokens into the stack where it lies
+        (``at[layer, ...]``) and reads its own slice by dynamic index.
+        Scanned in and stacked out instead, every token-step sliced
+        each layer's pool out of the stack, wrote it into a second
+        stack and copied that one back: half the decode program. The
+        shapes below leave the leading layers axis out.
 
         Dense (kv_page_size == 0): one [B, max_seq_len] KV row per
         batch row, written at a PER-ROW cursor ([B], not a shared
@@ -718,19 +729,35 @@ class Attention(nn.Module):
         cfg = self.cfg
         B, S, H, D = q.shape
         L = cfg.max_seq_len
+
+        def leaf(name):
+            # A carried collection cannot grow inside the scan.
+            if not self.has_variable("cache", name):
+                raise ValueError(
+                    f"decode needs the cache made by init_cache(): no "
+                    f"{name!r} in the 'cache' collection")
+            return self.variable("cache", name)
+
+        def own(var, flat=False):
+            """This layer's slice of a stacked leaf. ``flat`` merges a
+            paged leaf's page and slot axes ([N*P, ...]) BEFORE the
+            slice: the v5e compiler then slices straight into what the
+            score and value matmuls read; sliced as pages it adds a
+            relayout copy of the layer's pool in between."""
+            value = var.value
+            if flat:
+                value = value.reshape(value.shape[0], -1, *value.shape[3:])
+            return jax.lax.dynamic_index_in_dim(value, layer, 0,
+                                                keepdims=False)
+
+        ck, cv, cpos = (leaf("cached_key"), leaf("cached_value"),
+                        leaf("cached_pos"))
         if cfg.kv_page_size > 0:
             P, N = cfg.kv_page_size, cfg.kv_pages
             if block_tables is None:
                 raise ValueError(
                     "paged decode (kv_page_size > 0) requires block_tables")
             int8_kv = cfg.kv_quant == "int8"
-            kv_dtype = jnp.int8 if int8_kv else cfg.dtype
-            ck = self.variable("cache", "cached_key",
-                               lambda: jnp.zeros((N, P, H, D), kv_dtype))
-            cv = self.variable("cache", "cached_value",
-                               lambda: jnp.zeros((N, P, H, D), kv_dtype))
-            cpos = self.variable("cache", "cached_pos",
-                                 lambda: jnp.full((N, P), -1, jnp.int32))
             if int8_kv:
                 # Per-token symmetric scales, stored as one f32 plane
                 # per pool beside the pages ([N, P]: page x slot). The
@@ -739,12 +766,7 @@ class Attention(nn.Module):
                 # no calibration pass and page recycling needs no
                 # rescale — a recycled entry's stale scale is dead the
                 # moment its position id is -1.
-                ksc = self.variable(
-                    "cache", "key_scale",
-                    lambda: jnp.zeros((N, P), jnp.float32))
-                vsc = self.variable(
-                    "cache", "value_scale",
-                    lambda: jnp.zeros((N, P), jnp.float32))
+                ksc, vsc = leaf("key_scale"), leaf("value_scale")
             pos = positions  # [B, S]
             loc = pos if write_locations is None else write_locations
             ok = (pos >= 0) & (loc >= 0)
@@ -755,6 +777,11 @@ class Attention(nn.Module):
             # mode="drop" discards the update.
             page = jnp.where(ok & (page >= 0), page, N)
             slot = jnp.where(ok, loc % P, 0)
+
+            def write(var, rows):
+                var.value = var.value.at[layer, page, slot].set(
+                    rows, mode="drop")
+
             with jax.named_scope("kv_write"):
                 if int8_kv:
                     # Quantize-on-write: round each token's K/V row to
@@ -771,21 +798,14 @@ class Attention(nn.Module):
                         return q, s
                     kq, ks = q8(k)
                     vq, vs = q8(v)
-                    ck.value = ck.value.at[page, slot].set(
-                        kq, mode="drop")
-                    cv.value = cv.value.at[page, slot].set(
-                        vq, mode="drop")
-                    ksc.value = ksc.value.at[page, slot].set(
-                        ks, mode="drop")
-                    vsc.value = vsc.value.at[page, slot].set(
-                        vs, mode="drop")
+                    write(ck, kq)
+                    write(cv, vq)
+                    write(ksc, ks)
+                    write(vsc, vs)
                 else:
-                    ck.value = ck.value.at[page, slot].set(
-                        k.astype(cfg.dtype), mode="drop")
-                    cv.value = cv.value.at[page, slot].set(
-                        v.astype(cfg.dtype), mode="drop")
-                cpos.value = cpos.value.at[page, slot].set(
-                    pos, mode="drop")
+                    write(ck, k.astype(cfg.dtype))
+                    write(cv, v.astype(cfg.dtype))
+                write(cpos, pos)
             if attends_pool_in_place(B, L, N, P):
                 if int8_kv:
                     # Dequant the pool where it lies: int8 entries x
@@ -793,55 +813,52 @@ class Attention(nn.Module):
                     # compute dtype (what the gathered form does to
                     # each row's view).
                     with jax.named_scope("kv_dequant"):
-                        pk = (ck.value.astype(jnp.float32)
-                              * ksc.value[..., None, None]).astype(cfg.dtype)
-                        pv = (cv.value.astype(jnp.float32)
-                              * vsc.value[..., None, None]).astype(cfg.dtype)
+                        pk = (own(ck, flat=True).astype(jnp.float32)
+                              * own(ksc, flat=True)[..., None, None]
+                              ).astype(cfg.dtype)
+                        pv = (own(cv, flat=True).astype(jnp.float32)
+                              * own(vsc, flat=True)[..., None, None]
+                              ).astype(cfg.dtype)
                 else:
-                    pk, pv = ck.value, cv.value
-                return self._attend_pool(
-                    q, positions, block_tables, pk.reshape(N * P, H, D),
-                    pv.reshape(N * P, H, D), cpos.value)
+                    pk, pv = own(ck, flat=True), own(cv, flat=True)
+                return self._attend_pool(q, positions, block_tables, pk, pv,
+                                         own(cpos))
             # Gather each row's logical view [L] through its table.
             # Unallocated blocks clamp to page 0 for K/V (their scores
             # are masked to exactly-0 probability via position -1, so
             # the garbage never contributes) and force position -1.
             with jax.named_scope("kv_gather"):
                 pt = jnp.clip(block_tables, 0, N - 1)    # [B, nblk]
+
+                def view(var):
+                    rows = var.value[layer, pt]      # [B, nblk, P, ...]
+                    return rows.reshape(B, L, *rows.shape[3:])
+
+                gk, gv = view(ck), view(cv)
                 if int8_kv:
                     # Dequant-on-gather: int8 entries x the per-token
                     # scale plane, in f32 (one multiply per gathered
                     # element), then the compute dtype.
-                    gks = ksc.value[pt].reshape(B, L)[..., None, None]
-                    gvs = vsc.value[pt].reshape(B, L)[..., None, None]
-                    gk = (ck.value[pt].reshape(B, L, H, D).astype(
-                        jnp.float32) * gks).astype(cfg.dtype)
-                    gv = (cv.value[pt].reshape(B, L, H, D).astype(
-                        jnp.float32) * gvs).astype(cfg.dtype)
-                else:
-                    gk = ck.value[pt].reshape(B, L, H, D)
-                    gv = cv.value[pt].reshape(B, L, H, D)
+                    gk = (gk.astype(jnp.float32)
+                          * view(ksc)[..., None, None]).astype(cfg.dtype)
+                    gv = (gv.astype(jnp.float32)
+                          * view(vsc)[..., None, None]).astype(cfg.dtype)
                 gp = jnp.where((block_tables >= 0)[..., None],
-                               cpos.value[pt], -1).reshape(B, L)
+                               cpos.value[layer, pt], -1).reshape(B, L)
         else:
-            ck = self.variable("cache", "cached_key",
-                               lambda: jnp.zeros((B, L, H, D), cfg.dtype))
-            cv = self.variable("cache", "cached_value",
-                               lambda: jnp.zeros((B, L, H, D), cfg.dtype))
-            cpos = self.variable("cache", "cached_pos",
-                                 lambda: jnp.full((B, L), -1, jnp.int32))
-            cur = self.variable("cache", "cache_index",
-                                lambda: jnp.zeros((B,), jnp.int32))
-            i = cur.value  # [B]
+            cur = leaf("cache_index")
+            i = own(cur)  # [B]
             with jax.named_scope("kv_write"):
                 rows = jnp.arange(B, dtype=jnp.int32)[:, None]  # [B, 1]
                 at = i[:, None] + jnp.arange(
                     S, dtype=jnp.int32)[None]                   # [B, S]
-                ck.value = ck.value.at[rows, at].set(k.astype(cfg.dtype))
-                cv.value = cv.value.at[rows, at].set(v.astype(cfg.dtype))
-                cpos.value = cpos.value.at[rows, at].set(positions)
-                cur.value = i + S
-            gk, gv, gp = ck.value, cv.value, cpos.value
+                ck.value = ck.value.at[layer, rows, at].set(
+                    k.astype(cfg.dtype))
+                cv.value = cv.value.at[layer, rows, at].set(
+                    v.astype(cfg.dtype))
+                cpos.value = cpos.value.at[layer, rows, at].set(positions)
+                cur.value = cur.value.at[layer].set(i + S)
+            gk, gv, gp = own(ck), own(cv), own(cpos)
 
         with jax.named_scope("scores"):
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, gk)  # [B,H,S,L]
@@ -895,6 +912,37 @@ def attends_pool_in_place(batch: int, max_seq_len: int, kv_pages: int,
     static when the program is traced; the engine reports the outcome
     per program (``kfx_lm_attend_positions``)."""
     return batch * max_seq_len >= kv_pages * page_size
+
+
+def init_cache(cfg: TransformerConfig, batch: int = 0):
+    """The empty decode cache of ``cfg`` — the "cache" collection a
+    decode-mode ``TransformerLM`` is applied with: zeros, every cached
+    position id -1. Every leaf holds all layers (axis 0). Paged
+    (``kv_page_size > 0``): the pools [layers, kv_pages, page, H, D],
+    their position ids [layers, kv_pages, page] and, for int8 KV, the
+    two scale planes; batch-independent. Dense: [layers, batch,
+    max_seq_len, H, D] rows with a per-row cursor, so it needs
+    ``batch``. Made out here because the layer scan carries the cache,
+    and what a scan carries cannot come into being inside it."""
+    n, H, D = cfg.n_layers, cfg.n_heads, cfg.head_dim
+    if cfg.kv_page_size > 0:
+        rows = (n, cfg.kv_pages, cfg.kv_page_size)
+        int8_kv = cfg.kv_quant == "int8"
+        kv_dtype = jnp.int8 if int8_kv else cfg.dtype
+        attn = {}
+        if int8_kv:
+            attn = {"key_scale": jnp.zeros(rows, jnp.float32),
+                    "value_scale": jnp.zeros(rows, jnp.float32)}
+    else:
+        if batch < 1:
+            raise ValueError("the dense decode cache is per batch row: "
+                             "init_cache(cfg, batch)")
+        rows, kv_dtype = (n, batch, cfg.max_seq_len), cfg.dtype
+        attn = {"cache_index": jnp.zeros((n, batch), jnp.int32)}
+    attn.update(cached_key=jnp.zeros(rows + (H, D), kv_dtype),
+                cached_value=jnp.zeros(rows + (H, D), kv_dtype),
+                cached_pos=jnp.full(rows, -1, jnp.int32))
+    return {"layers": {"attn": attn}}
 
 
 class DenseFFN(nn.Module):
@@ -1017,7 +1065,8 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, block_tables=None,
-                 write_locations=None, lora=None, adapter_ids=None):
+                 write_locations=None, lora=None, adapter_ids=None,
+                 layer=0):
         cfg = self.cfg
         lora = lora or {}
 
@@ -1037,7 +1086,7 @@ class Block(nn.Module):
         x = sp_shard(x)
         x = x + Attention(cfg, name="attn")(
             RMSNorm(cfg.dtype, name="ln1")(x), positions, block_tables,
-            write_locations, lora.get("attn"), adapter_ids)
+            write_locations, lora.get("attn"), adapter_ids, layer)
         x = sp_shard(x)
         h = RMSNorm(cfg.dtype, name="ln2")(x)
         if cfg.n_experts > 0:
@@ -1158,23 +1207,34 @@ class TransformerLM(nn.Module):
                         f"(have {sorted(policies)})") from None
             kw = {"policy": policy} if policy is not None else {}
             block = nn.remat(Block, prevent_cse=False, **kw)
+        if cfg.kv_page_size > 0 and write_locations is None:
+            write_locations = positions
+        # positions/tables/ids broadcast to every layer; the lora
+        # stacks carry a leading layers axis the scan slices (each
+        # layer sees ITS adapters' factors — in_axes=0).
+        args = (positions, block_tables, write_locations, lora,
+                adapter_ids)
+        in_axes = (nn.broadcast, nn.broadcast, nn.broadcast, 0,
+                   nn.broadcast)
+        if cfg.decode:
+            # The KV cache is CARRIED through the layer loop, whole,
+            # and each layer is told its index: a collection scanned
+            # over its layers axis comes out of the loop as a fresh
+            # stack, which costs a second copy of every pool and its
+            # movement every token (Attention._decode_attend). The
+            # train step has no cache and keeps its program as it was.
+            args += (jnp.arange(cfg.n_layers, dtype=jnp.int32),)
+            in_axes += (0,)
         ScanBlock = nn.scan(
             block,
-            variable_axes={"params": 0, "aux_loss": 0, "cache": 0},
+            variable_axes={"params": 0, "aux_loss": 0},
+            variable_carry="cache" if cfg.decode else False,
             split_rngs={"params": True},
-            # positions/tables/ids broadcast to every layer; the lora
-            # stacks carry a leading layers axis the scan slices (each
-            # layer sees ITS adapters' factors — in_axes=0).
-            in_axes=(nn.broadcast, nn.broadcast, nn.broadcast, 0,
-                     nn.broadcast),
+            in_axes=in_axes,
             length=cfg.n_layers,
             metadata_params={nn.PARTITION_NAME: "layers"},
         )
-        if cfg.kv_page_size > 0 and write_locations is None:
-            write_locations = positions
-        x, _ = ScanBlock(cfg, name="layers")(x, positions, block_tables,
-                                             write_locations, lora,
-                                             adapter_ids)
+        x, _ = ScanBlock(cfg, name="layers")(x, *args)
 
         x = RMSNorm(cfg.dtype, name="ln_f")(x)
         if return_hidden:

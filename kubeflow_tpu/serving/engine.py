@@ -1574,35 +1574,12 @@ class DecodeEngine:
 
     # -- cache / compiled functions ------------------------------------------
     def _init_cache(self, draft: bool = False):
-        """Zeros of the paged cache pytree (positions -1 = every page
-        empty), built from eval_shape — no compile, no dispatch. The
-        pool is batch-independent, so the B used here is irrelevant to
-        the shapes. ``draft=True`` builds the draft model's pool
-        (fewer layers, its own page count, same page geometry)."""
-        import jax
-        import jax.numpy as jnp
+        """The empty paged cache pytree (positions -1 = every page
+        empty). ``draft=True`` builds the draft model's pool (fewer
+        layers, its own page count, same page geometry)."""
+        from ..models.transformer import init_cache
 
-        model = self.draft_model if draft else self.model
-        params = self.draft_params if draft else self.params
-
-        def mk(p):
-            toks = jnp.zeros((1, 1), jnp.int32)
-            pos = jnp.full((1, 1), -1, jnp.int32)
-            bt = jnp.full((1, self.n_blocks), -1, jnp.int32)
-            return model.apply({"params": p}, toks, positions=pos,
-                               block_tables=bt,
-                               mutable=["cache"])[1]["cache"]
-
-        shapes = jax.eval_shape(mk, params)
-        flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-        leaves = []
-        for path, s in flat:
-            name = getattr(path[-1], "key", str(path[-1]))
-            if name == "cached_pos":
-                leaves.append(jnp.full(s.shape, -1, s.dtype))
-            else:
-                leaves.append(jnp.zeros(s.shape, s.dtype))
-        return jax.tree_util.tree_unflatten(treedef, leaves)
+        return init_cache(self.draft_cfg if draft else self.cfg)
 
     def _init_logbuf(self):
         import jax.numpy as jnp
@@ -1669,6 +1646,24 @@ class DecodeEngine:
                 cfg.kv_pages * cfg.kv_page_size if in_place
                 else cfg.max_seq_len,
                 model=self.name, program=program)
+
+    def _report_temp(self, program: str, compiled):
+        """``kfx_lm_program_temp_bytes{model,program}``: the scratch
+        memory the compiler gave a model program beside its arguments
+        and results (``memory_analysis().temp_size_in_bytes``), set
+        when the program is built. The pools are arguments aliased to
+        results, so a program that writes them in place holds none of
+        their bytes here; one that restacks them holds a second copy
+        of every pool. Returns ``compiled``."""
+        stats = compiled.memory_analysis()
+        if stats is not None:
+            self._reg().gauge(
+                "kfx_lm_program_temp_bytes",
+                "Temporary device bytes of a compiled model program "
+                "(beside its arguments and results).").set(
+                    stats.temp_size_in_bytes, model=self.name,
+                    program=program)
+        return compiled
 
     def _build(self, build_fn, *args):
         """Run one AOT build under the ``_building`` marker so the
@@ -1746,8 +1741,9 @@ class DecodeEngine:
             jax.ShapeDtypeStruct((1,), np.int32),
         )
         self._report_attend(f"prefill_{P}", 1)
-        return jax.jit(self._named(run, f"prefill_{P}"),
-                       donate_argnums=donate).lower(*specs).compile()
+        return self._report_temp(f"prefill_{P}", jax.jit(
+            self._named(run, f"prefill_{P}"),
+            donate_argnums=donate).lower(*specs).compile())
 
     def _decode(self):
         with self._exec_lock:
@@ -1847,8 +1843,9 @@ class DecodeEngine:
             sds((B,), np.int32),      # adapter ids
         )
         self._report_attend("decode_chunk", B)
-        return jax.jit(self._named(run, "decode_chunk"),
-                       donate_argnums=donate).lower(*specs).compile()
+        return self._report_temp("decode_chunk", jax.jit(
+            self._named(run, "decode_chunk"),
+            donate_argnums=donate).lower(*specs).compile())
 
     def _reset_fn(self, draft: bool = False):
         """Compiled page invalidation: sets cached position ids to -1
@@ -2042,8 +2039,9 @@ class DecodeEngine:
             jax.ShapeDtypeStruct((1,), np.int32),
         )
         self._report_attend(f"draft_prefill_{P}", 1, draft=True)
-        return jax.jit(self._named(run, f"draft_prefill_{P}"),
-                       donate_argnums=donate).lower(*specs).compile()
+        return self._report_temp(f"draft_prefill_{P}", jax.jit(
+            self._named(run, f"draft_prefill_{P}"),
+            donate_argnums=donate).lower(*specs).compile())
 
     def _spec_step(self):
         with self._exec_lock:
@@ -2310,8 +2308,9 @@ class DecodeEngine:
         )
         self._report_attend("spec_step", B)
         self._report_attend("spec_step_draft", B, draft=True)
-        return jax.jit(self._named(run, "spec_step"),
-                       donate_argnums=donate).lower(*specs).compile()
+        return self._report_temp("spec_step", jax.jit(
+            self._named(run, "spec_step"),
+            donate_argnums=donate).lower(*specs).compile())
 
     def warm(self, buckets: Optional[Sequence[int]] = None) -> int:
         """Compile the hot step (the decode chunk, or the fused

@@ -127,11 +127,13 @@ def pair(tiny_lm):
 
 
 def _submit_throttled(eng, **kw):
-    """Submit with a 20ms per-token brake (on_token runs on the loop
-    thread), so a migration deterministically catches the request
-    mid-decode instead of racing its completion."""
+    """Submit with a 50ms per-token brake (on_token runs on the loop
+    thread), so a migration catches the request mid-decode instead of
+    racing its completion: a migration is two round trips to the loop
+    thread and the peer's import, 0.2-0.35 s on an idle host, and at
+    20ms a token the request's 0.5 s were gone first on a loaded one."""
     return eng.submit(PROMPT, max_new_tokens=24,
-                      on_token=lambda t: time.sleep(0.02), **kw)
+                      on_token=lambda t: time.sleep(0.05), **kw)
 
 
 def _wait_tokens(req, n, timeout=30.0):

@@ -1,12 +1,18 @@
-"""The two forms of paged decode attention (models/transformer.py
-``Attention._decode_attend``) agree: scoring the pool in place under
+"""The paged decode cache of models/transformer.py. First, the two forms
+of ``Attention._decode_attend`` agree: scoring the pool in place under
 the page-membership mask, and gathering each row's logical view through
 its block table. One constructed pool per trap the in-place form could
 fall into; the gathered form (what every prefill still runs) is the
 oracle. float32 to 1e-5, bfloat16 to one ulp at the size of the
 output, and no further than the oracle from the float32 numbers: the
 in-place form accumulates its scores in float32 and sums in the pool's
-order, so it is not bitwise the gathered form."""
+order, so it is not bitwise the gathered form. Second, the layer scan
+carries the cache (every leaf a stack of layers, written in place):
+logits and every leaf equal, bit for bit in float32, a loop over layers
+in Python that keeps one pool a layer in a list, and ``init_cache``
+makes the tree the engine's programs are compiled for."""
+
+import dataclasses
 
 import flax.linen as nn
 import jax
@@ -15,8 +21,11 @@ import numpy as np
 import pytest
 
 from kubeflow_tpu.models import transformer
-from kubeflow_tpu.models.transformer import (Attention, TransformerConfig,
-                                             attends_pool_in_place)
+from kubeflow_tpu.models.transformer import (Attention, Block, RMSNorm,
+                                             TransformerConfig,
+                                             TransformerLM,
+                                             attends_pool_in_place,
+                                             init_cache)
 
 B, H, D, P, L, N = 4, 2, 16, 4, 16, 12       # B*L = 64 >= N*P = 48
 
@@ -126,9 +135,17 @@ def _attend(trap, dtype, args):
         d_ff=8, max_seq_len=L, dtype=dtype, decode=True, kv_page_size=P,
         kv_pages=N, kv_quant=trap.get("kv_quant", ""))
     cache, *rest = args
+    # Every leaf is a stack of layers: the trap's pool is layer 1 of
+    # two, under a layer 0 of other numbers that nothing may touch.
+    stack = {name: np.stack([np.roll(leaf, 1, axis=0), leaf])
+             for name, leaf in cache.items()}
     out, vars_ = jax.jit(lambda c, *a: _Attend(cfg).apply(
-        {"cache": c}, *a, mutable=["cache"]))(cache, *rest)
-    return np.asarray(out, np.float32), vars_["cache"]
+        {"cache": c}, *a, 1, mutable=["cache"]))(stack, *rest)
+    for name, leaf in vars_["cache"].items():
+        np.testing.assert_array_equal(np.asarray(leaf[0], np.float32),
+                                      np.asarray(stack[name][0], np.float32))
+    return (np.asarray(out, np.float32),
+            {name: leaf[1] for name, leaf in vars_["cache"].items()})
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -199,3 +216,200 @@ def test_the_form_is_read_off_the_shapes(batch, max_seq_len, kv_pages, page,
                                          in_place):
     assert attends_pool_in_place(batch, max_seq_len, kv_pages,
                                  page) is in_place
+
+
+# -- the carried cache against one pool a layer ------------------------------
+
+LAYERS, VOCAB = 3, 64
+
+
+def _lm(rows, kv_quant=""):
+    """A float32 decode model over the pool of the traps above (12
+    pages of 4, ``max_seq_len`` 16) and its parameters: ``rows`` = 4
+    attends the pool in place, 1 gathers (a prefill program)."""
+    cfg = TransformerConfig(
+        vocab_size=VOCAB, d_model=H * D, n_heads=H, head_dim=D,
+        n_layers=LAYERS, d_ff=48, max_seq_len=L, dtype=jnp.float32,
+        attn_impl="xla", decode=True, kv_page_size=P, kv_pages=N,
+        kv_quant=kv_quant)
+    assert attends_pool_in_place(rows, L, N, P) is (rows == B)
+    params = TransformerLM(dataclasses.replace(cfg, decode=False)).init(
+        jax.random.PRNGKey(3), jnp.zeros((1, 4), jnp.int32))["params"]
+    return cfg, params
+
+
+def _per_layer(cfg, params, pools, tokens, positions, tables, loc):
+    """``TransformerLM``'s forward with no scan and no stack: the
+    blocks run one after another in Python, block ``i`` on its own
+    parameters and on ``pools[i]``, a cache of that one layer. Returns
+    (logits, the pools after the call)."""
+    one = dataclasses.replace(cfg, n_layers=1)
+    x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype).apply(
+        {"params": params["embed"]}, tokens)
+    after = []
+    for i, pool in enumerate(pools):
+        mine = jax.tree_util.tree_map(lambda w: w[i], params["layers"])
+        (x, _), vars_ = Block(one).apply(
+            {"params": mine, "cache": pool}, x, positions, tables, loc,
+            None, None, 0, mutable=["cache"])
+        after.append(vars_["cache"])
+    x = RMSNorm(cfg.dtype).apply({"params": params["ln_f"]}, x)
+    logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype).apply(
+        {"params": params["lm_head"]}, x)
+    return logits.astype(jnp.float32), after
+
+
+def _window(rows, first, width):
+    """Positions (= write locations) of a ``width``-token window that
+    starts at ``first[b]`` in row ``b`` (None = an inactive row)."""
+    pos = np.full((rows, width), -1, np.int32)
+    for b, at in enumerate(first):
+        if at is not None:
+            pos[b] = at + np.arange(width)
+    return pos
+
+
+def _tables(*rows):
+    return np.array([r + [-1] * (L // P - len(r)) for r in rows], np.int32)
+
+
+_FOUR = _tables([5, 0, 9], [3], [7, 8], [])
+# Each case: the calls made one after another on one cache, as
+# (block tables, positions of the window); tokens are drawn per call.
+CARRIED = {
+    # four prompts of 5, 2 and 6 tokens and an idle row, then three
+    # single-token steps of the three live rows
+    "decode_3_steps": dict(calls=[
+        (_FOUR, _window(4, [0, 0, 0, None], 6)),
+        (_FOUR, _window(4, [6, 6, 6, None], 1)),
+        (_FOUR, _window(4, [7, 7, 7, None], 1)),
+        (_FOUR, _window(4, [8, 8, 8, None], 1))]),
+    # a speculative verify window: the pending token and 3 proposals
+    "verify_window_4": dict(calls=[
+        (_FOUR, _window(4, [0, 0, 0, None], 5)),
+        (_FOUR, _window(4, [5, 5, 5, None], 4))]),
+    # a one-row prefill, then another whose first two blocks are the
+    # first one's pages (a prefix-cache hit: its tail starts at 8)
+    "prefill_prefix_hit": dict(calls=[
+        (_tables([2, 6, 7]), _window(1, [0], 10)),
+        (_tables([2, 6, 10, 11]), _window(1, [8], 6))]),
+    "int8_kv": dict(kv_quant="int8", calls=[
+        (_FOUR, _window(4, [0, 0, 0, None], 6)),
+        (_FOUR, _window(4, [6, 6, 6, None], 1)),
+        (_tables([5, 0, 9]), _window(1, [7], 3))]),
+}
+
+
+@pytest.mark.parametrize("case", CARRIED)
+def test_carried_cache_equals_one_pool_a_layer(case):
+    spec = CARRIED[case]
+    rng = np.random.default_rng(11)
+    cfg, params = _lm(B, spec.get("kv_quant", ""))
+    model = TransformerLM(cfg)
+    cache = init_cache(cfg)
+    pools = [jax.tree_util.tree_map(lambda leaf: leaf[i:i + 1],
+                                    cache["layers"]) for i in range(LAYERS)]
+    carried = jax.jit(lambda c, *a: model.apply(
+        {"params": params, "cache": c}, a[0], positions=a[1],
+        block_tables=a[2], write_locations=a[1], mutable=["cache"]))
+    plain = jax.jit(lambda pools, *a: _per_layer(
+        cfg, params, pools, a[0], a[1], a[2], a[1]))
+    for tables, pos in spec["calls"]:
+        tokens = rng.integers(0, VOCAB, pos.shape).astype(np.int32)
+        logits, vars_ = carried(cache, tokens, pos, tables)
+        cache = vars_["cache"]
+        want, pools = plain(pools, tokens, pos, tables)
+        live = pos >= 0
+        assert live.any() and np.isfinite(np.asarray(logits)[live]).all()
+        np.testing.assert_array_equal(np.asarray(logits)[live],
+                                      np.asarray(want)[live])
+        leaves = cache["layers"]["attn"]
+        assert set(leaves) == set(pools[0]["attn"])
+        for name, stack in leaves.items():
+            for i, pool in enumerate(pools):
+                np.testing.assert_array_equal(
+                    np.asarray(stack[i]), np.asarray(pool["attn"][name][0]),
+                    err_msg=f"{name}, layer {i}")
+    # every layer wrote, each its own numbers
+    keys = np.asarray(cache["layers"]["attn"]["cached_key"], np.float32)
+    assert all(np.abs(keys[i]).sum() > 0 for i in range(LAYERS))
+    assert not np.array_equal(keys[0], keys[1])
+
+
+@pytest.fixture(scope="module")
+def spec_engine():
+    """A tiny engine with a one-layer draft on a smaller pool."""
+    from kubeflow_tpu.serving.engine import DecodeEngine
+
+    cfg = TransformerConfig(vocab_size=VOCAB, d_model=32, n_heads=2,
+                            head_dim=16, n_layers=3, d_ff=64,
+                            max_seq_len=64, dtype=jnp.float32)
+    params = TransformerLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = DecodeEngine(cfg, params, n_slots=2, chunk_tokens=2,
+                       name="lm-carried", kv_page_size=16, kv_pages=6,
+                       draft_layers=1, draft_kv_pages=4, kv_quant="int8")
+    yield eng
+    eng.close()
+
+
+@pytest.mark.parametrize("draft, layers, pages", [(False, 3, 6),
+                                                  (True, 1, 4)],
+                         ids=["target", "draft"])
+def test_init_cache_makes_the_tree_the_programs_take(spec_engine, draft,
+                                                     layers, pages):
+    """Paths, shapes and dtypes of ``init_cache``'s tree are the ones
+    ``DecodeEngine._cache_specs`` compiles every program for, in the
+    target's pool and the draft's; every position id starts at -1."""
+    cfg = spec_engine.draft_cfg if draft else spec_engine.cfg
+    made = init_cache(cfg)
+    rows, heads = (layers, pages, 16), (2, 16)
+    want = {"cached_key": (rows + heads, jnp.int8),
+            "cached_value": (rows + heads, jnp.int8),
+            "key_scale": (rows, jnp.float32),
+            "value_scale": (rows, jnp.float32),
+            "cached_pos": (rows, jnp.int32)}
+    assert set(made) == {"layers"} and set(made["layers"]) == {"attn"}
+    assert {name: (leaf.shape, leaf.dtype)
+            for name, leaf in made["layers"]["attn"].items()} == want
+    specs = spec_engine._cache_specs(draft)
+    assert jax.tree_util.tree_structure(specs) \
+        == jax.tree_util.tree_structure(made)
+    assert jax.tree_util.tree_map(lambda s: (s.shape, s.dtype), specs) \
+        == jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), made)
+    for name, leaf in made["layers"]["attn"].items():
+        assert (np.asarray(leaf) == (-1 if name == "cached_pos" else 0)).all()
+
+
+def test_init_cache_of_the_dense_layout_is_per_row():
+    cfg = TransformerConfig(vocab_size=VOCAB, d_model=32, n_heads=2,
+                            head_dim=16, n_layers=2, d_ff=64,
+                            max_seq_len=8, dtype=jnp.float32, decode=True)
+    attn = init_cache(cfg, 3)["layers"]["attn"]
+    assert {name: leaf.shape for name, leaf in attn.items()} == {
+        "cached_key": (2, 3, 8, 2, 16), "cached_value": (2, 3, 8, 2, 16),
+        "cached_pos": (2, 3, 8), "cache_index": (2, 3)}
+    assert (np.asarray(attn["cached_pos"]) == -1).all()
+    with pytest.raises(ValueError, match="per batch row"):
+        init_cache(cfg)
+    # ... and is not made by applying the model without one
+    tokens = jnp.zeros((3, 1), jnp.int32)
+    params = TransformerLM(dataclasses.replace(cfg, decode=False)).init(
+        jax.random.PRNGKey(0), tokens)["params"]
+    with pytest.raises(ValueError, match="init_cache"):
+        TransformerLM(cfg).apply({"params": params}, tokens,
+                                 positions=tokens, mutable=["cache"])
+
+
+def test_a_build_reports_the_programs_temporary_bytes(spec_engine):
+    """``kfx_lm_program_temp_bytes{model,program}`` is set from the
+    compiled executable when a model program is built."""
+    spec_engine._decode()
+    spec_engine._prefill_for(8)
+    gauge = spec_engine._reg().gauge("kfx_lm_program_temp_bytes", "")
+    programs = {lab["program"]: value for lab, value in gauge.samples()
+                if lab["model"] == "lm-carried"}
+    assert {"decode_chunk", "prefill_8"} <= set(programs)
+    assert all(value >= 0 for value in programs.values())
+    assert programs["decode_chunk"] \
+        == spec_engine._decode().memory_analysis().temp_size_in_bytes

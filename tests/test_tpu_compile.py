@@ -127,12 +127,12 @@ def test_in_place_decode_attention_compiles_for_v5e(one_chip):
 
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                     sharding=one_chip)
-    pool = sds((N, P, H, D), jnp.bfloat16)
+    pool = sds((1, N, P, H, D), jnp.bfloat16)      # a stack of one layer
     row = sds((B, 1, H, D), jnp.bfloat16)
     ids = sds((B, 1), jnp.int32)
     compiled = jax.jit(layer_step, donate_argnums=0).lower(
         {"cached_key": pool, "cached_value": pool,
-         "cached_pos": sds((N, P), jnp.int32)},
+         "cached_pos": sds((1, N, P), jnp.int32)},
         row, row, row, ids, sds((B, L // P), jnp.int32), ids).compile()
     text = compiled.as_text()
     assert text.count(" convolution(") == 2, text[-3000:]
@@ -141,6 +141,76 @@ def test_in_place_decode_attention_compiles_for_v5e(one_chip):
                     "[768,32,32,128]"):
         assert widened not in text, widened
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("program", ["decode_chunk", "prefill_256"])
+def test_engine_programs_write_the_stacked_pool_in_place(one_chip,
+                                                         monkeypatch,
+                                                         program):
+    """The engine's own decode chunk and 256-token prefill, built by
+    ``DecodeEngine._build_decode`` / ``_build_prefill`` at the serving
+    cell's pool (16 rows, 288 pages of 32, 32 heads x 128; 2 layers and
+    a narrow ``d_ff``, which the pool's handling does not depend on).
+    The layer scan carries the cache, so the compiler updates the
+    stacked pools where they lie: it allocates no second stack, copies
+    no stack, and writes no layer's pool back into one. (Scanned in and
+    stacked out, the same programs held two ``AllocateBuffer``s and two
+    whole-stack ``copy``s in the token loop: half the decode step.)"""
+    import dataclasses
+    import re
+
+    from kubeflow_tpu.models.generate import decode_config
+    from kubeflow_tpu.models.transformer import (
+        TransformerConfig, TransformerLM, init_cache)
+    from kubeflow_tpu.serving.engine import DecodeEngine
+
+    layers, rows, L, P, N = 2, 16, 1536, 32, 288
+    cfg = dataclasses.replace(decode_config(TransformerConfig(
+        vocab_size=1024, d_model=4096, n_heads=32, head_dim=128,
+        n_layers=layers, d_ff=256, max_seq_len=L, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)), kv_page_size=P, kv_pages=N)
+    # The engine's builders on shapes alone: just what they read of an
+    # engine, with no weights, no pool and no loop thread behind it.
+    eng = object.__new__(DecodeEngine)
+    eng.cfg, eng.model, eng.name = cfg, TransformerLM(cfg), "aot"
+    eng.n_slots, eng.chunk_tokens, eng.n_blocks = rows, 8, L // P
+    eng._donate, eng._apool, eng._registry = True, None, None
+    eng.params = jax.eval_shape(
+        lambda: TransformerLM(dataclasses.replace(cfg, decode=False)).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    eng._cache = jax.eval_shape(lambda: init_cache(cfg))
+
+    # ... and lowered for the described chip instead of this host's CPU.
+    jit = jax.jit
+
+    class ForTheChip:
+        def __init__(self, fn, **kw):
+            self.jitted = jit(fn, **kw)
+
+        def lower(self, *specs):
+            return self.jitted.lower(*jax.tree_util.tree_map(
+                lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                               sharding=one_chip), specs))
+
+    monkeypatch.setattr(jax, "jit", ForTheChip)
+    compiled = eng._build_decode() if program == "decode_chunk" \
+        else eng._build_prefill(256)
+    monkeypatch.undo()
+    text = compiled.as_text()
+    assert f"jit_run_kfx_{program}" in text.splitlines()[0]
+    stack = re.escape(f"bf16[{layers},{N},{P},32,128]")
+    made = [line.strip()[:200] for line in text.splitlines()
+            if re.search(rf"= {stack}\S* (copy|copy-done|dynamic-update-slice|"
+                         rf"custom-call)\(", line)]
+    assert not made, made
+    assert "AllocateBuffer" not in "".join(
+        line for line in text.splitlines() if f"[{N},{P},32,128]" in line)
+    # both pools are arguments the results alias, written by a scatter
+    assert len(re.findall(rf"ROOT \S+ = {stack}\S* scatter\(", text)) == 2
+    assert text.splitlines()[0].count("may-alias") >= 4
+    if program == "prefill_256":
+        # (the decode chunk keeps relayout copies of its q/k/v kernels)
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 def test_libtpu_registers_the_overlap_flags():
