@@ -170,24 +170,35 @@ def require_chips(cp, chips: int) -> None:
           f"{chips}")
 
 
-def print_comparison(rows: List[Dict[str, Any]]) -> bool:
-    """Each number compared beside its limit; True when all hold."""
-    ok = True
+def print_comparison(rows: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    """Each number compared beside its limit, into the log and onto
+    standard error, where these are the run's last lines (the harness
+    writes nothing else there); the rows come back with a ``held`` flag
+    each. ``correct`` is all of them."""
+    out = []
     for r in rows:
-        held = r["value"] <= r["limit"]
-        ok = ok and held
-        say(f"compared {r['name']}: value={r['value']:.6g} "
-            f"limit={r['limit']:.6g} {'ok' if held else 'OVER'}")
-    return ok
+        held = bool(r["value"] <= r["limit"])
+        out.append(dict(r, held=held))
+        text = (f"compared {r['name']}: value={r['value']:.6g} "
+                f"limit={r['limit']:.6g} {'ok' if held else 'OVER'}")
+        say(text)
+        print(text, file=sys.stderr, flush=True)
+    return out
 
 
-def result_line(correct: bool, attempted: int, failed: int,
+def result_line(compared: List[Dict[str, Any]], attempted: int, failed: int,
                 metrics: Dict[str, Any], device: Dict[str, Any],
                 breakdown: Optional[Dict[str, Any]] = None) -> str:
-    line: Dict[str, Any] = {"correct": bool(correct),
+    """The run's one line. ``correct`` is true when every compared
+    number held; the numbers stand beside their limits under
+    ``compared``, the line's last key, so that a refused ``correct``
+    says in the one line the driver keeps which number failed."""
+    line: Dict[str, Any] = {"correct": all(r["held"] for r in compared),
                             "attempted": int(attempted),
                             "failed": int(failed), "metrics": metrics,
                             "device": device}
     if breakdown:
         line["breakdown"] = breakdown
+    line["compared"] = {r["name"]: {"value": r["value"], "limit": r["limit"]}
+                        for r in compared}
     return json.dumps(line)

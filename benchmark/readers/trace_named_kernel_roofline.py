@@ -1,15 +1,16 @@
 """Named kernels' share of their roofline: the least time the chip
 could take for every call in the trace, from shapes alone
 (benchmark/flops.py, benchmark/peaks.json), over the kernels' summed
-device time. As ``trace_kernel_roofline``, but a kernel is found by the
-name the program gave it (``pl.pallas_call(..., name=...)``), which a
-TPU trace shows as the custom call's own instruction name
+device time. A kernel is found by the name the program gave it
+(``pl.pallas_call(..., name=...)``), which a TPU trace shows as the
+custom call's own instruction name
 (``%kfx_flash_dq.11 = ... custom-call(...)``); no operand is counted.
 A program whose kernels carry no such name gives nothing to read.
 
 args: {"kernels": {"fwd": "kfx_flash_fwd", ...}}: the cost function's
 kind (benchmark/flops.py flash_kernel_cost) -> the kernel's name.
-Shapes are the cell's, as in ``trace_kernel_roofline``."""
+Shapes are the cell's: batch rows a chip (global batch / data ways),
+the configuration's heads a chip and head size, the mix's sequence."""
 
 from benchmark import flops, peaks
 

@@ -1,14 +1,12 @@
 """Median device time of one compiled program's runs in the trace.
 
-The engine's programs all carry the name ``jax.jit`` gave their Python
-function (``run``), so a program is picked among the modules whose name
-holds ``match`` by rank of total device time: ``rank`` 0 is the one
-that took most (in a steady decode-heavy window, the decode chunk),
-``rank`` 1 the next (the prefill of the commonest bucket) and so on;
-``rank`` "rest" pools every other. A ``named_scope`` in the program
-would replace the ranking by a name (PERF.md, for the tracing issue).
+A program is picked among the modules whose name holds ``match``, the
+name the program gave it (``jit_run_kfx_decode_chunk``,
+``jit_kfx_train_step``). Where several modules hold it they are ranked
+by total device time: ``rank`` 0 (the default) is the one that took
+most, ``rank`` 1 the next, ``rank`` "rest" pools every other.
 
-args: {"match": "jit_run", "rank": 0 | "rest", "min_ms": 0.0,
+args: {"match": "kfx_decode_chunk", "rank": 0 | "rest", "min_ms": 0.0,
        "divide_by": "serving.decode_chunk" (optional, a key of the
        run's context), "scale": 1000.0}"""
 

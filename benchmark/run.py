@@ -7,7 +7,8 @@
 Runs on the machine it is started on, which must hold the TPU chips the
 cell asks for; anything else is exit code 1 and no result line. The
 last line of standard output is one JSON object (correct, attempted,
-failed, metrics, device, and with --trace 1 breakdown). ``--trace 0``
+failed, metrics, device, with --trace 1 breakdown, and last compared:
+every number ``correct`` rests on beside its limit). ``--trace 0``
 reports the cell's end-to-end metrics, ``--trace 1`` its per-layer
 metrics. What a cell is lives in data files found by the names in
 BENCHMARK.json (benchmark/manifest.py).

@@ -37,11 +37,14 @@ def judge(rows, rate: float, seconds: float) -> dict:
     done = sum(half <= r["end_s"] <= seconds for r in ok) / half
     offered = sum(r["due_s"] >= half for r in rows) / half
     e2e = serve_cell.end_to_end(rows)
+    late = serve_cell.lateness_ms(rows)
     return {"rate": rate, "sent": len(rows), "failed": len(rows) - len(ok),
             "offered_2nd_half": offered, "completed_2nd_half": done,
             "ttft_first_third_ms": 1e3 * first,
             "ttft_last_third_ms": 1e3 * last,
             "sustained": bool(done >= 0.97 * offered and last <= 2 * first),
+            "late_p99_ms": round(late["p99"], 2),
+            "late_max_ms": round(late["max"], 2),
             **{k: round(v, 2) for k, v in e2e.items()}}
 
 

@@ -48,6 +48,14 @@ def test_serving_cell_end_to_end(root):
                                    "out_tokens_per_s", "setup_s"}
     assert all(v["value"] > 0 for v in res["metrics"].values())
     assert res["device"]["platform"] == "cpu"
+    # the numbers compared: in the log, as the line's last key, and a
+    # sound run's line has no other key than these
+    assert list(res) == ["correct", "attempted", "failed", "metrics",
+                         "device", "compared"]
+    assert set(res["compared"]) == {
+        "served_logit_gap_max", "served_logit_gap_mean",
+        "compilations_in_window", "generator_late_p99_ms"}
+    assert all(c["value"] <= c["limit"] for c in res["compared"].values())
     assert "compared served_logit_gap_max" in out
     assert "traffic {" in out and "generator_late_p99_ms" in out
 
@@ -55,17 +63,18 @@ def test_serving_cell_end_to_end(root):
 def test_serving_cell_traced_reads_every_layer_metric(root):
     res, out = tiny.run_cell(root, "tiny-chat", seconds=4, trace=1)
     assert res["correct"] is True, out[-3000:]
-    # (prefill_chunk_ms needs the device's own module line: the CPU
-    # stand-in pools every program under one name; prefix_reuse_pct
-    # finds nothing to read with the tiny cell's prefix cache off)
+    # (prefill_program_ms names the real cell's 256-token program,
+    # which the tiny cell never runs; prefix_reuse_pct finds nothing to
+    # read with the tiny cell's prefix cache off)
     assert set(res["metrics"]) >= {
         "plane_overhead_ms", "queue_wait_p95_ms",
         "ttft_p50_ms", "tpot_p50_ms",
-        "tokens_per_dispatch", "decode_step_ms",
+        "tokens_per_dispatch", "decode_program_ms",
         "decode_hbm_pct", "device_idle_pct.serve", "finished_requests"}
     assert res["metrics"]["finished_requests"]["value"] == 12
     assert res["device"]["busy_s"] > 0 and res["device"]["window_s"] > 0
     assert res["breakdown"]["device_ops"] and res["breakdown"]["idle_gaps"]
+    assert list(res)[-2:] == ["breakdown", "compared"]
 
 
 def test_serving_control_int8_kv_is_not_correct(root):
@@ -74,6 +83,21 @@ def test_serving_control_int8_kv_is_not_correct(root):
     res, out = tiny.run_cell(root, "tiny-long", seconds=8, control="int8kv")
     assert res["correct"] is False, out[-3000:]
     assert "OVER" in out
+    # the line itself says which number failed, beside its limit
+    over = {n for n, c in res["compared"].items() if c["value"] > c["limit"]}
+    assert over and over <= {"served_logit_gap_mean", "served_logit_gap_max"}
+    assert list(res)[-1] == "compared"
+
+
+def test_a_late_sender_is_not_correct_and_the_line_says_so(root):
+    """The guard on the harness's own sender: against a limit of
+    nought every sender is late. The outputs are sound, ``correct`` is
+    false all the same, and the line names the number that failed."""
+    res, out = tiny.run_cell(root, "tiny-late", seconds=4)
+    over = {n for n, c in res["compared"].items() if c["value"] > c["limit"]}
+    assert res["correct"] is False and res["failed"] == 0, out[-3000:]
+    assert over == {"generator_late_p99_ms"}
+    assert res["compared"]["generator_late_p99_ms"]["limit"] == 0
 
 
 def test_serving_token_altered_where_it_is_produced_is_not_correct(root):
@@ -102,6 +126,8 @@ def test_training_controls_are_not_correct(root, control):
                              control=control)
     assert res["correct"] is False, out[-3000:]
     assert "param_change_norm_gap" in out and "OVER" in out
+    gap = res["compared"]["param_change_norm_gap"]
+    assert gap["value"] > gap["limit"]
 
 
 def test_without_a_tpu_there_is_no_result():

@@ -100,6 +100,61 @@ def test_delivered_rate_does_not_swing_with_one_hand_over_at_the_edge():
     assert abs(inside - 128 / 0.58) < 2.0
 
 
+@pytest.mark.parametrize("p50_ms, limit_ms", [
+    (60.0, 250.0),      # a fast program: the floor decides
+    (1249.0, 250.0),
+    (1251.0, 250.2),    # a slow one: the share of its median TTFT
+    (5000.0, 1000.0),
+])
+def test_lateness_limit_is_the_larger_of_floor_and_share(p50_ms, limit_ms):
+    from benchmark.serve_open_loop_cell import late_limit_ms
+
+    cell = {"late_floor_ms": 250.0, "late_share_limit": 0.2}
+    assert late_limit_ms(cell, p50_ms) == pytest.approx(limit_ms)
+    real = manifest.cell("baichuan7b-chat-steady")
+    assert late_limit_ms(real, 1.0) == real["late_floor_ms"] >= 200.0
+    with pytest.raises(manifest.ManifestError, match="late_floor_ms"):
+        late_limit_ms(manifest.table(os.path.join(
+            manifest.BENCH_DIR, "cells", "deepseek7b-train-fsdp4.json")), 1.0)
+
+
+def test_lateness_is_read_by_rank_over_the_requests_sent():
+    from benchmark.serve_open_loop_cell import lateness_ms
+
+    rows = [{"late_s": 0.002}] * 140 + [{"late_s": 0.09}, {"late_s": 0.3},
+                                        {"late_s": None}]
+    late = lateness_ms(rows)        # 142 sent: rank int(0.99 * 141) = 139
+    assert late["max"] == pytest.approx(300.0)
+    assert late["p99"] == pytest.approx(2.0)
+    assert lateness_ms(rows + [{"late_s": 0.5}])["p99"] == pytest.approx(90.0)
+    assert lateness_ms([{"late_s": None}])["p99"] == math.inf
+
+
+@pytest.mark.parametrize("late_ms, correct", [(120.0, True), (251.0, False)])
+def test_result_line_carries_the_compared_numbers_last(late_ms, correct):
+    import json
+
+    from benchmark import harness as H
+
+    rows = H.print_comparison([
+        {"name": "served_logit_gap_mean", "value": 0.005, "limit": 0.012},
+        {"name": "compilations_in_window", "value": 0, "limit": 0},
+        {"name": "generator_late_p99_ms", "value": late_ms, "limit": 250.0}])
+    assert [r["held"] for r in rows] == [True, True, correct]
+    dev = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+           "memory_peak_bytes": 1}
+    metrics = {"setup_s": {"value": 1.0, "unit": "s"}}
+    line = json.loads(H.result_line(rows, 143, 0, metrics, dev))
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]
+    assert line["correct"] is correct
+    assert line["compared"]["generator_late_p99_ms"] == {
+        "value": late_ms, "limit": 250.0}
+    traced = json.loads(H.result_line(rows, 143, 0, metrics, dev,
+                                      {"device_ops": [["x", 1.0]]}))
+    assert list(traced)[-2:] == ["breakdown", "compared"]
+
+
 def test_interval_arithmetic():
     u = trace_reduce.union([(0, 2), (1, 3), (5, 6)])
     assert u == [(0, 3), (5, 6)] and trace_reduce.total(u) == 4

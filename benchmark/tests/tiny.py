@@ -75,19 +75,20 @@ def make_root(tmp: str) -> str:
     put("configs/tiny.json", TINY_CONFIG)
     put("traffic/tiny-chat.json", TINY_CHAT)
     put("traffic/tiny-train.json", TINY_TRAIN)
-    wrapper = "benchmark.workers.traced_replica"
-    put("cells/tiny-chat.json", {"rate_rps": 3.0, "grace_s": 30.0,
-                                 "check_requests": 3, "warm_new_tokens": 4,
-                                 "trace_after_s": 0.5, "trace_seconds": 1.0,
-                                 "late_share_limit": 20.0, "serving": {},
-                                 "traced_replica": wrapper})
+    chat = {"rate_rps": 3.0, "grace_s": 30.0, "check_requests": 3,
+            "warm_new_tokens": 4, "trace_after_s": 0.5, "trace_seconds": 1.0,
+            "late_share_limit": 20.0, "late_floor_ms": 250.0, "serving": {},
+            "traced_replica": "benchmark.workers.traced_replica"}
+    put("cells/tiny-chat.json", chat)
+    # A sender held to a limit of nought: it always runs late by some
+    # microseconds, so the guard on the sender is seen to bite.
+    put("traffic/tiny-late.json", TINY_CHAT)
+    put("cells/tiny-late.json", dict(chat, late_share_limit=0.0,
+                                     late_floor_ms=0.0))
     put("cells/tiny-train.json", {"traced_steps": 3})
     put("traffic/tiny-long.json", dict(
         TINY_CHAT, output_tokens={"dist": "uniform", "min": 12, "max": 20}))
-    big = {"rate_rps": 3.0, "grace_s": 30.0, "check_requests": 24,
-           "warm_new_tokens": 4, "trace_after_s": 0.2, "trace_seconds": 0.5,
-           "late_share_limit": 20.0, "serving": {},
-           "traced_replica": wrapper}
+    big = dict(chat, check_requests=24, trace_after_s=0.2, trace_seconds=0.5)
     put("cells/tiny-long.json", big)
     put("cells/tiny-broken.json", dict(
         big, traced_replica="benchmark.tests.broken_replica"))
@@ -108,6 +109,8 @@ def make_root(tmp: str) -> str:
         {"name": "tiny-long", "config": "tiny", "traffic": "tiny-long",
          "chips": 1, "why": "test"},
         {"name": "tiny-broken", "config": "tiny", "traffic": "tiny-long",
+         "chips": 1, "why": "test"},
+        {"name": "tiny-late", "config": "tiny", "traffic": "tiny-late",
          "chips": 1, "why": "test"}]
     for m in man["end_to_end"] + man["per_layer"]:
         if "workloads" in m:
@@ -115,7 +118,7 @@ def make_root(tmp: str) -> str:
                 "moves", m["name"]) else "tiny-chat"
             m["workloads"] = m["workloads"] + (
                 [kind] if kind == "tiny-train"
-                else ["tiny-chat", "tiny-long", "tiny-broken"])
+                else ["tiny-chat", "tiny-long", "tiny-broken", "tiny-late"])
     man["per_layer"].append(
         {"name": "finished_requests", "unit": "requests", "better": "higher",
          "source": "program_counter", "layer": "test", "moves": "ttft_p95_ms",

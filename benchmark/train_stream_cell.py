@@ -116,7 +116,7 @@ def run(man: Dict[str, Any], wl: Dict[str, Any], seed: int, seconds: float,
     rows = reference_compare.training_rows(w["check"], ref, limits)
     rows.append({"name": "compilations_in_window", "value": compiled,
                  "limit": 0})
-    correct = H.print_comparison(rows)
+    compared = H.print_comparison(rows)
 
     dev = dict(device, memory_peak_bytes=int(w["memory_peak_bytes"]))
     e2e = {"train_tokens_per_s": tokens_per_s, "setup_s": setup_s}
@@ -124,12 +124,12 @@ def run(man: Dict[str, Any], wl: Dict[str, Any], seed: int, seconds: float,
         metrics = {m["name"]: {"value": e2e[m["name"]], "unit": m["unit"]}
                    for m in manifest.metrics_for(man, "end_to_end",
                                                  wl["name"])}
-        return H.result_line(correct, len(steps), 0, metrics, dev)
+        return H.result_line(compared, len(steps), 0, metrics, dev)
     tr = w["trace"]
     dev.update(busy_s=tr["busy_s"], window_s=tr["window_s"])
     ctx = {"worker": w, "trace": tr, "cfg": cfg, "cell": cell,
            "mix": manifest.load_json(mix_path), "device": device,
            "seconds": seconds, "e2e": e2e}
     metrics = manifest.read_layer_metrics(man, wl["name"], ctx, bench_dir)
-    return H.result_line(correct, len(steps), 0, metrics, dev,
+    return H.result_line(compared, len(steps), 0, metrics, dev,
                          tr.get("breakdown"))
