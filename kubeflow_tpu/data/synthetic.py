@@ -28,7 +28,7 @@ _SPECS = {
     "imagenet-tiny": (100_000, 10_000, (64, 64, 3), 200, 1.2, 0.25),
     # Full ImageNet geometry (224^2, 1000-way) for input-shape probes:
     # separates a conv stack's MFU ceiling from the small-stem shapes
-    # the CIFAR examples use (bench resnet50 ladder).
+    # the CIFAR examples use (a resnet50 ladder).
     "imagenet-sim": (100_000, 10_000, (224, 224, 3), 1000, 1.2, 0.25),
 }
 

@@ -173,7 +173,7 @@ def random_lora_flat(cfg, rank: int, seed: int = 0,
                      std: float = 0.02) -> Dict[str, Dict[str, Any]]:
     """A synthetic full-target adapter (both factors random normal, so
     it actually changes the model — a fresh fine-tune's B is zero and
-    would be invisible): bench and tests use these where a real
+    would be invisible): tests use these where a real
     fine-tune would be wasted compile time."""
     rng = np.random.default_rng(seed)
     L = cfg.n_layers
@@ -541,8 +541,7 @@ class AdapterPool:
 
     def nbytes(self) -> int:
         """Device bytes of the adapter stacks (target + draft) — the
-        HBM cost of serving n_slots adapters over one base, the number
-        the ``lm_adapters_hbm_ratio`` bench headline divides by."""
+        HBM cost of serving n_slots adapters over one base."""
         import jax
 
         return int(sum(

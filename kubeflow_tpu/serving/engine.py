@@ -66,7 +66,7 @@ locations the monolithic prefill would (attention masks by cached
 position id, so a chunk's window attends its own tokens causally and
 everything earlier through the block table), and the final chunk
 lands the last real token's logits — greedy output stays
-byte-identical to the ``KFX_LM_ENGINE=0`` oracle. Chunked admission
+byte-identical to ``LMGenerator``. Chunked admission
 composes with prefix-cache hits (the cursor starts at the matched
 tail), preemption-by-recompute (a mid-prefill slot is a valid victim:
 pages freed, request re-queued whole), drain (a prefilling slot is
@@ -79,8 +79,8 @@ share.
 Exactness: attention masks by cached *position id* (-1 = empty), never
 by cache location, and decode writes land at the DENSE-EQUIVALENT
 location (prompt bucket + step), so greedy decode stays byte-identical
-to the one-shot oracle (asserted in tests/test_engine.py;
-``KFX_LM_ENGINE=0`` keeps the oracle serving for A/B). When the pool
+to the one-shot ``LMGenerator`` (models/generate.py, the oracle
+tests/test_engine.py asserts against). When the pool
 runs dry mid-decode the youngest slot is preempted and re-queued as a
 recompute continuation (its pages freed for the older slots); a
 request that cannot be placed at all fails with ``PageAllocError``
@@ -100,7 +100,7 @@ draft-propose scan -> target verify -> distribution-preserving accept
 -> rejected-tail KV invalidation (cursor rollback + position-id stamp,
 no page copies). Greedy acceptance is the temperature->0 limit of the
 residual-sampling rule (one-hot target probs), so greedy engine output
-stays BYTE-identical to the ``KFX_LM_ENGINE=0`` oracle — the standing
+stays BYTE-identical to ``LMGenerator`` — the standing
 parity contract — and sampled output preserves the target distribution
 exactly (accept d_i with min(1, p_i(d)/q_i(d)); on rejection sample
 the normalized residual max(p_i - q_i, 0); the bonus token after k
@@ -1139,8 +1139,7 @@ class DecodeEngine:
             if _flightrec.enabled_from_env() else None
         # Per-tenant usage ledger (serving/metering.py): exact prompt/
         # generated token counts by {tenant, qos, adapter}, billed on
-        # the admission/retirement funnel. None disables every hook
-        # (the bench's detached leg).
+        # the admission/retirement funnel. None disables every hook.
         from .metering import TenantLedger
 
         self.usage: Optional[TenantLedger] = TenantLedger()
@@ -1201,8 +1200,8 @@ class DecodeEngine:
     def prefix_stats(self) -> Dict[str, int]:
         """Cumulative prefix-cache counters (zeros while the cache is
         off): prompt tokens admitted and tokens served from cached
-        pages. Public surface for per-window deltas (bench's
-        shared-prefix leg computes its skipped fraction from these)."""
+        pages. Public surface for per-window deltas (the skipped
+        fraction is tokens_reused / prompt_tokens)."""
         reused = self._prefix.tokens_reused if self._prefix is not None \
             else 0
         return {"tokens_reused": reused,
@@ -1212,8 +1211,7 @@ class DecodeEngine:
         """Cumulative speculative-decode counters (zeros with the
         draft off): draft tokens proposed, proposals the target
         accepted, and slots degraded to non-speculative on draft-pool
-        shortfall. Public surface for per-window deltas (the bench
-        speculative leg computes its accept rate from these)."""
+        shortfall. Public surface for per-window deltas."""
         return {"proposed": self._spec_proposed,
                 "accepted": self._spec_accepted,
                 "degraded": self._spec_degraded}
@@ -1221,7 +1219,7 @@ class DecodeEngine:
     def adapter_stats(self) -> Dict[str, int]:
         """Cumulative adapter-pool counters (zeros without a pool):
         artifact loads, LRU evictions, slot capacity and free slots.
-        Public surface for bench/test deltas."""
+        Public surface for per-window deltas."""
         if self._apool is None:
             return {"loads": 0, "evictions": 0, "slots": 0, "free": 0}
         return {"loads": self._apool.loads,
@@ -1232,7 +1230,7 @@ class DecodeEngine:
     def weight_stats(self) -> Dict[str, Any]:
         """Cumulative weight-pool counters (zeros without a pool):
         artifact swap-ins, evictions, slot capacity, free slots and
-        the resident model names. Public surface for bench/test deltas
+        the resident model names. Public surface for per-window deltas
         and the server's JSON engine block."""
         if self._wpool is None:
             return {"loads": 0, "evictions": 0, "slots": 0, "free": 0,
@@ -1273,8 +1271,7 @@ class DecodeEngine:
         truncated weights, the adapter stacks and the logits buffer.
         The multi-tenant headline divides ``total`` by a base-only
         engine's: N adapters over ONE base costs base + stacks, vs ~N
-        bases for N merged deployments (docs/serving.md, BENCH
-        ``lm_adapters_hbm_ratio``)."""
+        bases for N merged deployments (docs/serving.md)."""
         import jax
 
         def nbytes(tree) -> int:
@@ -1292,8 +1289,7 @@ class DecodeEngine:
             if self._apool is not None else 0,
             # Pooled checkpoints BEYOND the resident default (whose
             # tree aliases self.params and is counted there): the
-            # marginal HBM cost of hosting N models on one replica —
-            # the lm_multimodel bench ratio's numerator delta.
+            # marginal HBM cost of hosting N models on one replica.
             "weights": max(0, self._wpool.nbytes() - nbytes(self.params))
             if self._wpool is not None else 0,
         }
@@ -1381,7 +1377,7 @@ class DecodeEngine:
                       "to peer acknowledgement).",
                       buckets=QUEUE_WAIT_BUCKETS).observe(
                           0.0, n=0, model=self.name)
-        # Engine truth, not a bench-derived number: capacity planning
+        # Engine truth, not a derived number: capacity planning
         # reads pool bytes = kv_pages x page_size x this gauge.
         reg.gauge("kfx_lm_kv_bytes_per_token",
                   "KV-cache bytes per cached token (entries + "

@@ -14,18 +14,15 @@ dtypes as strings) + ``params.msgpack``. The predictor serves a
 ``spec.<rev>.adapters`` — multi-tenant serving, docs/serving.md;
 absent = the revision's default adapter, "" = the base model.)
 
-Two decode backends share the same model and the same HTTP contract:
-
-  * the continuous-batching DecodeEngine (serving/engine.py, default) —
-    each prompt becomes its own slotted request, admitted mid-flight
-    between decode chunks, so concurrent traffic batches on-device and
-    short requests retire past long ones; speculative decoding rides on
-    top by default (a layer-truncated draft proposes, the target
-    verifies multi-token windows — ``KFX_LM_SPEC*`` knobs below,
-    ``KFX_LM_SPEC=0`` to disable, docs/serving.md for sizing);
-  * the one-shot LMGenerator (models/generate.py, ``KFX_LM_ENGINE=0``)
-    — run-to-completion; kept as the greedy-parity oracle and escape
-    hatch (it does not support ``stop_token``).
+Decoding is the continuous-batching DecodeEngine (serving/engine.py):
+each prompt becomes its own slotted request, admitted mid-flight
+between decode chunks, so concurrent traffic batches on-device and
+short requests retire past long ones; speculative decoding rides on
+top by default (a layer-truncated draft proposes, the target verifies
+multi-token windows — ``KFX_LM_SPEC*`` knobs below, ``KFX_LM_SPEC=0``
+to disable, docs/serving.md for sizing). The one-shot LMGenerator
+(models/generate.py) is not a serving mode: it is the oracle the tests
+hold the engine's greedy bytes to.
 
 Tokenization is caller-side (the platform is tokenizer-agnostic, like
 the reference's bring-your-own-model servers).
@@ -154,10 +151,13 @@ class LMPredictor(Predictor):
     """Generate-only predictor (classification ``:predict`` does not
     apply; the server routes ``:generate`` here).
 
-    ``KFX_LM_ENGINE`` (default on) selects the continuous-batching
-    DecodeEngine; ``=0`` falls back to the one-shot LMGenerator oracle.
-    ``n_slots`` is ``max_batch_size`` — with the engine the old hard
-    batch rejection becomes bounded queueing (engine.max_queue)."""
+    ``load()`` builds the continuous-batching DecodeEngine with
+    ``n_slots = max_batch_size``; more prompts than slots queue, up to
+    ``engine.max_queue``. ``self._engine`` is None only before
+    ``load()``: the guards on it below are the routes the hosting
+    server answers for a registered predictor that has not loaded yet
+    (/healthz, /debug/*, the model listing, /drain, migrate, close);
+    ``:generate`` and KV import are refused upstream until ``ready``."""
 
     def __init__(self, model_dir: str, name: str = "",
                  max_batch_size: int = 8, device: str = "default",
@@ -166,13 +166,11 @@ class LMPredictor(Predictor):
         self.name = name or "model"
         self.max_batch_size = max_batch_size
         self.device = device
-        self._gen = None
         self._engine = None
         self._rate = _RateWindow()
         self._warm_count = 0
         self._warm_thread: Optional[threading.Thread] = None
         self.vocab_size = 0
-        self.use_engine = os.environ.get("KFX_LM_ENGINE", "1") != "0"
         self.chunk_tokens = int(
             os.environ.get("KFX_LM_ENGINE_CHUNK", "8"))
         # Paged-KV knobs: page size in tokens; pool size in pages
@@ -209,8 +207,7 @@ class LMPredictor(Predictor):
         # re-export needed); "0" = the escape hatch — DEQUANTIZE an
         # int8 export at load and serve the full-precision path.
         # KFX_LM_KV_QUANT="int8" stores the engine's paged KV pools as
-        # int8 (+ per-token scale planes); engine-only — the one-shot
-        # oracle keeps its dense full-precision cache.
+        # int8 (+ per-token scale planes).
         # KFX_LM_QUANT_DRAFT="int8" quantizes only the speculative
         # DRAFT's weights (accept rate is the only thing at risk).
         self.quant = os.environ.get("KFX_LM_QUANT", "")
@@ -347,89 +344,74 @@ class LMPredictor(Predictor):
         if self.device == "cpu":
             params = jax.device_put(params, jax.devices("cpu")[0])
         self.vocab_size = cfg.vocab_size
-        if self.use_engine:
-            from .engine import DecodeEngine
+        from .engine import DecodeEngine
 
-            # Draft depth: explicit KFX_LM_SPEC_LAYERS, else a quarter
-            # of the target (floored at 1), always strictly shallower
-            # than the target — a 1-layer model has nothing to
-            # truncate, so speculation silently stays off there.
-            draft = 0
-            if self.spec and cfg.n_layers > 1 and not self.models:
-                # A weight pool excludes speculation (the draft would
-                # need its own per-model truncation); auto-disable
-                # rather than fail construction.
-                draft = self.spec_layers or max(1, cfg.n_layers // 4)
-                draft = min(draft, cfg.n_layers - 1)
-            # registry as a thunk: register() swaps self.metrics for
-            # the hosting server's registry AFTER load; the engine must
-            # follow it, not pin whatever was current at construction.
-            self._engine = DecodeEngine(
-                cfg, params, n_slots=self.max_batch_size,
-                chunk_tokens=self.chunk_tokens, name=self.name,
-                registry=lambda: self.metrics,
-                kv_page_size=self.kv_page_size,
-                kv_pages=self.kv_pages or None,
-                prefix_cache=self.prefix_cache,
-                draft_layers=draft,
-                propose_tokens=max(1, self.spec_tokens),
-                draft_kv_pages=self.spec_pages or None,
-                kv_quant="int8" if self.kv_quant == "int8" else "",
-                draft_quant="int8" if self.draft_quant == "int8" else "",
-                stall_threshold_s=self.stall_threshold_s,
-                prefill_chunk_tokens=max(0, self.prefill_chunk),
-                adapters=self.adapters or None,
-                adapter_slots=self.adapter_slots,
-                adapter_rank=self.adapter_rank,
-                adapter_default=self.adapter_default,
-                adapter_fallback=self.adapter_fallback,
-                qos_default=self.qos_default,
-                deadline_default_s=self.deadline_default_ms / 1000.0,
-                rate_limits=self.rate_limits or None,
-                rate_burst_s=self.rate_burst_s,
-                role=self.role,
-                # A prefill-tier replica always gets a sender, even
-                # before the operator's first :kvpeers push: an empty
-                # list raises TransferError and the handoff degrades
-                # to decoding locally (zero lost), exactly the severed
-                # -transfer path.
-                kv_peer_send=(self._kv_send
-                              if (self.kv_peers or self.role == "prefill")
-                              else None),
-                kv_offload_pages=max(0, self.kv_offload_pages),
-                models=self.models or None,
-                weight_slots=(max(0, self.weight_slots)
-                              if self.models else 0),
-                model_default=(self.model_default
-                               if self.models else ""),
-                model_idle_s=max(0.0, self.model_idle_s))
-            self._attach_usage()
-            buckets = self.warm_buckets or self._engine.prompt_buckets
-            # First bucket + the decode chunk warm synchronously —
-            # ready means "can serve one request without a compile".
-            self._engine.warm(buckets[:1])
-            self._set_warm(1)
-            rest = buckets[1:]
-        else:
-            from ..models.generate import LMGenerator
-
-            self._gen = LMGenerator(cfg, params)
-            L = self._gen.cfg.max_seq_len
-            buckets = self.warm_buckets or [
-                b for b in (8, 16, 32, 64, 128, 256, 512, 1024, 2048)
-                if b <= max(8, L // 2)]
-            # A length-b all-zeros prompt pads to exactly bucket b, so
-            # each warm call compiles that bucket's prefill+decode.
-            self._gen.generate([[0] * buckets[0]], max_new_tokens=8)
-            self._set_warm(1)
-            rest = buckets[1:]
+        # Draft depth: explicit KFX_LM_SPEC_LAYERS, else a quarter
+        # of the target (floored at 1), always strictly shallower
+        # than the target — a 1-layer model has nothing to
+        # truncate, so speculation silently stays off there.
+        draft = 0
+        if self.spec and cfg.n_layers > 1 and not self.models:
+            # A weight pool excludes speculation (the draft would
+            # need its own per-model truncation); auto-disable
+            # rather than fail construction.
+            draft = self.spec_layers or max(1, cfg.n_layers // 4)
+            draft = min(draft, cfg.n_layers - 1)
+        # registry as a thunk: register() swaps self.metrics for
+        # the hosting server's registry AFTER load; the engine must
+        # follow it, not pin whatever was current at construction.
+        self._engine = DecodeEngine(
+            cfg, params, n_slots=self.max_batch_size,
+            chunk_tokens=self.chunk_tokens, name=self.name,
+            registry=lambda: self.metrics,
+            kv_page_size=self.kv_page_size,
+            kv_pages=self.kv_pages or None,
+            prefix_cache=self.prefix_cache,
+            draft_layers=draft,
+            propose_tokens=max(1, self.spec_tokens),
+            draft_kv_pages=self.spec_pages or None,
+            kv_quant="int8" if self.kv_quant == "int8" else "",
+            draft_quant="int8" if self.draft_quant == "int8" else "",
+            stall_threshold_s=self.stall_threshold_s,
+            prefill_chunk_tokens=max(0, self.prefill_chunk),
+            adapters=self.adapters or None,
+            adapter_slots=self.adapter_slots,
+            adapter_rank=self.adapter_rank,
+            adapter_default=self.adapter_default,
+            adapter_fallback=self.adapter_fallback,
+            qos_default=self.qos_default,
+            deadline_default_s=self.deadline_default_ms / 1000.0,
+            rate_limits=self.rate_limits or None,
+            rate_burst_s=self.rate_burst_s,
+            role=self.role,
+            # A prefill-tier replica always gets a sender, even
+            # before the operator's first :kvpeers push: an empty
+            # list raises TransferError and the handoff degrades
+            # to decoding locally (zero lost), exactly the severed
+            # -transfer path.
+            kv_peer_send=(self._kv_send
+                          if (self.kv_peers or self.role == "prefill")
+                          else None),
+            kv_offload_pages=max(0, self.kv_offload_pages),
+            models=self.models or None,
+            weight_slots=(max(0, self.weight_slots)
+                          if self.models else 0),
+            model_default=(self.model_default
+                           if self.models else ""),
+            model_idle_s=max(0.0, self.model_idle_s))
+        self._attach_usage()
+        buckets = self.warm_buckets or self._engine.prompt_buckets
+        # First bucket + the decode chunk warm synchronously —
+        # ready means "can serve one request without a compile".
+        self._engine.warm(buckets[:1])
+        self._set_warm(1)
         self.ready = True
         # The remaining buckets compile on a background thread: the
         # first real request on a warm bucket pays nothing, and
         # readiness of the full bucket set is observable via the
         # kfx_lm_warm_buckets gauge instead of a first-request stall.
         self._warm_thread = threading.Thread(
-            target=self._warm_rest, args=(rest,), daemon=True,
+            target=self._warm_rest, args=(buckets[1:],), daemon=True,
             name=f"kfx-lm-warm-{self.name}")
         self._warm_thread.start()
 
@@ -466,26 +448,23 @@ class LMPredictor(Predictor):
         done = 1
         for b in buckets:
             try:
-                if self._engine is not None:
-                    self._engine.warm([b])
-                else:
-                    self._gen.generate([[0] * b], max_new_tokens=8)
+                self._engine.warm([b])
             except Exception:
                 continue  # a failed warm costs the first request, only
             done += 1
             self._set_warm(done)
 
     def engine_heartbeat(self) -> Optional[Dict[str, Any]]:
-        """Decode-loop liveness snapshot (None on the one-shot oracle
-        path, which has no persistent loop to wedge) — what turns the
-        hosting server's /healthz into a real liveness probe."""
+        """Decode-loop liveness snapshot (None before ``load()``: no
+        loop to wedge yet) — what turns the hosting server's /healthz
+        into a real liveness probe."""
         if self._engine is None:
             return None
         return self._engine.heartbeat()
 
     def flight_snapshot(self) -> Optional[Dict[str, Any]]:
         """The /debug/flight payload: the engine's flight ring plus
-        the current heartbeat (None when there is no engine or the
+        the current heartbeat (None before ``load()`` or when the
         recorder is disabled). Reading is safe from any HTTP thread —
         the ring is a deque the loop appends to atomically, and a
         wedged loop has stopped appending entirely."""
@@ -523,15 +502,13 @@ class LMPredictor(Predictor):
         it is idle (refcount 0, not the pinned default). Returns True
         when the slot was freed; False when unknown, not resident, or
         held by in-flight requests."""
-        if self._engine is None:
-            return False
         return self._engine.evict_model(name)
 
     def drain(self, wait_s: float = 0.0) -> bool:
         """Stop admitting and wait up to ``wait_s`` for in-flight
         generations to finish (serving/engine.py drain contract).
         Returns True when nothing is left in flight; trivially drained
-        on the engineless oracle path (its calls are synchronous)."""
+        before ``load()``."""
         if self._engine is None:
             return True
         return self._engine.drain(wait_s)
@@ -546,9 +523,6 @@ class LMPredictor(Predictor):
         the router's re-dispatched ``:generate`` body — the seeded
         recovery it would have sent anyway — claims the adopted
         generation here instead of recomputing from the prompt."""
-        if self._engine is None:
-            raise kvtransfer.TransferError(
-                "KV import requires the engine path (KFX_LM_ENGINE=1)")
         header = kvtransfer.peek(raw)
         key = str(header.get("resume", ""))
         q: "_queue.Queue[Optional[int]]" = _queue.Queue()
@@ -632,7 +606,7 @@ class LMPredictor(Predictor):
         request never has a resumable migration to claim."""
         adapter = p["adapter"]
         if adapter is None:
-            adapter = getattr(self._engine, "adapter_default", "")
+            adapter = self._engine.adapter_default
         kw = p["kw"]
         return kvtransfer.resume_key(
             p["prompts"][0], kw["max_new_tokens"], kw["temperature"],
@@ -659,12 +633,10 @@ class LMPredictor(Predictor):
                              "is required")
         if isinstance(prompts[0], int):  # single prompt convenience
             prompts = [prompts]
-        limit = (self._engine.max_queue if self._engine is not None
-                 else self.max_batch_size)
+        limit = self._engine.max_queue
         if len(prompts) > limit:
             raise ValueError(f"batch {len(prompts)} exceeds "
-                             f"{'queue capacity' if self._engine is not None else 'max_batch_size'} "
-                             f"{limit}")
+                             f"queue capacity {limit}")
         for p in prompts:
             arr = np.asarray(p)
             if arr.size == 0 or arr.min() < 0 or \
@@ -674,10 +646,6 @@ class LMPredictor(Predictor):
         stop = body.get("stop_token")
         if stop is not None:
             stop = int(stop)
-            if self._engine is None:
-                raise ValueError(
-                    "stop_token requires the engine path "
-                    "(KFX_LM_ENGINE=1)")
         # Per-request adapter selection (multi-tenant LoRA): a string
         # adapter name from spec.<rev>.adapters.artifacts; absent =
         # the revision's default adapter; "" = explicitly the base
@@ -685,10 +653,6 @@ class LMPredictor(Predictor):
         adapter = body.get("adapter")
         if adapter is not None and not isinstance(adapter, str):
             raise ValueError("adapter must be a string adapter name")
-        if adapter is not None and self._engine is None:
-            raise ValueError(
-                "adapter selection requires the engine path "
-                "(KFX_LM_ENGINE=1)")
         # Per-request model selection (multi-model weight pool): a
         # string name from spec.<rev>.models.artifacts; absent = the
         # revision's default model. Unknown names are a client 400; a
@@ -696,10 +660,6 @@ class LMPredictor(Predictor):
         model = body.get("model")
         if model is not None and not isinstance(model, str):
             raise ValueError("model must be a string model name")
-        if model is not None and self._engine is None:
-            raise ValueError(
-                "model selection requires the engine path "
-                "(KFX_LM_ENGINE=1)")
         # QoS class ("interactive"/"batch"): per-request override of
         # the revision default; validated by the engine.
         qos = body.get("qos")
@@ -746,19 +706,13 @@ class LMPredictor(Predictor):
         cap = _BACKEND_TIMEOUT_S - 2.0
         if deadline_s is not None:
             return min(deadline_s, cap)
-        return min(self._engine.request_timeout_s, cap) \
-            if self._engine is not None else cap
+        return min(self._engine.request_timeout_s, cap)
 
     def _record_generate(self, n_tokens: int, elapsed: float) -> None:
-        # Decode throughput is the LM serving headline (BENCH lm rows);
-        # exporting it makes `kfx top` and /metrics agree with bench.
+        # Decode throughput over the trailing window, for `kfx top` and
+        # /metrics. kfx_lm_generated_tokens_total is the engine's: it
+        # counts emitted tokens itself, per chunk.
         self._rate.record(n_tokens)
-        if self._engine is None:
-            # The engine counts emitted tokens itself, per chunk.
-            self.metrics.counter(
-                "kfx_lm_generated_tokens_total",
-                "Tokens generated since startup.").inc(n_tokens,
-                                                       model=self.name)
         self.metrics.gauge(
             "kfx_lm_tokens_per_second",
             "Decode throughput over the trailing 30s window.").set(
@@ -771,40 +725,35 @@ class LMPredictor(Predictor):
     def generate(self, body: Dict[str, Any]) -> Dict[str, Any]:
         p = self._parse_generate(body)
         t0 = time.perf_counter()
-        reqs = None
-        if self._engine is not None:
-            # A re-dispatched body whose generation migrated HERE
-            # attaches to the adopted in-flight request instead of
-            # recomputing (kv_import indexed it by resume key).
-            entry = (self._claim_resume(self._resume_key_for(p))
-                     if len(p["prompts"]) == 1 else None)
-            if entry is not None:
-                reqs = [entry["req"]]
-            else:
-                # submit_batch + result instead of generate():
-                # identical semantics (same atomic enqueue, same batch
-                # deadline), but the Request handles survive for the
-                # per-request timing block the flight recorder
-                # computes.
-                reqs = self._engine.submit_batch(
-                    p["prompts"], stop_token=p["stop"],
-                    adapter=p["adapter"], model=p["model"],
-                    qos=p["qos"],
-                    deadline_s=p["deadline_s"], tenant=p["tenant"],
-                    **p["kw"])
-            deadline = time.monotonic() \
-                + self._wait_budget_s(p["deadline_s"])
-            out = [r.result(max(0.001, deadline - time.monotonic()))
-                   for r in reqs]
+        # A re-dispatched body whose generation migrated HERE
+        # attaches to the adopted in-flight request instead of
+        # recomputing (kv_import indexed it by resume key).
+        entry = (self._claim_resume(self._resume_key_for(p))
+                 if len(p["prompts"]) == 1 else None)
+        if entry is not None:
+            reqs = [entry["req"]]
         else:
-            out = self._gen.generate(p["prompts"], **p["kw"])
+            # submit_batch + result instead of generate(): identical
+            # semantics (same atomic enqueue, same batch deadline),
+            # but the Request handles survive for the per-request
+            # timing block the flight recorder computes.
+            reqs = self._engine.submit_batch(
+                p["prompts"], stop_token=p["stop"],
+                adapter=p["adapter"], model=p["model"],
+                qos=p["qos"],
+                deadline_s=p["deadline_s"], tenant=p["tenant"],
+                **p["kw"])
+        deadline = time.monotonic() \
+            + self._wait_budget_s(p["deadline_s"])
+        out = [r.result(max(0.001, deadline - time.monotonic()))
+               for r in reqs]
         elapsed = time.perf_counter() - t0
         n_tokens = sum(len(ids) for ids in out)
         tps = n_tokens / elapsed if elapsed > 0 else 0.0
         self._record_generate(n_tokens, elapsed)
         result = {"generated_tokens": out,
                   "tokens_per_second": round(tps, 2)}
-        if reqs is not None and self._engine.flight is not None:
+        if self._engine.flight is not None:
             # Per-request latency attribution, one breakdown per
             # prompt in order — the server also folds the first into
             # the X-Kfx-Timing response header.
@@ -838,14 +787,6 @@ class LMPredictor(Predictor):
                 or skip < 0:
             raise ValueError("stream_skip must be an int >= 0")
         budget_s = self._wait_budget_s(p["deadline_s"])
-        if self._engine is None:
-            # One-shot oracle: generate fully, then replay as events —
-            # same wire contract, no incremental delivery.
-            t0 = time.perf_counter()
-            out = self._gen.generate(p["prompts"], **p["kw"])[0]
-            elapsed = time.perf_counter() - t0
-            self._record_generate(len(out), elapsed)
-            return iter(self._replay_events(out, skip, elapsed))
         # A re-dispatched stream whose generation migrated HERE
         # attaches to the adopted request: tokens that traveled with
         # the pages replay first (their indices continue the donor's
@@ -869,14 +810,6 @@ class LMPredictor(Predictor):
         head = f"event: {event}\n" if event else ""
         return (head + "data: " + json.dumps(obj)
                 + "\n\n").encode("utf-8")
-
-    def _replay_events(self, tokens, skip: int, elapsed: float):
-        for i, t in enumerate(tokens):
-            if i >= skip:
-                yield self._sse({"index": i, "token": int(t)})
-        tps = len(tokens) / elapsed if elapsed > 0 else 0.0
-        yield self._sse({"done": True, "n_tokens": len(tokens),
-                         "tokens_per_second": round(tps, 2)})
 
     def _stream_events(self, req, q, skip: int, budget_s: float,
                        prefix: int = 0) -> Iterator[bytes]:

@@ -372,7 +372,7 @@ class ModelServer:
         # piggyback throttle (_maybe_snapshot_flight).
         self._flight_snap_ts = 0.0
         # Server-reported latency distribution (so serving_p50_ms is a
-        # /metrics fact, not only a bench observation) + request/error
+        # /metrics fact, not only a client's observation) + request/error
         # counters, all rendered by the registry on /metrics.
         self.metrics = MetricsRegistry()
         self.latency = self.metrics.histogram(
@@ -433,8 +433,8 @@ class ModelServer:
 
         class Server(ThreadingHTTPServer):
             # Default listen backlog is 5: a burst of concurrent clients
-            # (the bench's 32-connection load leg) overflows it and the
-            # kernel resets the excess SYNs. Size it for bursty fleets.
+            # (32 connections at once) overflows it and the kernel
+            # resets the excess SYNs. Size it for bursty fleets.
             request_queue_size = 128
 
         self.httpd = Server((host, port), Handler)
@@ -493,8 +493,8 @@ class ModelServer:
 
     def _latency_summary(self) -> Dict[str, Dict[str, Optional[float]]]:
         """Server-reported per-model p50/p99 (ms) from the request
-        histogram — the number bench-observed serving_p50_ms should
-        agree with (±bucket resolution)."""
+        histogram — the number a client-observed p50 should agree
+        with (±bucket resolution)."""
         out: Dict[str, Dict[str, Optional[float]]] = {}
         for name in self.predictors:
             if not self.latency.count(model=name):

@@ -56,7 +56,7 @@ the ``kfx_lm_weight_swap_seconds`` histogram, an
 ``kfx_autoscaler_cold_start_seconds{mode="swap"}`` observation — the
 central scraper stamps namespace/isvc/revision, so swap cold starts
 land on the SAME fleet histogram as the operator's ``mode="spawn"``
-process respawns, and the bench headline is one query. The
+process respawns, and swap against respawn is one query. The
 ``weights.load`` chaos point (docs/chaos.md) injects a delayed/failed
 artifact read during the swap.
 
@@ -235,7 +235,7 @@ class WeightPool:
     def nbytes(self) -> int:
         """Device bytes of every resident tree — the HBM cost of
         hosting the pool, the number ``engine.hbm_bytes()["weights"]``
-        and the ``lm_multimodel`` bench ratio read."""
+        reads."""
         total = 0
         for t in self._trees:
             if t is None:

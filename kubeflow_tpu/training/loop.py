@@ -83,7 +83,7 @@ class TrainLoop:
         self._obs_rate = obs.gauge(
             "kfx_train_examples_per_second",
             "Training throughput of the most recent dispatch.")
-        # Several loops can share one process (bench ladders, HPO
+        # Several loops can share one process (model ladders, HPO
         # trials); the model label keeps their distributions apart.
         self._obs_model = type(model).__name__
 
@@ -381,8 +381,8 @@ def _make_optimizer(name: str, lr: float, weight_decay: float
     Hyperparameters ride in opt_state as runtime values
     (optax.inject_hyperparams), NOT as trace constants: every HPO trial
     then reuses ONE compiled step from the persistent cache instead of
-    recompiling per sampled learning rate (measured 1-3s XLA:CPU /
-    5-15s XLA:TPU compile per distinct lr in the Katib sweep bench).
+    recompiling per sampled learning rate (1-3s XLA:CPU / 5-15s XLA:TPU
+    compile per distinct lr in a Katib sweep; not a ledger number).
     The configured values are returned alongside so a checkpoint resume
     can re-assert them over the checkpointed ones
     (TrainLoop.reapply_hyperparams)."""

@@ -20,6 +20,8 @@ from kubeflow_tpu.vmeshenv import virtual_mesh_env  # noqa: E402
 
 assert "jax" not in sys.modules, "jax imported before tests/conftest.py"
 os.environ.update(virtual_mesh_env(8))
+# test_benchmark_rehearsal_*.py import their cases from there.
+pytest.register_assert_rewrite("benchmark.tests")
 
 
 @pytest.fixture

@@ -477,9 +477,9 @@ class TestAcceptanceHBM:
         """One engine serves 8 DIFFERENT adapters in one wave (every
         slot wearing its own), with measured device bytes <= 1.5x a
         base-only engine of the same shape — the N-tenants-for-one-
-        base economics (BENCH lm_adapters_hbm_ratio is the full-size
-        headline; this pins the accounting and the concurrency at
-        unit scale)."""
+        base economics (this pins the accounting and the concurrency
+        at unit scale; the full-size ratio is not measured on the
+        chip)."""
         from kubeflow_tpu.serving.adapters import random_lora_flat
         from kubeflow_tpu.serving.engine import DecodeEngine
         from kubeflow_tpu.serving.export import export_adapter

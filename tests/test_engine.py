@@ -1204,9 +1204,8 @@ class TestSpeculativeDistribution:
 
 
 class TestEngineThroughput:
-    # ~12s soak whose acceptance number (>= 3x concurrent speedup)
-    # is pinned on the BENCH_CONTRACT line (lm_engine_speedup,
-    # test_bench_guard) — tier-2 keeps the in-test proof.
+    # ~12s soak: tier-2 keeps the in-test proof of the acceptance
+    # number (>= 3x concurrent speedup).
     @pytest.mark.slow
     def test_concurrent_throughput_3x(self):
         """Acceptance criterion: 8 concurrent single-prompt requests
@@ -1261,11 +1260,10 @@ class TestEngineThroughput:
 
 class TestEngineServing:
     @pytest.fixture()
-    def lm_server(self, tiny_lm, tmp_path, monkeypatch):
+    def lm_server(self, tiny_lm, tmp_path):
         from kubeflow_tpu.serving.lm_server import LMPredictor, export_lm
         from kubeflow_tpu.serving.server import ModelServer
 
-        monkeypatch.setenv("KFX_LM_ENGINE", "1")
         cfg, params = tiny_lm
         export_lm(str(tmp_path / "lm"), cfg, params)
         p = LMPredictor(str(tmp_path / "lm"), name="lm")
@@ -1346,30 +1344,23 @@ class TestEngineServing:
             model="lm") >= 2
 
     def test_engine_parity_with_oracle_predictor(self, tiny_lm,
-                                                 tmp_path, monkeypatch):
-        """KFX_LM_ENGINE=0 serves the one-shot oracle; the engine
-        path's greedy responses are byte-identical to it."""
+                                                 tmp_path):
+        """The predictor's greedy :generate responses are
+        byte-identical to the one-shot LMGenerator oracle's."""
+        from kubeflow_tpu.models.generate import LMGenerator
         from kubeflow_tpu.serving.lm_server import LMPredictor, export_lm
 
         cfg, params = tiny_lm
         export_lm(str(tmp_path / "lm"), cfg, params)
-        monkeypatch.setenv("KFX_LM_ENGINE", "0")
-        oracle = LMPredictor(str(tmp_path / "lm"), name="lm",
-                             warm_buckets=[8])
-        oracle.load()
-        assert oracle._engine is None  # flag respected
-        monkeypatch.setenv("KFX_LM_ENGINE", "1")
         engine = LMPredictor(str(tmp_path / "lm"), name="lm",
                              warm_buckets=[8])
         engine.load()
         try:
-            body = {"prompt_tokens": [[5, 9, 11], [2], [1, 2, 3, 4]],
-                    "max_new_tokens": 10}
-            assert engine.generate(dict(body))["generated_tokens"] == \
-                oracle.generate(dict(body))["generated_tokens"]
-            with pytest.raises(ValueError, match="stop_token"):
-                oracle.generate({"prompt_tokens": [[1]],
-                                 "stop_token": 3})
+            prompts = [[5, 9, 11], [2], [1, 2, 3, 4]]
+            body = {"prompt_tokens": prompts, "max_new_tokens": 10}
+            assert engine.generate(body)["generated_tokens"] == \
+                LMGenerator(cfg, params).generate(
+                    prompts, max_new_tokens=10)
         finally:
             engine.close()
 
@@ -1386,7 +1377,6 @@ class TestEngineServing:
 
         cfg, params = tiny_lm
         export_lm(str(tmp_path / "lm"), cfg, params)
-        monkeypatch.setenv("KFX_LM_ENGINE", "1")
         monkeypatch.setenv("KFX_LM_QUANT", "int8")
         monkeypatch.setenv("KFX_LM_KV_QUANT", "int8")
         p = LMPredictor(str(tmp_path / "lm"), name="lm",
@@ -1411,12 +1401,10 @@ class TestEngineServing:
         finally:
             srv.stop()
 
-    def test_overload_is_503_with_retry_after(self, tiny_lm, tmp_path,
-                                              monkeypatch):
+    def test_overload_is_503_with_retry_after(self, tiny_lm, tmp_path):
         from kubeflow_tpu.serving.lm_server import LMPredictor, export_lm
         from kubeflow_tpu.serving.server import ModelServer
 
-        monkeypatch.setenv("KFX_LM_ENGINE", "1")
         cfg, params = tiny_lm
         export_lm(str(tmp_path / "lm"), cfg, params)
         p = LMPredictor(str(tmp_path / "lm"), name="lm",
@@ -1745,12 +1733,11 @@ class TestRequestPlaneServing:
         return out
 
     @pytest.fixture()
-    def predictor(self, tiny_lm, tmp_path, monkeypatch):
+    def predictor(self, tiny_lm, tmp_path):
         from kubeflow_tpu.serving.lm_server import LMPredictor, export_lm
 
         cfg, params = tiny_lm
         export_lm(str(tmp_path / "lm"), cfg, params)
-        monkeypatch.setenv("KFX_LM_ENGINE", "1")
         p = LMPredictor(str(tmp_path / "lm"), name="lm",
                         warm_buckets=[8])
         p.load()
@@ -1799,30 +1786,23 @@ class TestRequestPlaneServing:
                                        "deadline_ms": True})
 
     def test_oracle_stream_frames_byte_identical(self, tiny_lm,
-                                                 tmp_path, monkeypatch):
-        """KFX_LM_ENGINE=0: the one-shot oracle replays the SAME wire
-        frames the engine path streams (token frames byte-identical),
-        so the router's recovery math holds across engine modes."""
-        from kubeflow_tpu.serving.lm_server import LMPredictor, export_lm
+                                                 predictor):
+        """The streamed token frames are byte-identical to frames
+        built from the one-shot LMGenerator oracle's tokens (index,
+        token), so the router's recovery math rests on the oracle's
+        bytes, not on the engine agreeing with itself."""
+        from kubeflow_tpu.models.generate import LMGenerator
 
         cfg, params = tiny_lm
-        export_lm(str(tmp_path / "lm"), cfg, params)
-        body = {"prompt_tokens": [[5, 9, 11]], "max_new_tokens": 8}
-        monkeypatch.setenv("KFX_LM_ENGINE", "1")
-        eng_p = LMPredictor(str(tmp_path / "lm"), name="lm",
-                            warm_buckets=[8])
-        eng_p.load()
-        try:
-            eng_frames = list(eng_p.generate_stream(dict(body)))
-        finally:
-            eng_p.close()
-        monkeypatch.setenv("KFX_LM_ENGINE", "0")
-        orc_p = LMPredictor(str(tmp_path / "lm"), name="lm")
-        orc_p.load()
-        assert orc_p._engine is None
-        orc_frames = list(orc_p.generate_stream(dict(body)))
-        assert orc_frames[:-1] == eng_frames[:-1]  # token frames
-        assert json.loads(orc_frames[-1].split(b"data: ", 1)[1])[
+        prompt = [5, 9, 11]
+        frames = list(predictor.generate_stream(
+            {"prompt_tokens": [prompt], "max_new_tokens": 8}))
+        oracle = LMGenerator(cfg, params).generate(
+            [prompt], max_new_tokens=8)[0]
+        assert frames[:-1] == [
+            predictor._sse({"index": i, "token": int(t)})
+            for i, t in enumerate(oracle)]
+        assert json.loads(frames[-1].split(b"data: ", 1)[1])[
             "n_tokens"] == 8
 
     def test_server_sse_endpoint_and_admission(self, tiny_lm, tmp_path,
@@ -1837,7 +1817,6 @@ class TestRequestPlaneServing:
 
         cfg, params = tiny_lm
         export_lm(str(tmp_path / "lm"), cfg, params)
-        monkeypatch.setenv("KFX_LM_ENGINE", "1")
         # 4 tok/s * 5s burst = 20-token budget; each request weighs
         # 3 prompt + 10 new = 13. Overdraw semantics: request one
         # debits to 7, request two to -6, request THREE sheds (and the

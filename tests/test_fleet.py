@@ -145,9 +145,7 @@ class TestServerSelfHealing:
         from kubeflow_tpu.serving.server import ModelServer
 
         saved = {k: os.environ.get(k)
-                 for k in ("KFX_LM_ENGINE", "KFX_LM_SPEC",
-                           "KFX_LM_STALL_S")}
-        os.environ["KFX_LM_ENGINE"] = "1"
+                 for k in ("KFX_LM_SPEC", "KFX_LM_STALL_S")}
         os.environ["KFX_LM_SPEC"] = "0"
         os.environ["KFX_LM_STALL_S"] = "0.5"
         p = LMPredictor(lm_export, name="lm", warm_buckets=[8])
@@ -860,10 +858,9 @@ class TestPrefixAffinity:
         from kubeflow_tpu.serving.server import ModelServer
 
         saved = {k: os.environ.get(k)
-                 for k in ("KFX_LM_ENGINE", "KFX_LM_SPEC",
-                           "KFX_LM_KV_PAGE_SIZE",
+                 for k in ("KFX_LM_SPEC", "KFX_LM_KV_PAGE_SIZE",
                            "KFX_LM_PREFILL_CHUNK")}
-        os.environ.update({"KFX_LM_ENGINE": "1", "KFX_LM_SPEC": "0",
+        os.environ.update({"KFX_LM_SPEC": "0",
                            "KFX_LM_KV_PAGE_SIZE": "16",
                            "KFX_LM_PREFILL_CHUNK": "16"})
         servers = []

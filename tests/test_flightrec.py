@@ -331,20 +331,16 @@ class TestFlightHTTP:
         from kubeflow_tpu.serving.lm_server import LMPredictor, export_lm
         from kubeflow_tpu.serving.server import ModelServer
 
-        os.environ["KFX_LM_ENGINE"] = "1"
-        try:
-            cfg, params = tiny_lm
-            root = str(tmp_path_factory.mktemp("flight-lm"))
-            export_lm(os.path.join(root, "lm"), cfg, params)
-            p = LMPredictor(os.path.join(root, "lm"), name="lm")
-            p.load()
-            srv = ModelServer(port=0)
-            srv.register(p)
-            srv.start()
-            yield srv, p
-            srv.stop()
-        finally:
-            os.environ.pop("KFX_LM_ENGINE", None)
+        cfg, params = tiny_lm
+        root = str(tmp_path_factory.mktemp("flight-lm"))
+        export_lm(os.path.join(root, "lm"), cfg, params)
+        p = LMPredictor(os.path.join(root, "lm"), name="lm")
+        p.load()
+        srv = ModelServer(port=0)
+        srv.register(p)
+        srv.start()
+        yield srv, p
+        srv.stop()
 
     def _get(self, port, path):
         with urllib.request.urlopen(

@@ -416,7 +416,6 @@ class TestFleetMigrationE2E:
         from kubeflow_tpu.serving.router import Router
         from kubeflow_tpu.serving.server import ModelServer
 
-        monkeypatch.setenv("KFX_LM_ENGINE", "1")
         monkeypatch.setenv("KFX_LM_SPEC", "0")
         monkeypatch.setenv("KFX_LM_KV_PAGE_SIZE", "16")
         monkeypatch.setenv("KFX_LM_ENGINE_CHUNK", "4")

@@ -452,7 +452,7 @@ class TestTenantLedger:
             req.result(60)
             led = eng.usage
             assert led.totals("base")["requests"] == 1
-            # usage=None disables the hooks (the bench off-leg).
+            # usage=None disables the hooks.
             eng.usage = None
             eng.generate([[3, 4]], max_new_tokens=4)
             assert led.totals("base")["requests"] == 1  # unchanged
@@ -689,8 +689,15 @@ class TestSLOFleetE2E:
                 base = [r for r in rows if r["tenant"] == "base"]
                 return base[0] if base else None
 
-            wait_for(lambda: (totals() or {}).get("windowRequests")
-                     == expect_req, 30,
+            # A request is billed at admission and its generated tokens
+            # at retirement: a scrape between the two already counts the
+            # last request, so wait for its tokens too.
+            def settled():
+                row = totals() or {}
+                return (row.get("windowRequests") == expect_req
+                        and row.get("generatedTokens") == 6 * expect_req)
+
+            wait_for(settled, 30,
                      "scraped ledger totals matching served requests")
             row = totals()
             assert row["promptTokens"] == 4 * expect_req

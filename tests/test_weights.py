@@ -466,7 +466,6 @@ class TestFleetSoak:
         from kubeflow_tpu.serving.server import ModelServer
 
         sources, _ = exports
-        monkeypatch.setenv("KFX_LM_ENGINE", "1")
         monkeypatch.setenv("KFX_LM_MODELS", json.dumps(
             {n: sources[n] for n in MODELS}))
         monkeypatch.setenv("KFX_LM_MODEL_DEFAULT", "m0")
