@@ -7,6 +7,14 @@ NamedShardings from `parallel.mesh` rules, the batch is sharded over
 inserts every collective — gradient reduce-scatters for fsdp, all-reduces
 for tp, all-to-alls for ep. Nothing here calls a collective by hand.
 
+One place says *where* a collective goes, still as a sharding: the
+chunked loss (``_chunked_ce``) constrains the cast head to its spec
+without "data" before its chunk loop and sums the head's gradient per
+data shard inside it, so the head is gathered once a step and its
+gradient reduced once, not once a chunk each way. That function also
+carries the file's one hand-written differentiation rule: the loss and
+its gradients come out of the same pass over the chunks.
+
 bf16 compute / f32 state, donated buffers, global-norm clipping, cosine
 schedule with warmup, MoE load-balance aux loss.
 """
@@ -193,9 +201,11 @@ class LMTrainLoop:
         if self._state_shardings is None:
             # Trace under the mesh: the model's cp/sp paths contain bare-
             # PartitionSpec sharding constraints that need an ambient mesh.
+            # (An abstract key: nothing here touches a device, so the
+            # mesh may be one that is only described, as in an AOT compile.)
             with jax.set_mesh(self.mesh):
                 abs_state = jax.eval_shape(
-                    self._init_fn, jax.random.PRNGKey(self.hp.seed))
+                    self._init_fn, jax.ShapeDtypeStruct((2,), jnp.uint32))
             axes = param_logical_axes(abs_state.params)
             params_sh = tree_shardings(self.mesh, axes, self.rules,
                                        abs_state.params)
@@ -220,47 +230,114 @@ class LMTrainLoop:
 
     # -- loss ---------------------------------------------------------------
     def _chunked_ce(self, params, hidden, targets):
-        """lm_head + CE per sequence chunk (cfg.loss_chunk tokens) via
-        lax.scan, chunk body rematted: the [B, S, vocab] f32 logits never
-        exist whole — only one [B, C, vocab] transient at a time. Returns
-        (mean ce, mean accuracy); grads to lm_head flow through the
-        manual einsum against params["lm_head"]["kernel"] (same math as
-        the nn.Dense it replaces: use_bias=False, cfg.dtype compute,
-        f32 softmax)."""
+        """lm_head + CE per sequence chunk (cfg.loss_chunk tokens) in ONE
+        lax.scan that also makes the gradients: the [B, S, vocab] f32
+        logits never exist whole — only one [B, C, vocab] transient at a
+        time — and each chunk's logits are computed once. Returns (mean
+        ce, mean accuracy). Same math as the nn.Dense it replaces
+        (use_bias=False, cfg.dtype matmul inputs, f32 softmax).
+
+        What does not depend on the chunk stays out of the loop: the
+        head is cast to cfg.dtype and gathered over the fsdp axis once
+        (``loss_head_gather``; the constraint drops "data" from the
+        kernel's spec and nothing else, so without fsdp it is a no-op
+        and a tp-sharded vocabulary stays sharded), and the head's
+        gradient is summed over chunks per data shard in f32 (a leading
+        ``dp`` axis sharded over "data") and reduced across chips once,
+        after the loop.
+
+        The differentiation rule is written by hand because autodiff
+        cannot give that: it would rerun the logits matmul in the
+        backward loop, and a cfg.dtype operand hoisted out of the scan
+        would have its cotangent summed in cfg.dtype. The forward rule
+        computes ``softmax - onehot``, ``dh`` and ``h^T dlogits`` beside
+        the loss; the backward rule only scales them by the incoming
+        cotangent. Undifferentiated (``evaluate``) the same loop runs
+        without the gradient half."""
         cfg = self.cfg
         C = cfg.loss_chunk
         B, S, D = hidden.shape
         if S % C:
             raise ValueError(f"seq len {S} not divisible by "
                              f"loss_chunk={C}")
-        n = S // C
+        n, dp = S // C, self.plan.dp
         kernel = params["lm_head"]["kernel"]
-        h = hidden.reshape(B, n, C, D).transpose(1, 0, 2, 3)  # [n,B,C,D]
-        t = targets.reshape(B, n, C).transpose(1, 0, 2)
+        head = {"lm_head": {"kernel": kernel}}  # the spec the state gave it
+        kernel_spec = tree_shardings(
+            self.mesh, param_logical_axes(head), self.rules,
+            head)["lm_head"]["kernel"].spec
+        vocab_axis = kernel_spec[1]
+        cons = lambda x, *spec: jax.lax.with_sharding_constraint(
+            x, NamedSharding(self.mesh, P(*spec)))
 
-        def body(carry, xs):
-            h_c, t_c = xs
+        def chunks(kernel, hidden, targets, with_grads):
+            with jax.named_scope("loss_head_gather"):
+                w = cons(kernel.astype(cfg.dtype), None, vocab_axis)
+            h = hidden.reshape(B, n, C, D).transpose(1, 0, 2, 3)  # [n,B,C,D]
+            # Rows stay on their data shard and whole in D: left to its
+            # propagation, XLA may carry ln_f's fsdp shard of D into the
+            # loop and gather the head there again.
+            h = cons(h, P.UNCONSTRAINED, AXIS_DATA, P.UNCONSTRAINED, None)
+            t = targets.reshape(B, n, C).transpose(1, 0, 2)
 
-            def chunk(h_c):
-                logits = jnp.einsum(
-                    "bcd,dv->bcv", h_c.astype(cfg.dtype),
-                    kernel.astype(cfg.dtype)).astype(jnp.float32)
-                ce = optax.softmax_cross_entropy_with_integer_labels(
-                    logits, t_c)
-                hit = (logits.argmax(-1) == t_c).astype(jnp.float32)
-                return jnp.sum(ce), jnp.sum(hit)
+            def body(carry, xs):
+                h_c, t_c = xs
+                h_c = h_c.astype(cfg.dtype)
+                with jax.named_scope("loss_chunk"):
+                    logits = jnp.einsum(
+                        "bcd,dv->bcv", h_c, w).astype(jnp.float32)
+                    logz = jax.nn.logsumexp(logits, axis=-1, keepdims=True)
+                    onehot = jax.nn.one_hot(t_c, logits.shape[-1],
+                                            dtype=jnp.bool_)
+                    ce = logz[..., 0] - jnp.sum(
+                        jnp.where(onehot, logits, 0.0), axis=-1)
+                    hit = (logits.argmax(-1) == t_c).astype(jnp.float32)
+                    ce_s, hit_s = carry[0] + ce.sum(), carry[1] + hit.sum()
+                    if not with_grads:
+                        return (ce_s, hit_s), None
+                    dlogits = (jnp.exp(logits - logz)
+                               - onehot.astype(jnp.float32)
+                               ).astype(cfg.dtype)
+                    dh_c = jnp.einsum("bcv,dv->bcd", dlogits, w,
+                                      preferred_element_type=jnp.float32)
+                    # Per data shard: no chip adds another's rows here.
+                    dw = carry[2] + jnp.einsum(
+                        "pbcd,pbcv->pdv",
+                        h_c.reshape(dp, B // dp, C, D),
+                        dlogits.reshape(dp, B // dp, C, -1),
+                        preferred_element_type=jnp.float32)
+                return (ce_s, hit_s, dw), dh_c
 
-            # prevent_cse=False: the chunk body lives inside lax.scan,
-            # where CSE across iterations cannot happen anyway — the
-            # guard only blocks optimisations (same tuning as the layer
-            # stack's nn.remat in models/transformer.py).
-            with jax.named_scope("loss_chunk"):
-                ce_s, hit_s = jax.checkpoint(
-                    chunk, prevent_cse=False)(h_c)
-            return (carry[0] + ce_s, carry[1] + hit_s), None
+            init = (jnp.float32(0.0), jnp.float32(0.0))
+            if not with_grads:
+                return jax.lax.scan(body, init, (h, t))[0], None
+            dw0 = cons(jnp.zeros((dp,) + kernel.shape, jnp.float32),
+                       AXIS_DATA, None, vocab_axis)
+            (ce_s, hit_s, dw), dh = jax.lax.scan(body, init + (dw0,), (h, t))
+            dw = cons(dw.sum(0), *kernel_spec)
+            # The backward pass starts from dh: held behind the same
+            # barrier, it cannot start before the reduction has freed
+            # the per-shard sums (left free, the scheduler keeps them
+            # through the whole backward layer scan).
+            dw, dh = jax.lax.optimization_barrier((dw, dh))
+            dh = dh.transpose(1, 0, 2, 3).reshape(B, S, D)
+            return (ce_s, hit_s), (dw, dh)
 
-        init = (jnp.float32(0.0), jnp.float32(0.0))
-        (ce_sum, hit_sum), _ = jax.lax.scan(body, init, (h, t))
+        @jax.custom_vjp
+        def sums(kernel, hidden, targets):
+            return chunks(kernel, hidden, targets, with_grads=False)[0]
+
+        def sums_fwd(kernel, hidden, targets):
+            return chunks(kernel, hidden, targets, with_grads=True)
+
+        def sums_bwd(grads, cotangents):
+            dw, dh = grads
+            g = cotangents[0]  # of the summed ce; hits carry no gradient
+            return ((dw * g).astype(kernel.dtype),
+                    (dh * g).astype(hidden.dtype), None)
+
+        sums.defvjp(sums_fwd, sums_bwd)
+        ce_sum, hit_sum = sums(kernel, hidden, targets)
         total = B * S
         return ce_sum / total, hit_sum / total
 

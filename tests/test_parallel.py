@@ -265,6 +265,64 @@ class TestShardedTraining:
         assert np.allclose(results[0][0], results[8][0], atol=2e-3), results
         assert abs(results[0][1] - results[8][1]) < 1e-3, results
 
+    @pytest.mark.parametrize("layout", [
+        {}, {"fsdp": True}, {"tp": 2, "fsdp": True}],
+        ids=["dp", "fsdp", "tp2-fsdp"])
+    def test_one_pass_loss_gives_whole_logits_gradients(self, tiny_cfg,
+                                                        layout):
+        """The chunked loss makes its gradients by a hand-written rule
+        in the same loop as the loss (parallel/lm_train.py
+        ``_chunked_ce``): after one ``value_and_grad`` in float32 it
+        gives the whole-logits path's loss, accuracy and every leaf's
+        gradient, and ``evaluate`` (the same loop undifferentiated)
+        gives the differentiated loss."""
+        import dataclasses
+
+        import jax
+        import jax.numpy as jnp
+
+        from kubeflow_tpu.data.lm import LMDataset
+        from kubeflow_tpu.parallel.lm_train import LMHyperParams, LMTrainLoop
+        from kubeflow_tpu.parallel.mesh import make_mesh
+
+        tokens = next(LMDataset(vocab_size=tiny_cfg.vocab_size,
+                                seq_len=32).batches(16))
+        got = {}
+        for chunk in (0, 8):
+            cfg = dataclasses.replace(tiny_cfg, dtype=jnp.float32,
+                                      loss_chunk=chunk)
+            mesh, plan = make_mesh(8, **layout)
+            loop = LMTrainLoop(cfg, mesh, plan, LMHyperParams(seed=0))
+            state = loop.init_state()
+            with jax.set_mesh(mesh):
+                (loss, acc), grads = jax.jit(jax.value_and_grad(
+                    loop._loss_fn, has_aux=True))(
+                        state.params, loop.global_batch(tokens))
+            got[chunk] = (float(loss), float(acc), jax.device_get(grads),
+                          loop.evaluate(state, tokens)["loss"])
+        (loss, acc, grads, _), (c_loss, c_acc, c_grads, c_eval) = \
+            got[0], got[8]
+        assert abs(c_loss - loss) < 1e-5 and c_acc == acc, (got[0][:2],
+                                                            got[8][:2])
+        assert abs(c_eval - c_loss) < 1e-5, (c_eval, c_loss)
+        def gaps(a_tree, b_tree, part=lambda leaf: leaf):
+            """Per leaf, the widest difference relative to the leaf's
+            largest entry (the sums are reassociated float32)."""
+            return {
+                jax.tree_util.keystr(path): float(
+                    np.abs(part(a) - part(b)).max() / np.abs(part(a)).max())
+                for (path, a), b in zip(
+                    jax.tree_util.tree_leaves_with_path(a_tree),
+                    jax.tree_util.tree_leaves(b_tree))}
+
+        every = gaps(grads, c_grads)
+        # (the stacked leaves' last row is the last block)
+        last_block = gaps(grads["layers"], c_grads["layers"],
+                          part=lambda leaf: leaf[-1])
+        head = every["['lm_head']['kernel']"]
+        assert max(every.values()) < 1e-5, (
+            f"lm_head {head:.2e}; last block {last_block}; all {every}")
+
     def test_loss_chunk_must_divide_seq(self, tiny_cfg):
         import dataclasses
 

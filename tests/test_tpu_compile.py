@@ -24,10 +24,9 @@ TOPOLOGY = "v5e:2x2"
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -39,9 +38,16 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 # (batch, heads, seq, head_dim): the K/V block of every kernel is the
@@ -211,6 +217,88 @@ def test_engine_programs_write_the_stacked_pool_in_place(one_chip,
     if program == "prefill_256":
         # (the decode chunk keeps relayout copies of its q/k/v kernels)
         assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_train_step_gathers_the_head_once_and_outside_every_loop(topo):
+    """``LMTrainLoop``'s own step under fsdp on the four described
+    chips, at a small size whose head is its largest leaf (2 layers, 4
+    loss chunks). The chunked loss gathers the head once a step, before
+    its loop, and reduces the head's gradient once, after it: no
+    ``while`` body holds a collective that makes or takes an array of
+    the head's shape, and the whole program holds one ``all-gather`` of
+    it. (Read and cast inside the chunk body, the same step gathered it
+    in the forward and in the backward loop and reduce-scattered its
+    gradient there, once a chunk each.) The static form of the trace's
+    one ``loss_head_gather`` event a step."""
+    import re
+
+    from kubeflow_tpu.models.transformer import TransformerConfig
+    from kubeflow_tpu.parallel.lm_train import LMTrainLoop
+    from kubeflow_tpu.parallel.mesh import make_mesh
+
+    D, V, S = 256, 8192, 512
+    cfg = TransformerConfig(
+        vocab_size=V, d_model=D, n_heads=2, head_dim=128, n_layers=2,
+        d_ff=512, max_seq_len=S, dtype=jnp.bfloat16, loss_chunk=S // 4)
+    mesh, plan = make_mesh(devices=topo.devices, fsdp=True)
+    loop = LMTrainLoop(cfg, mesh, plan)
+    with jax.set_mesh(mesh):
+        state = jax.tree.map(
+            lambda leaf, sharding: jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=sharding),
+            jax.eval_shape(loop._init_fn,
+                           jax.ShapeDtypeStruct((2,), jnp.uint32)),
+            loop.state_shardings())
+        tokens = jax.ShapeDtypeStruct((2 * plan.dp, S + 1), jnp.int32,
+                                      sharding=loop.batch_sharding)
+        text = loop._build_train_step().lower(state, tokens) \
+            .compile().as_text()
+    assert "loss_head_gather" in text and "loss_chunk" in text
+
+    # computation -> its instructions; every instruction -> its type
+    computations, types, name = {}, {}, None
+    for line in text.splitlines():
+        opened = re.match(r"(?:ENTRY )?%?([\w.-]+) \(.*\{$", line)
+        if opened:
+            name = opened.group(1)
+            computations[name] = []
+        elif name and " = " in line:
+            computations[name].append(line)
+            result, rest = line.strip().split(" = ", 1)
+            types[result.lstrip("%").removeprefix("ROOT %")] = \
+                rest[:re.search(r" [\w-]+\(", rest).start()]
+
+    def reached(name, seen):
+        for line in computations.get(name, ()):
+            for callee in re.findall(
+                    r"(?:calls|to_apply|body|condition)=%?([\w.-]+)", line):
+                if callee not in seen:
+                    seen.add(callee)
+                    reached(callee, seen)
+        return seen
+
+    bodies = set(re.findall(r"body=%?([\w.-]+)", text))
+    assert len(bodies) == 3, bodies  # layers, loss chunks, layers back
+    in_a_loop = set().union(*({b} | reached(b, set()) for b in bodies))
+    head = re.compile(rf"\[{D},{V}\]")
+    collective = re.compile(
+        r" (all-gather|all-reduce|reduce-scatter|all-to-all|"
+        r"collective-permute)(-start)?\((.*?)\)")
+    of_the_head = {True: [], False: []}
+    for name, lines in computations.items():
+        for line in lines:
+            found = collective.search(line.split(" = ", 1)[1])
+            if not found:
+                continue
+            operands = re.findall(r"%([\w.-]+)", found.group(3))
+            shapes = line.split(" = ", 1)[1][:found.start()] + " ".join(
+                types.get(operand, "") for operand in operands)
+            if head.search(shapes):
+                of_the_head[name in in_a_loop].append(
+                    (found.group(1), line.strip()[:160]))
+    assert not of_the_head[True], of_the_head[True]
+    assert [kind for kind, _ in of_the_head[False]].count("all-gather") \
+        == 1, of_the_head[False]
 
 
 def test_libtpu_registers_the_overlap_flags():
