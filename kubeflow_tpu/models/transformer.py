@@ -148,8 +148,99 @@ class TransformerConfig:
     # Dense FFN only (MoE experts are not LoRA targets).
     lora_rank: int = 0
     lora_alpha: float = 16.0
+    # What every norm adds under the root, and the rotary base.
+    norm_eps: float = 1e-6
+    rope_base: float = 10_000.0
+    # Layer pattern: () = ``n_layers`` blocks of one kind under one scan
+    # named "layers" (``n_experts`` decides its FFN). Otherwise runs of
+    # one kind each, in order, e.g. (("dense", 1), ("expert", 5)): a
+    # scan a run, named "<kind>_layers", params and cache stacked per
+    # run. "dense" is the SwiGLU FFN of width ``d_ff``; "expert" the
+    # routed experts below (models/experts.py).
+    layer_pattern: Tuple[Tuple[str, int], ...] = ()
+    # Latent attention (MLA; models/latent.py), on when kv_lora_rank >
+    # 0: queries through a ``q_lora_rank`` bottleneck, per head
+    # ``qk_nope_head_dim`` + ``qk_rope_head_dim`` (the rotary part) and
+    # values of ``v_head_dim``; the cache holds kv_lora_rank latent +
+    # qk_rope_head_dim rotary numbers a token a layer, no heads.
+    # ``n_heads`` counts the heads; ``head_dim`` is not read.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Learned sparse attention (DSA), on when index_topk > 0: an
+    # indexer of ``index_n_heads`` x ``index_head_dim`` scores every
+    # cached token (its key is a second cache leaf) and the main
+    # attention reads the index_topk best of a row, all of them while
+    # there are no more.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # Routed experts of an "expert" layer: a float32 sigmoid router
+    # over ``n_routed_experts`` with a bias that enters the choice
+    # only, ``expert_top_k`` chosen, their scores normalised and scaled
+    # by ``routed_scaling_factor``; experts of width ``expert_d_ff``,
+    # ``n_shared_experts`` of them always on. ``held_experts`` (first,
+    # count) is this program's share of an expert-parallel layer: it
+    # holds and computes those, routes over all, and what the others
+    # would add is not in its result.
+    n_routed_experts: int = 0
+    held_experts: Tuple[int, int] = (0, 0)
+    expert_d_ff: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self):
+        # A configuration read back from JSON brings lists.
+        object.__setattr__(self, "layer_pattern", tuple(
+            (str(k), int(n)) for k, n in self.layer_pattern))
+        object.__setattr__(self, "held_experts",
+                           tuple(int(n) for n in self.held_experts))
+        if self.layer_pattern:
+            kinds = [k for k, _ in self.layer_pattern]
+            if (set(kinds) - {"dense", "expert"}
+                    or len(set(kinds)) != len(kinds)
+                    or any(n < 1 for _, n in self.layer_pattern)
+                    or sum(n for _, n in self.layer_pattern)
+                    != self.n_layers):
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r}: runs of "
+                    "'dense' / 'expert', each kind once, counts >= 1 "
+                    f"adding up to n_layers {self.n_layers}")
+            if "expert" in kinds:
+                first, count = self.held_experts
+                if (self.n_routed_experts < 1 or self.expert_d_ff < 1
+                        or count < 1 or first < 0
+                        or first + count > self.n_routed_experts
+                        or not 1 <= self.expert_top_k
+                        <= self.n_routed_experts):
+                    raise ValueError(
+                        "an 'expert' layer needs n_routed_experts, "
+                        "expert_d_ff, expert_top_k and held_experts "
+                        "(first, count) inside the routed range; got "
+                        f"{self.n_routed_experts}, {self.expert_d_ff}, "
+                        f"{self.expert_top_k}, {self.held_experts}")
+        if self.kv_lora_rank > 0:
+            if min(self.q_lora_rank, self.qk_nope_head_dim,
+                   self.qk_rope_head_dim, self.v_head_dim) < 1 \
+                    or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "latent attention needs q_lora_rank, "
+                    "qk_nope_head_dim, v_head_dim and an even "
+                    "qk_rope_head_dim")
+            if self.lora_rank or self.quant:
+                raise ValueError(
+                    "latent attention has no LoRA targets and no int8 "
+                    "weights (lora_rank / quant must be unset; "
+                    "kv_quant is served)")
+        if self.index_topk > 0 and (
+                self.kv_lora_rank < 1 or self.index_n_heads < 1
+                or self.index_head_dim < self.qk_rope_head_dim):
+            raise ValueError(
+                "index_topk selects for latent attention: it needs "
+                "kv_lora_rank, index_n_heads and an index_head_dim of "
+                "at least qk_rope_head_dim")
         if self.attn_impl not in ("auto", "flash", "xla", "naive", "ring"):
             raise ValueError(
                 f"unknown attn_impl {self.attn_impl!r} (expected 'auto', "
@@ -193,6 +284,14 @@ class TransformerConfig:
     def qkv_features(self) -> int:
         return self.n_heads * self.head_dim
 
+    @property
+    def layer_runs(self) -> Tuple[Tuple[str, str, int], ...]:
+        """(scan name, kind, layers) of every run of the stack, in
+        order; one run "layers" of kind "" where no pattern is set."""
+        if not self.layer_pattern:
+            return (("layers", "", self.n_layers),)
+        return tuple((f"{k}_layers", k, n) for k, n in self.layer_pattern)
+
 
 def rope(x: jnp.ndarray, positions: jnp.ndarray, base: float = 10_000.0
          ) -> jnp.ndarray:
@@ -209,13 +308,14 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray, base: float = 10_000.0
 
 class RMSNorm(nn.Module):
     dtype: Any = jnp.bfloat16
+    eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            jnp.float32)
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
-        y = x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6)
+        y = x.astype(jnp.float32) * jax.lax.rsqrt(var + self.eps)
         return (y * scale).astype(self.dtype)
 
 
@@ -565,8 +665,8 @@ class Attention(nn.Module):
         # RoPE with absolute positions (pads carry -1; their rows are
         # masked out of every decode-mode attention, so the garbage
         # rotation never contributes).
-        q = rope(q, jnp.maximum(positions, 0))
-        k = rope(k, jnp.maximum(positions, 0))
+        q = rope(q, jnp.maximum(positions, 0), cfg.rope_base)
+        k = rope(k, jnp.maximum(positions, 0), cfg.rope_base)
         q = q / np.sqrt(cfg.head_dim)
         _probe("attn_q", q)
         _probe("attn_k", k)
@@ -922,9 +1022,47 @@ def init_cache(cfg: TransformerConfig, batch: int = 0):
     their position ids [layers, kv_pages, page] and, for int8 KV, the
     two scale planes; batch-independent. Dense: [layers, batch,
     max_seq_len, H, D] rows with a per-row cursor, so it needs
-    ``batch``. Made out here because the layer scan carries the cache,
-    and what a scan carries cannot come into being inside it."""
-    n, H, D = cfg.n_layers, cfg.n_heads, cfg.head_dim
+    ``batch``. Latent attention: per cached token the latent and rotary
+    numbers in one leaf (``latent_entry_width``) and the indexer's key
+    in a second
+    (models/latent.py), paged. One subtree for every run of
+    ``cfg.layer_runs``, each leaf that run's layers. Made out here
+    because the layer scan carries the cache, and what a scan carries
+    cannot come into being inside it."""
+    return {name: {"attn": _attn_cache(cfg, n, batch)}
+            for name, _, n in cfg.layer_runs}
+
+
+def latent_entry_width(cfg: TransformerConfig) -> int:
+    """Numbers a cached token takes in the ``cached_latent`` leaf: the
+    latent and the rotary key, padded to whole 128-lane tiles. The TPU
+    tiles a leaf's minor dimension in 128 lanes whatever is declared,
+    so the padding costs no memory there; declared unpadded (576 for
+    GLM-5), the leaf cannot be written in place and every dispatch
+    copies the whole pool first (2.1 GiB at the cell's size: AOT for
+    the v5e, PR 36)."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def _attn_cache(cfg: TransformerConfig, n: int, batch: int):
+    """The attention cache leaves of a run of ``n`` layers."""
+    H, D = cfg.n_heads, cfg.head_dim
+    if cfg.kv_lora_rank > 0:
+        if cfg.kv_page_size < 1:
+            raise ValueError("latent attention is cached in pages "
+                             "(kv_page_size > 0)")
+        rows = (n, cfg.kv_pages, cfg.kv_page_size)
+        int8_kv = cfg.kv_quant == "int8"
+        attn = {"cached_latent": jnp.zeros(
+                    rows + (latent_entry_width(cfg),),
+                    jnp.int8 if int8_kv else cfg.dtype),
+                "cached_pos": jnp.full(rows, -1, jnp.int32)}
+        if int8_kv:
+            attn["latent_scale"] = jnp.zeros(rows, jnp.float32)
+        if cfg.index_topk > 0:
+            attn["cached_index_key"] = jnp.zeros(
+                rows + (cfg.index_head_dim,), cfg.dtype)
+        return attn
     if cfg.kv_page_size > 0:
         rows = (n, cfg.kv_pages, cfg.kv_page_size)
         int8_kv = cfg.kv_quant == "int8"
@@ -942,15 +1080,17 @@ def init_cache(cfg: TransformerConfig, batch: int = 0):
     attn.update(cached_key=jnp.zeros(rows + (H, D), kv_dtype),
                 cached_value=jnp.zeros(rows + (H, D), kv_dtype),
                 cached_pos=jnp.full(rows, -1, jnp.int32))
-    return {"layers": {"attn": attn}}
+    return attn
 
 
 class DenseFFN(nn.Module):
     cfg: TransformerConfig
+    d_ff: int = 0   # 0 = cfg.d_ff (a shared expert states its own)
 
     @nn.compact
     def __call__(self, x, lora=None, adapter_ids=None):
         cfg = self.cfg
+        d_ff = self.d_ff or cfg.d_ff
         if cfg.quant == "int8":
             dense = lambda name, feats: QuantDenseGeneral(
                 (feats,), axis=(-1,), dtype=cfg.dtype, name=name)
@@ -958,7 +1098,7 @@ class DenseFFN(nn.Module):
             dense = lambda name, feats: nn.Dense(
                 feats, use_bias=False, dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype, name=name)
-        wi = _lora_apply(self, cfg, "wi", dense("wi", 2 * cfg.d_ff)(x),
+        wi = _lora_apply(self, cfg, "wi", dense("wi", 2 * d_ff)(x),
                          x, lora, adapter_ids)
         wi = checkpoint_name(wi, "mlp_wi")
         gate, up = jnp.split(wi, 2, axis=-1)
@@ -1062,13 +1202,15 @@ class Block(nn.Module):
     """One decoder layer. Scan-shaped: returns (carry, per-layer output)."""
 
     cfg: TransformerConfig
+    kind: str = ""   # a run of cfg.layer_pattern: "dense" or "expert"
 
     @nn.compact
     def __call__(self, x, positions, block_tables=None,
                  write_locations=None, lora=None, adapter_ids=None,
-                 layer=0):
+                 layer=0, experts=None):
         cfg = self.cfg
         lora = lora or {}
+        norm = lambda name: RMSNorm(cfg.dtype, cfg.norm_eps, name=name)
 
         def sp_shard(y):
             """Sequence-dim activation sharding between matmul regions:
@@ -1084,17 +1226,34 @@ class Block(nn.Module):
                 y, P(AXIS_DATA, axis, None))
 
         x = sp_shard(x)
-        x = x + Attention(cfg, name="attn")(
-            RMSNorm(cfg.dtype, name="ln1")(x), positions, block_tables,
-            write_locations, lora.get("attn"), adapter_ids, layer)
+        counts = {}
+        if cfg.kv_lora_rank > 0:
+            from .latent import LatentAttention
+
+            y, seen = LatentAttention(cfg, name="attn")(
+                norm("ln1")(x), positions, block_tables, write_locations,
+                layer)
+            x = x + y
+            if seen is not None:
+                counts["sparse"] = seen
+        else:
+            x = x + Attention(cfg, name="attn")(
+                norm("ln1")(x), positions, block_tables,
+                write_locations, lora.get("attn"), adapter_ids, layer)
         x = sp_shard(x)
-        h = RMSNorm(cfg.dtype, name="ln2")(x)
-        if cfg.n_experts > 0:
+        h = norm("ln2")(x)
+        if self.kind == "expert":
+            from .experts import RoutedExperts
+
+            y, counts["moe"] = RoutedExperts(cfg, name="moe")(
+                h, positions >= 0, *experts, layer)
+            return x + y, counts
+        if cfg.n_experts > 0 and not self.kind:
             x = x + MoEFFN(cfg, name="moe")(h)
         else:
             x = x + DenseFFN(cfg, name="mlp")(h, lora.get("mlp"),
                                               adapter_ids)
-        return x, None
+        return x, counts or None
 
 
 class TransformerLM(nn.Module):
@@ -1223,20 +1382,48 @@ class TransformerLM(nn.Module):
             # stack, which costs a second copy of every pool and its
             # movement every token (Attention._decode_attend). The
             # train step has no cache and keeps its program as it was.
-            args += (jnp.arange(cfg.n_layers, dtype=jnp.int32),)
             in_axes += (0,)
-        ScanBlock = nn.scan(
-            block,
-            variable_axes={"params": 0, "aux_loss": 0},
-            variable_carry="cache" if cfg.decode else False,
-            split_rngs={"params": True},
-            in_axes=in_axes,
-            length=cfg.n_layers,
-            metadata_params={nn.PARTITION_NAME: "layers"},
-        )
-        x, _ = ScanBlock(cfg, name="layers")(x, *args)
+        counts = {}
+        for name, kind, n in cfg.layer_runs:
+            run_args, run_axes = args, in_axes
+            if cfg.decode:
+                run_args += (jnp.arange(n, dtype=jnp.int32),)
+            if kind == "expert":
+                # The held experts of every expert layer, in one stack
+                # a matrix, outside the scan (models/experts.py).
+                held, F = cfg.held_experts[1], cfg.expert_d_ff
+                stack = lambda name, *shape: self.param(
+                    name, nn.initializers.lecun_normal(),
+                    (n, held) + shape, cfg.param_dtype)
+                if not cfg.decode:
+                    run_args += (jnp.arange(n, dtype=jnp.int32),)
+                    run_axes += (0,)
+                run_args += ((stack("expert_wi", cfg.d_model, 2 * F),
+                              stack("expert_wo", F, cfg.d_model)),)
+                run_axes += (nn.broadcast,)
+            ScanBlock = nn.scan(
+                block,
+                variable_axes={"params": 0, "aux_loss": 0},
+                variable_carry="cache" if cfg.decode else False,
+                split_rngs={"params": True},
+                in_axes=run_axes,
+                length=n,
+                metadata_params={nn.PARTITION_NAME: "layers"},
+            )
+            x, ys = ScanBlock(cfg, *((kind,) if kind else ()), name=name)(
+                x, *run_args)
+            for what, per_layer in (ys or {}).items():
+                counts.setdefault(what, []).append(per_layer)
+        # What this call's layers counted, for the engine's counters:
+        # the routed experts' (models/experts.py COUNTS) summed, the
+        # selection's (models/latent.py COUNTS) a layer: a layer's sum
+        # of positions fits int32, the stack's does not.
+        if "moe" in counts:
+            self.sow("counts", "moe", sum(c.sum(0) for c in counts["moe"]))
+        if "sparse" in counts:
+            self.sow("counts", "sparse", jnp.concatenate(counts["sparse"]))
 
-        x = RMSNorm(cfg.dtype, name="ln_f")(x)
+        x = RMSNorm(cfg.dtype, cfg.norm_eps, name="ln_f")(x)
         if return_hidden:
             # Big-vocab loss chunking (parallel/lm_train.py): the caller
             # applies lm_head per sequence chunk so the [B, S, vocab]
@@ -1267,6 +1454,8 @@ _AXES_BY_SUFFIX: Dict[Tuple[str, ...], Tuple[Optional[str], ...]] = {
     ("mlp", "wi", "kernel"): ("embed", "mlp"),
     ("mlp", "wo", "kernel"): ("mlp", "embed"),
     ("moe", "gate", "kernel"): ("embed", None),
+    ("expert_wi",): ("layers", "expert", "embed", "expert_mlp"),
+    ("expert_wo",): ("layers", "expert", "expert_mlp", "embed"),
     ("moe", "wi"): ("expert", "embed", "expert_mlp"),
     ("moe", "wo"): ("expert", "expert_mlp", "embed"),
     ("lm_head", "kernel"): ("embed", "vocab"),
@@ -1284,7 +1473,8 @@ def param_logical_axes(params) -> Any:
     leaves = []
     for path, leaf in flat:
         names = tuple(getattr(p, "key", str(p)) for p in path)
-        stacked = "layers" in names
+        stacked = any(n == "layers" or n.endswith("_layers")
+                      for n in names)
         axes: Optional[Tuple[Optional[str], ...]] = None
         for suffix, spec in _AXES_BY_SUFFIX.items():
             if names[-len(suffix):] == suffix:

@@ -696,6 +696,29 @@ class PrefixCache:
         return freed
 
 
+# The counter families of the sparse selection (models/latent.py
+# COUNTS) and of the routed experts (models/experts.py COUNTS), in
+# those orders, with their help texts. The layers count on the device.
+_COUNT_FAMILIES = {
+    "kfx_lm_sparse_cached_positions_total":
+        "Cached positions query tokens could attend, summed over tokens "
+        "and sparse-attention layers.",
+    "kfx_lm_sparse_attended_positions_total":
+        "Locations of the cache view the main attention scored and mixed "
+        "(the whole view a query: the selection is a mask over it), summed "
+        "over tokens and layers.",
+    "kfx_lm_moe_assignments_total":
+        "(token, chosen expert) pairs routed, over routed-expert layers.",
+    "kfx_lm_moe_assignments_held_total":
+        "Routed pairs whose expert this replica holds.",
+    "kfx_lm_moe_dispatches_total":
+        "Grouped-product dispatches (one a routed-expert layer a model "
+        "call).",
+    "kfx_lm_moe_max_rows_total":
+        "Rows of the fullest held expert, summed over dispatches.",
+}
+
+
 class DecodeEngine:
     """Owns the paged KV pool, the block tables, the prefix cache, the
     compiled prefill/decode functions and the decode-loop thread. One
@@ -789,6 +812,29 @@ class DecodeEngine:
             base, kv_page_size=ps, kv_pages=self.n_pages,
             kv_quant=kv_quant or base.kv_quant)
         self.name = name
+        if base.layer_pattern or base.kv_lora_rank > 0:
+            # What still assumes one run of layers named "layers" or
+            # K/V leaves a head: refused by name, none runs wrong.
+            # (KV transfer, offload and migration move whole pages
+            # leaf by leaf and take any cache tree.)
+            for asked, feature, why in (
+                    (draft_layers > 0, "speculative decoding",
+                     "the draft is the first layers of ONE scanned "
+                     "'layers' stack (truncate_layers)"),
+                    (bool(adapters), "LoRA adapters",
+                     "the adapter stacks target q/k/v/out and mlp "
+                     "kernels of the one dense block"),
+                    (bool(models), "the weight pool",
+                     "it has not been driven with more than one run "
+                     "of layers")):
+                if asked:
+                    raise ValueError(
+                        f"{feature} cannot take this configuration "
+                        f"(layer_pattern {base.layer_pattern!r}, "
+                        f"kv_lora_rank {base.kv_lora_rank}): {why}")
+        # What the programs' layers counted (_counted), one tuple a
+        # dispatch, on the device until flushed.
+        self._counts_pending: List[Any] = []
         self.n_slots = n_slots
         self.chunk_tokens = chunk_tokens
         # Chunked prefill: admit prompt tails in page-multiple chunks,
@@ -1170,6 +1216,16 @@ class DecodeEngine:
         concurrent-admission multiplier at a fixed pool byte budget
         (docs/serving.md HBM accounting)."""
         c = self.cfg
+        if c.kv_lora_rank > 0:
+            import jax
+
+            # Latent attention: what the leaves hold a token (latent +
+            # rotary, the indexer's key, int8 scales), all layers.
+            return int(sum(
+                int(np.prod(x.shape[3:])) * x.shape[0] * x.dtype.itemsize
+                for path, x in jax.tree_util.tree_flatten_with_path(
+                    self._cache_specs())[0]
+                if getattr(path[-1], "key", "") != "cached_pos"))
         if c.kv_quant == "int8":
             return (2 * c.n_layers * c.n_heads * c.head_dim
                     + 2 * c.n_layers * 4 + 4)
@@ -1410,6 +1466,10 @@ class DecodeEngine:
                   "Prompt tokens admitted (cumulative; denominator of "
                   "the prefill-skipped fraction).").set(
                       st["prompt_tokens"], model=self.name)
+        # The selection's and the routed experts' families exist (at 0)
+        # for every configuration; they grow in _flush_counts.
+        for family, text in _COUNT_FAMILIES.items():
+            reg.counter(family, text).inc(0, model=self.name)
         # Chunked-prefill families, pre-seeded (counter at 0; the
         # histogram family registered with a zero-count observe) so a
         # pre-traffic `scrape_metrics --require` already sees them.
@@ -1692,7 +1752,8 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        model = self.model
+        model, counted = self.model, self._counted
+        mutable = ["cache"] + (["counts"] if counted else [])
 
         def run(params, cache, logbuf, tokens, table, slot, true_len,
                 start, lora, aid):
@@ -1713,12 +1774,13 @@ class DecodeEngine:
             logits, vars_ = model.apply(
                 {"params": params, "cache": cache}, tokens,
                 positions=pos, block_tables=table, lora=lora,
-                adapter_ids=aid, mutable=["cache"])
+                adapter_ids=aid, mutable=mutable)
             last = jax.lax.dynamic_slice_in_dim(
                 logits, true_len - 1, 1, axis=1)[0, 0]  # [V]
             logbuf = jax.lax.dynamic_update_slice_in_dim(
                 logbuf, last[None, :].astype(logbuf.dtype), slot, axis=0)
-            return vars_["cache"], logbuf
+            return (vars_["cache"], logbuf) + tuple(
+                vars_["counts"][what][0] for what in counted)
 
         donate = (1, 2) if self._donate else ()
         specs = (
@@ -1759,6 +1821,8 @@ class DecodeEngine:
         from ..models.generate import _sample
 
         model, k = self.model, self.chunk_tokens
+        counted = self._counted
+        mutable = ["cache"] + (["counts"] if counted else [])
 
         def sample_slots(logits, keys, temp, topk):
             # vmap the shared one-row sampler: per-slot RNG stream AND
@@ -1795,7 +1859,7 @@ class DecodeEngine:
                     {"params": params, "cache": cache}, feed[:, None],
                     positions=eff_pos[:, None], block_tables=tables,
                     write_locations=eff_loc[:, None], lora=lora,
-                    adapter_ids=aids, mutable=["cache"])
+                    adapter_ids=aids, mutable=mutable)
                 # The logits CARRY is active-gated like the cache
                 # writes: an inactive row's dummy step produced
                 # garbage logits, and in weight-pool mode "inactive"
@@ -1807,15 +1871,17 @@ class DecodeEngine:
                                     logits2[:, 0], logits)
                 pos2 = jnp.where(active, pos + 1, pos)
                 loc2 = jnp.where(active, loc + 1, loc)
+                out = (tok, emit) + tuple(
+                    vars_["counts"][what][0] for what in counted)
                 return ((vars_["cache"], logits3, pos2, loc2,
-                         active2, produced2, next_rngs), (tok, emit))
+                         active2, produced2, next_rngs), out)
 
             carry = (cache, logbuf, pos, loc, active, produced, rngs)
-            carry, (toks, emits) = jax.lax.scan(step, carry, None,
-                                                length=k)
+            carry, (toks, emits, *counts) = jax.lax.scan(
+                step, carry, None, length=k)
             cache, logbuf, pos, loc, active, produced, rngs = carry
             return (cache, logbuf, pos, loc, active, produced, rngs,
-                    toks, emits)
+                    toks, emits) + tuple(c.sum(0) for c in counts)
 
         donate = (1, 2) if self._donate else ()
         B, V = self.n_slots, self.cfg.vocab_size
@@ -2330,16 +2396,25 @@ class DecodeEngine:
         self._reset_fn()
         if self._prefix is not None:
             self._copy_fn()
+        from ..models.generate import pow2_bucket
+
+        chunk_bucket = 0
         if self.prefill_chunk_tokens:
             # Chunked admission dispatches the chunk-size bucket for
             # every full chunk — compile it once here, not inside the
             # first long-prompt request.
-            from ..models.generate import pow2_bucket
-
-            self._prefill_for(
-                pow2_bucket(self.prefill_chunk_tokens,
-                            self.cfg.max_seq_len))
+            chunk_bucket = pow2_bucket(self.prefill_chunk_tokens,
+                                       self.cfg.max_seq_len)
+            self._prefill_for(chunk_bucket)
         for b in buckets if buckets is not None else self.prompt_buckets:
+            if self.cfg.kv_lora_rank > 0 and 0 < chunk_bucket < b:
+                # A latent configuration's long-context buckets: no
+                # admission dispatches them (a tail longer than the
+                # chunk goes in chunks), and at 32 k tokens such a
+                # program does not fit beside the pool. (The same holds
+                # for the dense block's; whether set-up should skip
+                # them there is a perf change with pairs of its own.)
+                continue
             self._prefill_for(int(b))
             if self.spec:
                 self._draft_prefill_for(int(b))
@@ -3890,12 +3965,12 @@ class DecodeEngine:
                                       np.int32(row[first_own]),
                                       np.int32(cow[0]),
                                       np.int32(cow[1]))
-                self._cache, self._logbuf = fn(
+                self._cache, self._logbuf = self._keep_counts(fn(
                     self.params if wid < 0 else self._wpool.tree(wid),
                     self._cache, self._logbuf, tokens,
                     row[None, :], np.int32(slot), np.int32(len(tail)),
                     np.int32(matched), self._lora_tree(),
-                    np.full((1,), aid, np.int32))
+                    np.full((1,), aid, np.int32)), 2)
             except Exception as e:
                 if self._donate:
                     # A failed DISPATCH may have died after the
@@ -4224,14 +4299,14 @@ class DecodeEngine:
                             tokens=str(length)), \
                 self._phase("engine.prefill.enqueue"):
             try:
-                self._cache, self._logbuf = fn(
+                self._cache, self._logbuf = self._keep_counts(fn(
                     self._params_for(slot), self._cache, self._logbuf,
                     tokens,
                     np.ascontiguousarray(
                         self._tables[slot])[None, :],
                     np.int32(slot), np.int32(length), np.int32(start),
                     self._lora_tree(),
-                    np.full((1,), int(self._aids[slot]), np.int32))
+                    np.full((1,), int(self._aids[slot]), np.int32)), 2)
             except Exception as e:
                 if self._donate:
                     self._fail_inflight(e)
@@ -4702,7 +4777,7 @@ class DecodeEngine:
                         self._max_new, self._lora_tree(),
                         np.ascontiguousarray(self._aids))
                 (self._cache, self._logbuf, pos, loc, active,
-                 produced, rngs, toks, emits) = out
+                 produced, rngs, toks, emits) = self._keep_counts(out, 9)
                 # The first host read blocks until the chunk (and any
                 # prefill enqueued before it) has run on the device.
                 with self._phase("engine.device_wait"):
@@ -4750,7 +4825,47 @@ class DecodeEngine:
                 reg.counter("kfx_lm_generated_tokens_total",
                             "Tokens generated since startup.").inc(
                                 emitted, model=self.name)
+            self._flush_counts()
             self._touch_gauges()
+
+    @property
+    def _counted(self) -> Tuple[str, ...]:
+        """What this configuration's layers count beside their result,
+        in _COUNT_FAMILIES' order: a selection's layers the positions
+        cached for a query and the locations the main attention read
+        (models/latent.py COUNTS, a layer), routed-expert layers what
+        they dispatched (models/experts.py COUNTS). The programs hand
+        the counts back after their own outputs."""
+        c = self.cfg
+        return tuple(what for what, on in (
+            ("sparse", c.kv_lora_rank > 0 and c.index_topk > 0),
+            ("moe", any(k == "expert" for _, k, _ in c.layer_runs)))
+            if on)
+
+    def _keep_counts(self, out, n: int):
+        """The first ``n`` outputs of a model program; what its layers
+        counted beside them waits, on the device, for
+        ``_flush_counts``."""
+        if self._counted:
+            self._counts_pending.append(out[n:])
+        return out[:n]
+
+    def _flush_counts(self) -> None:
+        """Into the registry, what the programs counted since the last
+        flush. Called once the decode chunk's outputs are on the host,
+        so every program enqueued before it has run and no read here
+        waits."""
+        sums = {"sparse": np.zeros(2, np.int64),
+                "moe": np.zeros(4, np.int64)}
+        for counts in self._counts_pending:
+            for what, c in zip(self._counted, counts):
+                c = np.asarray(c, np.int64)
+                sums[what] += c.reshape(-1, c.shape[-1]).sum(0)
+        self._counts_pending.clear()
+        reg = self._reg()
+        values = list(sums["sparse"]) + list(sums["moe"])
+        for (family, text), v in zip(_COUNT_FAMILIES.items(), values):
+            reg.counter(family, text).inc(int(v), model=self.name)
 
     def _decode_grouped(self):
         """One decode chunk across every active slot, in WEIGHT-POOL

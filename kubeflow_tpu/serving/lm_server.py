@@ -351,10 +351,14 @@ class LMPredictor(Predictor):
         # than the target — a 1-layer model has nothing to
         # truncate, so speculation silently stays off there.
         draft = 0
-        if self.spec and cfg.n_layers > 1 and not self.models:
+        if self.spec and cfg.n_layers > 1 and not self.models and (
+                self.spec_layers or not cfg.layer_pattern):
             # A weight pool excludes speculation (the draft would
-            # need its own per-model truncation); auto-disable
-            # rather than fail construction.
+            # need its own per-model truncation), and so does a stack
+            # of several runs (the draft truncates ONE): the default
+            # auto-disables rather than fail construction; an
+            # explicit KFX_LM_SPEC_LAYERS reaches the engine, which
+            # refuses by name.
             draft = self.spec_layers or max(1, cfg.n_layers // 4)
             draft = min(draft, cfg.n_layers - 1)
         # registry as a thunk: register() swaps self.metrics for
@@ -787,6 +791,9 @@ class LMPredictor(Predictor):
                 or skip < 0:
             raise ValueError("stream_skip must be an int >= 0")
         budget_s = self._wait_budget_s(p["deadline_s"])
+        # The request's own deadline is absolute; the default budget
+        # is for a wait without progress.
+        idle = p["deadline_s"] is None
         # A re-dispatched stream whose generation migrated HERE
         # attaches to the adopted request: tokens that traveled with
         # the pages replay first (their indices continue the donor's
@@ -795,7 +802,7 @@ class LMPredictor(Predictor):
         entry = self._claim_resume(self._resume_key_for(p))
         if entry is not None:
             return self._stream_events(entry["req"], entry["q"], skip,
-                                       budget_s,
+                                       budget_s, idle,
                                        prefix=entry["imported"])
         q: "_queue.Queue[Optional[int]]" = _queue.Queue()
         req = self._engine.submit(
@@ -803,7 +810,7 @@ class LMPredictor(Predictor):
             adapter=p["adapter"], model=p["model"], qos=p["qos"],
             deadline_s=p["deadline_s"], tenant=p["tenant"],
             meter_skip=skip, on_token=q.put, **p["kw"])
-        return self._stream_events(req, q, skip, budget_s)
+        return self._stream_events(req, q, skip, budget_s, idle)
 
     @staticmethod
     def _sse(obj: Dict[str, Any], event: str = "") -> bytes:
@@ -812,7 +819,13 @@ class LMPredictor(Predictor):
                 + "\n\n").encode("utf-8")
 
     def _stream_events(self, req, q, skip: int, budget_s: float,
-                       prefix: int = 0) -> Iterator[bytes]:
+                       idle: bool, prefix: int = 0) -> Iterator[bytes]:
+        """``idle``: ``budget_s`` bounds each wait without a token
+        (the default budget: what the router's per-read timeout sees,
+        so a long generation that keeps delivering is not starved);
+        otherwise it bounds the whole stream (the request's own
+        ``deadline_s``: the engine and the client agree on ONE
+        clock)."""
         t0 = time.perf_counter()
         deadline = time.monotonic() + budget_s
         seen = 0
@@ -838,6 +851,8 @@ class LMPredictor(Predictor):
                 continue
             if tok is None:
                 break
+            if idle:
+                deadline = time.monotonic() + budget_s
             if seen >= skip:
                 yield self._sse({"index": seen, "token": tok})
             seen += 1

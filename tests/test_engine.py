@@ -1772,6 +1772,55 @@ class TestRequestPlaneServing:
         # byte for byte.
         assert frames[:3] + resumed[:-1] == frames[:-1]
 
+    @pytest.mark.parametrize("idle", [True, False])
+    def test_stream_budget_idle_or_absolute(self, predictor, monkeypatch,
+                                            idle):
+        """The default budget bounds each wait WITHOUT a token (a
+        generation that keeps delivering outlives it); a request's own
+        deadline_s bounds the whole stream however steadily tokens
+        arrive. Eight tokens 0.1 s apart against a budget of 0.3 s."""
+        import queue
+        import threading
+        import types
+
+        monkeypatch.setattr(predictor._engine, "flight", None)
+        req = types.SimpleNamespace(error=None, tokens=[])
+        q = queue.Queue()
+
+        def feed():
+            for t in range(8):
+                time.sleep(0.1)
+                req.tokens.append(t)
+                q.put(t)
+            q.put(None)
+
+        threading.Thread(target=feed, daemon=True).start()
+        events = self._events(predictor._stream_events(req, q, 0, 0.3,
+                                                       idle))
+        tokens = [e["token"] for _, e in events if "token" in e]
+        if idle:
+            assert tokens == list(range(8))
+            assert events[-1][1]["done"] is True
+        else:
+            assert events[-1][0] and events[-1][1]["code"] == 503
+            assert 1 <= len(tokens) < 8
+
+    def test_stream_deadline_reaches_events(self, predictor, monkeypatch):
+        """generate_stream hands _stream_events the request's own
+        deadline as an absolute budget and the default as an idle
+        one."""
+        seen = []
+        monkeypatch.setattr(
+            predictor, "_stream_events",
+            lambda req, q, skip, budget_s, idle, prefix=0:
+            seen.append((budget_s, idle)) or iter(()))
+        predictor.generate_stream({"prompt_tokens": [[1, 2]],
+                                   "max_new_tokens": 2})
+        predictor.generate_stream({"prompt_tokens": [[1, 2]],
+                                   "max_new_tokens": 2,
+                                   "deadline_ms": 1500})
+        assert seen[0][1] is True and seen[1] == (1.5, False)
+
     def test_stream_validation(self, predictor):
         with pytest.raises(ValueError, match="exactly one prompt"):
             predictor.generate_stream({"prompt_tokens": [[1], [2]]})

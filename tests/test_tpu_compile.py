@@ -219,6 +219,72 @@ def test_engine_programs_write_the_stacked_pool_in_place(one_chip,
         assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+@pytest.mark.parametrize("program", ["decode_chunk", "prefill_1024"])
+def test_latent_pool_is_written_in_place_at_the_cells_size(one_chip,
+                                                           monkeypatch,
+                                                           program):
+    """The engine's programs for ``benchmark/configs/glm-5.json`` as the
+    cell serves it (16 rows, 6144 pages of 64, 32 768 positions; every
+    width the published one, 1 dense + 5 expert layers): they compile
+    for the chip, fit it, and update the latent pool where it lies. The
+    ``cached_latent`` leaf is 640 wide, not 576: declared unpadded, the
+    compiler copied the whole pool at the top of every dispatch (2.1 GiB,
+    and 3.1 GiB of temporaries: PR 36). The routed experts' stacks reach
+    the grouped product whole: no layer's slice of them is made."""
+    import re
+
+    from benchmark import kfx_adapter_glm_moe_dsa as A
+    from benchmark.manifest import BENCH_DIR, load_json
+    from kubeflow_tpu.models.transformer import (
+        TransformerConfig, TransformerLM, init_cache)
+    from kubeflow_tpu.serving.engine import DecodeEngine
+
+    published = load_json(os.path.join(BENCH_DIR, "configs", "glm-5.json"))
+    serving = published["serving"]
+    L, P, N = (serving["max_seq_len"], serving["kv_page_size"],
+               serving["kv_pages"])
+    cfg = TransformerConfig(**A.transformer_kwargs(
+        published, max_seq_len=L, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, decode=True, kv_page_size=P, kv_pages=N))
+    eng = object.__new__(DecodeEngine)
+    eng.cfg, eng.model, eng.name = cfg, TransformerLM(cfg), "aot"
+    eng.n_slots, eng.chunk_tokens, eng.n_blocks = serving["slots"], 8, L // P
+    eng._donate, eng._apool, eng._registry = True, None, None
+    tree, _ = A.host_views(published, jnp.bfloat16)   # shapes: never touched
+    eng.params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+    eng._cache = jax.eval_shape(lambda: init_cache(cfg))
+    jit = jax.jit
+
+    class ForTheChip:
+        def __init__(self, fn, **kw):
+            self.jitted = jit(fn, **kw)
+
+        def lower(self, *specs):
+            return self.jitted.lower(*jax.tree_util.tree_map(
+                lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                               sharding=one_chip), specs))
+
+    monkeypatch.setattr(jax, "jit", ForTheChip)
+    compiled = eng._build_decode() if program == "decode_chunk" \
+        else eng._build_prefill(1024)
+    monkeypatch.undo()
+    text = compiled.as_text()
+    assert f"jit_run_kfx_{program}" in text.splitlines()[0]
+    pool = re.escape(f"[5,{N},{P},640]")
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(rf"= \w+{pool}\S* (copy|copy-done)\(", line)]
+    assert not copies, copies
+    experts = re.escape("bf16[16,6144,4096]")
+    assert not re.search(rf"= {experts}\S* (fusion|copy|dynamic-slice)\(",
+                         text)
+    assert "ragged-dot" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2 << 30
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < int(15.75 * 2 ** 30))
+
+
 def test_train_step_gathers_the_head_once_and_outside_every_loop(topo):
     """``LMTrainLoop``'s own step under fsdp on the four described
     chips, at a small size whose head is its largest leaf (2 layers, 4
