@@ -17,13 +17,14 @@ With ``index_topk`` set, a query attends only the ``index_topk`` cached
 positions its indexer scores highest (``sum_j w_j relu(q^I_j . k^I_s)``
 over the indexer's heads), all of them while there are no more. The
 scores are taken over the row's logical view, gathered page by page
-through its block table, the selection by ``jax.lax.top_k``, and the
-main attention runs over the same view with everything but the
-selected masked out (why not over a gather of the selected:
-``_attend``). The view is as wide as the longest row of the call
-needs, by a ``lax.switch`` over doubling widths from ``index_topk`` to
-``max_seq_len``: a call whose rows all hold no more than
-``index_topk`` tokens scores nothing and selects nothing.
+through its block table; the selection is a mask, made by counting
+(``_selected``: no sort), and the main attention runs over the same
+view with everything but the selected masked out (why not over a
+gather of the selected: ``_attend``). The view is as wide as the
+longest row of the call needs, by a ``lax.switch`` over doubling
+widths from ``index_topk`` to ``max_seq_len``: a call whose rows all
+hold no more than ``index_topk`` tokens scores nothing and selects
+nothing.
 
 One form for every program: a prefill chunk (one row, many queries)
 and a decode step (many rows, one query) run the same code; the token's
@@ -37,6 +38,7 @@ Scopes, under the module's ``attn``: ``latent_write``, ``indexer``,
 
 from __future__ import annotations
 
+import functools
 from typing import List
 
 import flax.linen as nn
@@ -51,6 +53,81 @@ from .transformer import (RMSNorm, TransformerConfig, latent_entry_width,
 # view): the indexer's heads go in groups and a prefill chunk's queries
 # in blocks that keep to it.
 _SCORE_BLOCK_BYTES = 1 << 28
+
+
+# Bits of a key one counting pass of the selection settles: three
+# thresholds a pass. Timed on the chip (PR 37, a mask from float32
+# scores): at [1024, 32768] a pass reads the 134 MB of keys at the
+# memory's speed whatever it counts, and one bit a pass takes 10.0 ms,
+# two 5.7, four (fifteen thresholds: the vector unit's time) 8.3; a
+# decode step's [16, 32768] 64-282, 60 and 104 us (the sort: 35.5 ms
+# and 373 us). Narrower views keep the order but for one bit a pass,
+# the best by a half at [1024, 8192] and the worst in decode.
+_PASS_BITS = 2
+
+
+def _ordered(scores):
+    """uint32 keys of the same order as float32 ``scores``: ``-0.0`` as
+    ``+0.0``, ``-inf`` below every number."""
+    sign = jnp.uint32(1 << 31)
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    bits = jnp.where(bits == sign, jnp.uint32(0), bits)
+    return jnp.where(bits >= sign, ~bits, bits | sign)
+
+
+def _kth_largest(keys, k, bits: int):
+    """The ``k``-th largest (``k`` an int or int32 [...], 1 <= k <= W)
+    of the uint32 ``keys`` [..., W] along the last axis, all of them
+    under ``2 ** bits``: the largest ``t`` that ``k`` keys reach.
+
+    By counting, from the top bits down: a pass counts the keys at or
+    above the three thresholds that extend the bits settled so far by
+    two more, one read of ``keys``, and keeps the largest that ``k``
+    keys still reach. ``bits / 2`` passes and no sort."""
+    steps = -(-bits // _PASS_BITS)
+    digits = jnp.arange(1, 1 << _PASS_BITS, dtype=jnp.uint32)
+    k = jnp.asarray(k, jnp.int32)[..., None]
+
+    def settle(state):
+        shift, top = state
+        by = shift.astype(jnp.uint32)
+        thresholds = top[..., None] | (digits << by)           # [..., 3]
+        reach = jnp.sum(keys[..., None, :] >= thresholds[..., None], -1,
+                        dtype=jnp.int32)
+        digit = jnp.sum(reach >= k, -1, dtype=jnp.uint32)
+        return shift - _PASS_BITS, top | (digit << by)
+
+    return jax.lax.while_loop(
+        lambda state: state[0] >= 0, settle,
+        (jnp.int32((steps - 1) * _PASS_BITS),
+         jnp.zeros(keys.shape[:-1], jnp.uint32)))[1]
+
+
+# A jit of its own, for the set-up's sake: a program holds the selection
+# eight times (four view widths in each run of layers) and flax's scan
+# traces a layer twice, so written out it added a quarter to the
+# operations every pass over a program walks, and 1.5 s to the 3.6 s a
+# program takes to lower on the chip's host, with 13 programs to a
+# replica (my chip run, PR 37). As one call it is traced once a width
+# and lowered to one function a width. (Bound as the module loads: a
+# test that swaps ``jax.jit`` imports this module first.)
+@functools.partial(jax.jit, static_argnums=1)
+def _selected(scores, K: int):
+    """The ``K`` best of float32 ``scores`` [..., W] (``0 < K < W``)
+    along the last axis, as a mask: what a stable ``top_k`` takes, bit
+    for bit. Above the K-th score, and of those that tie with it the
+    earliest. Only the K-th score is wanted, so it is counted out, not
+    sorted out; the last tie taken the same way, by how far from the
+    view's end the ties lie."""
+    W = scores.shape[-1]
+    keys = _ordered(scores)
+    kth = _kth_largest(keys, K, 32)[..., None]
+    above = keys > kth
+    left = K - jnp.sum(above, -1, dtype=jnp.int32)              # >= 1
+    back = jnp.where(keys == kth,
+                     jnp.uint32(W) - jnp.arange(W, dtype=jnp.uint32), 0)
+    last_tie = _kth_largest(back, left, W.bit_length())[..., None]
+    return above | (back >= last_tie)
 
 
 def rope_head(x, positions, dim: int, base: float):
@@ -250,16 +327,7 @@ class LatentAttention(nn.Module):
                     jnp.arange(Hi // group))
                 index = jnp.where(reads, index, -jnp.inf)
             with jax.named_scope("select"):
-                # The K best, as a mask: above the K-th score, and of
-                # those that tie with it the earliest (top_k is stable,
-                # so the ties it took end at the last one it took).
-                best, at = jax.lax.top_k(index, K)             # [B, S, K]
-                least = best[..., -1:]
-                last_tie = jnp.max(jnp.where(best == least, at, -1), -1,
-                                   keepdims=True)
-                where = jnp.arange(W, dtype=at.dtype)
-                reads = reads & ((index > least) | (
-                    (index == least) & (where <= last_tie)))
+                reads = reads & _selected(index, K)
         with jax.named_scope("latent_attend"):
             rows = view(pool["lat"])                           # [B, W, E]
             if "scale" in pool:

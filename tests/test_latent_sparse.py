@@ -157,6 +157,108 @@ def test_the_selection_never_reads_what_is_not_the_rows(case):
     np.testing.assert_allclose(got, want, atol=2e-6)
 
 
+def top_k_mask(index, K):
+    """The selection as it was made until PR 37, from a stable
+    ``jax.lax.top_k``: the reference of the counting form."""
+    best, at = jax.lax.top_k(index, K)
+    least = best[..., -1:]
+    last_tie = jnp.max(jnp.where(best == least, at, -1), -1, keepdims=True)
+    where = jnp.arange(index.shape[-1], dtype=at.dtype)
+    return (index > least) | ((index == least) & (where <= last_tie))
+
+
+SCORES = {
+    "random": lambda rng, shape: rng.standard_normal(shape),
+    # ties across the K-th place (+ 0.0: no -0.0, which a sort puts
+    # below +0.0 and a comparison does not)
+    "eight_values": lambda rng, shape: rng.integers(-3, 5, size=shape) + 0.0,
+    "all_zero": lambda rng, shape: np.zeros(shape),
+    # every exponent of the normal numbers, both signs (no subnormals:
+    # a comparison flushes them to zero, a sort keeps them apart)
+    "every_exponent": lambda rng, shape: (
+        rng.choice([-1.0, 1.0], size=shape)
+        * 10.0 ** rng.uniform(-37, 38, size=shape)),
+}
+
+
+@pytest.mark.parametrize("shape", [(16, 1, 200), (1, 64, 256)],
+                         ids=["decode", "prefill"])
+@pytest.mark.parametrize("readable", [59, 64, 101],
+                         ids=["fewer", "exactly_k", "more"])
+@pytest.mark.parametrize("kind", sorted(SCORES))
+def test_the_counted_selection_is_top_ks_mask_bit_for_bit(kind, readable,
+                                                          shape):
+    """``latent._selected`` against the mask a stable ``top_k`` gives:
+    ties across the K-th place, ``-inf`` tails that leave fewer than K,
+    exactly K and more than K positions to read, a decode step's shape
+    and a prefill chunk's (a query further on reads a position more)."""
+    from kubeflow_tpu.models.latent import _selected
+
+    K, (_, S, W) = 64, shape
+    rng = np.random.default_rng(sum(map(ord, kind)) + readable + S)
+    scores = SCORES[kind](rng, shape).astype(np.float32)
+    reads = np.broadcast_to(
+        np.arange(W) < readable + np.arange(S)[:, None], shape)
+    index = jnp.asarray(np.where(reads, scores, -np.inf))
+    got = jax.jit(_selected, static_argnums=1)(index, K)
+    np.testing.assert_array_equal(got, top_k_mask(index, K))
+    np.testing.assert_array_equal((np.asarray(got) & reads).sum(-1),
+                                  np.minimum(K, reads.sum(-1)))
+
+
+def test_no_sort_is_left_in_the_select_scope_of_the_lowered_prefill(
+        tiny_engine_parts):
+    """``kfx_prefill_16`` of the tiny sparse configuration, as the
+    engine lowers it: every view width that selects (32, 64, 128; 16 is
+    ``index_topk``, where all is selected) counts, in either run of
+    layers: the counting loops, and neither a sort nor a top_k."""
+    import re
+
+    from kubeflow_tpu.models.latent import view_widths
+    from kubeflow_tpu.serving.engine import DecodeEngine
+
+    _, tcfg, params = tiny_engine_parts
+    texts, real_jit = {}, jax.jit
+
+    class Recording:
+        def __init__(self, fn, **kw):
+            self.fn, self.jitted = fn, real_jit(fn, **kw)
+
+        def lower(self, *specs):
+            lowered = self.jitted.lower(*specs)
+            texts[self.fn.__name__] = lowered.as_text(debug_info=True)
+            return lowered
+
+    eng = DecodeEngine(tcfg, params, n_slots=2, chunk_tokens=4, name="low",
+                       kv_page_size=8, kv_pages=30, prefix_cache=False,
+                       prefill_chunk_tokens=16)
+    try:
+        assert view_widths(eng.cfg) == [16, 32, 64, 128]
+        jax.jit = lambda fn, **kw: Recording(fn, **kw)
+        try:
+            eng._build_prefill(16)
+        finally:
+            jax.jit = real_jit
+    finally:
+        eng.close()
+    text = texts["run_kfx_prefill_16"]
+    # one function a width that selects, called from either run of
+    # layers, the two counting loops in it and no sort
+    selections = [f for f in text.split("func.func private @")
+                  if f.startswith("_selected")]
+    assert len(selections) == 3
+    assert len(re.findall(r"func\.call @_selected", text)) == 2 * 3
+    for body in selections:
+        assert body.count("stablehlo.while") == 2
+        assert "sort" not in body and "top_k" not in body
+    # what the operations are called, scope / primitive: none under
+    # ``attn`` is a sort (the names do show one where there is one: the
+    # router's top_k and the dispatch's argsort)
+    names = re.findall(r'^#loc\d+ = loc\("([^"]*)"', text, re.M)
+    sorts = [n for n in names if "top_k" in n or "sort" in n]
+    assert sorts and not [n for n in sorts if "attn" in n]
+
+
 def test_int8_latent_pool_is_close_and_not_equal():
     cfg = tiny_glm.config()
     tcfg, params = tiny_glm.program(cfg, SEED, kv_quant="int8", **SERVE)

@@ -235,6 +235,9 @@ def test_latent_pool_is_written_in_place_at_the_cells_size(one_chip,
 
     from benchmark import kfx_adapter_glm_moe_dsa as A
     from benchmark.manifest import BENCH_DIR, load_json
+    # (loaded before jax.jit is swapped below: it jits its selection as
+    # it loads, and the model imports it only when a layer is traced)
+    from kubeflow_tpu.models import latent  # noqa: F401
     from kubeflow_tpu.models.transformer import (
         TransformerConfig, TransformerLM, init_cache)
     from kubeflow_tpu.serving.engine import DecodeEngine
