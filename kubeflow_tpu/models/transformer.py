@@ -190,6 +190,44 @@ class TransformerConfig:
     expert_d_ff: int = 0
     n_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
+    # Grouped key/value heads: ``n_kv_heads`` of them (0 = n_heads),
+    # each shared by n_heads / n_kv_heads query heads; the cache holds
+    # n_kv_heads x head_dim a token a layer.
+    n_kv_heads: int = 0
+    # The four multipliers of a scaled block (each 1.0, and 0.0 for
+    # the attention's, is the program without them): the embedding's
+    # rows times ``embedding_multiplier``; scores ``q . k`` times
+    # ``attention_multiplier`` (0 = 1 / sqrt(head_dim)); every mixer's
+    # and FFN's result times ``residual_multiplier`` before it joins
+    # the residual; logits divided by ``logits_scaling``.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # False: no rotary (or any) position term in attention.
+    rope: bool = True
+    # True: the head is the embedding, transposed; no ``lm_head``.
+    tie_embeddings: bool = False
+    # "mamba" layers (models/ssm.py; "attention" is their companion
+    # kind: the attention above with the SwiGLU FFN; runs of these two
+    # kinds may recur in ``layer_pattern``, (("mamba", 5), ("attention",
+    # 1), ("mamba", 9), ...): a kind's later runs are scans named
+    # "<kind>_layers2", "<kind>_layers3", ...): ``ssm_heads`` x
+    # ``ssm_head_dim`` inputs, a state of ``ssm_state`` numbers each,
+    # ``ssm_groups`` B/C groups, a causal convolution of ``ssm_conv``
+    # taps, the chunked form at ``ssm_chunk`` tokens. A row's state is
+    # held in ``ssm_state_dtype``, in leaves indexed by slot:
+    # ``state_slots`` of them beside a paged pool (the serving engine
+    # sets it, as it sets ``kv_pages``); the dense layout has the
+    # batch's rows.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    ssm_state_dtype: Any = jnp.float32
+    state_slots: int = 0
 
     def __post_init__(self):
         # A configuration read back from JSON brings lists.
@@ -197,17 +235,55 @@ class TransformerConfig:
             (str(k), int(n)) for k, n in self.layer_pattern))
         object.__setattr__(self, "held_experts",
                            tuple(int(n) for n in self.held_experts))
+        object.__setattr__(self, "ssm_state_dtype",
+                           jnp.dtype(self.ssm_state_dtype))
         if self.layer_pattern:
             kinds = [k for k, _ in self.layer_pattern]
-            if (set(kinds) - {"dense", "expert"}
-                    or len(set(kinds)) != len(kinds)
+            once = [k for k in kinds if k in ("dense", "expert")]
+            if (set(kinds) - {"dense", "expert", "mamba", "attention"}
+                    or len(set(once)) != len(once)
+                    or any(a == b for a, b in zip(kinds, kinds[1:]))
                     or any(n < 1 for _, n in self.layer_pattern)
                     or sum(n for _, n in self.layer_pattern)
                     != self.n_layers):
                 raise ValueError(
                     f"layer_pattern {self.layer_pattern!r}: runs of "
-                    "'dense' / 'expert', each kind once, counts >= 1 "
-                    f"adding up to n_layers {self.n_layers}")
+                    "'dense' / 'expert' (each kind once) or 'mamba' / "
+                    "'attention' (a kind may recur, never twice in a "
+                    f"row), counts >= 1 adding up to n_layers "
+                    f"{self.n_layers}")
+            if "mamba" in kinds:
+                if min(self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+                       self.ssm_groups, self.ssm_chunk) < 1 \
+                        or self.ssm_conv < 2 \
+                        or self.ssm_heads % self.ssm_groups:
+                    raise ValueError(
+                        "a 'mamba' layer needs ssm_heads (a multiple of "
+                        "ssm_groups), ssm_head_dim, ssm_state, ssm_chunk "
+                        "and ssm_conv >= 2")
+                if self.lora_rank or self.quant or self.kv_lora_rank \
+                        or "expert" in kinds:
+                    raise ValueError(
+                        "'mamba' layers have no LoRA targets and no int8 "
+                        "weights, and stand beside plain attention and "
+                        "dense FFNs only (lora_rank, quant, kv_lora_rank "
+                        "unset; no 'expert' run)")
+        if self.tie_embeddings and (self.quant or self.loss_chunk):
+            raise ValueError(
+                "tie_embeddings has no lm_head for int8 weights or the "
+                "chunked loss to read")
+        if self.n_kv_heads < 0 or (
+                self.n_kv_heads and self.n_heads % self.n_kv_heads):
+            raise ValueError(
+                f"n_kv_heads {self.n_kv_heads} must divide n_heads "
+                f"{self.n_heads} (0 = one a query head)")
+        if self.kv_heads != self.n_heads and (
+                self.kv_lora_rank or self.lora_rank or self.quant
+                or self.cp > 1):
+            raise ValueError(
+                "grouped key/value heads (n_kv_heads < n_heads) are "
+                "served by the plain attention alone: not with latent "
+                "attention, LoRA, int8 weights or ring attention")
             if "expert" in kinds:
                 first, count = self.held_experts
                 if (self.n_routed_experts < 1 or self.expert_d_ff < 1
@@ -285,12 +361,28 @@ class TransformerConfig:
         return self.n_heads * self.head_dim
 
     @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
     def layer_runs(self) -> Tuple[Tuple[str, str, int], ...]:
         """(scan name, kind, layers) of every run of the stack, in
-        order; one run "layers" of kind "" where no pattern is set."""
+        order; one run "layers" of kind "" where no pattern is set. A
+        kind's second run is "<kind>_layers2"."""
         if not self.layer_pattern:
             return (("layers", "", self.n_layers),)
-        return tuple((f"{k}_layers", k, n) for k, n in self.layer_pattern)
+        runs, seen = [], {}
+        for k, n in self.layer_pattern:
+            seen[k] = seen.get(k, 0) + 1
+            runs.append((f"{k}_layers" + (str(seen[k]) if seen[k] > 1
+                                          else ""), k, n))
+        return tuple(runs)
+
+    @property
+    def has_slot_state(self) -> bool:
+        """Whether a row carries state beside its pages: the leaves of
+        its "mamba" layers, indexed by slot."""
+        return any(k == "mamba" for k, _ in self.layer_pattern)
 
 
 def rope(x: jnp.ndarray, positions: jnp.ndarray, base: float = 10_000.0
@@ -578,7 +670,15 @@ def attention_path(cfg: TransformerConfig, seq_len: int) -> str:
     if cfg.cp > 1:
         # Context-parallel: the only seq-sharded kernel.
         return "ring"
-    return "flash" if Attention(cfg)._use_flash(seq_len) else "dense"
+    if not Attention(cfg)._use_flash(seq_len):
+        return "dense"
+    if cfg.kv_heads != cfg.n_heads:
+        raise ValueError(
+            f"the flash kernels take one key/value head a query head: "
+            f"n_kv_heads {cfg.kv_heads} of n_heads {cfg.n_heads} at "
+            f"{seq_len} tokens needs attn_impl='naive' (grouped heads "
+            "are served through the decode cache, not trained)")
+    return "flash"
 
 
 class Attention(nn.Module):
@@ -655,19 +755,23 @@ class Attention(nn.Module):
         # and the head scaling — exactly where a merged-weight kernel
         # (W + scale·A·B) would put them, so the dense merged oracle
         # and the batched-gather path compute the same function.
-        def hproj(name):
-            y = proj(name, (cfg.n_heads, cfg.head_dim))(x)
+        def hproj(name, heads=cfg.n_heads):
+            y = proj(name, (heads, cfg.head_dim))(x)
             return _lora_apply(self, cfg, name, y, x, lora, adapter_ids)
 
         q = tagged_heads("attn_q", hproj("query"))
-        k = tagged_heads("attn_k", hproj("key"))
-        v = tagged_heads("attn_v", hproj("value"))
-        # RoPE with absolute positions (pads carry -1; their rows are
-        # masked out of every decode-mode attention, so the garbage
-        # rotation never contributes).
-        q = rope(q, jnp.maximum(positions, 0), cfg.rope_base)
-        k = rope(k, jnp.maximum(positions, 0), cfg.rope_base)
-        q = q / np.sqrt(cfg.head_dim)
+        k = tagged_heads("attn_k", hproj("key", cfg.kv_heads))
+        v = tagged_heads("attn_v", hproj("value", cfg.kv_heads))
+        if cfg.rope:
+            # RoPE with absolute positions (pads carry -1; their rows
+            # are masked out of every decode-mode attention, so the
+            # garbage rotation never contributes).
+            q = rope(q, jnp.maximum(positions, 0), cfg.rope_base)
+            k = rope(k, jnp.maximum(positions, 0), cfg.rope_base)
+        if cfg.attention_multiplier:
+            q = q * cfg.attention_multiplier
+        else:
+            q = q / np.sqrt(cfg.head_dim)
         _probe("attn_q", q)
         _probe("attn_k", k)
         _probe("attn_v", v)
@@ -732,6 +836,9 @@ class Attention(nn.Module):
                 out = fa.flash_attention_apply(q, k, v, o, lse)
         else:
             # Dense causal attention (XLA fuses the softmax chain).
+            if cfg.kv_heads != cfg.n_heads:
+                k, v = (jnp.repeat(a, cfg.n_heads // cfg.kv_heads, 2)
+                        for a in (k, v))
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
             mask = nn.make_causal_mask(jnp.zeros((B, S)), dtype=jnp.bool_)
             scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
@@ -852,6 +959,12 @@ class Attention(nn.Module):
 
         ck, cv, cpos = (leaf("cached_key"), leaf("cached_value"),
                         leaf("cached_pos"))
+        # Grouped heads lie side by side in their leaves (_attn_cache):
+        # heads() of what is read, entry() of what is written.
+        heads = entry = lambda x: x
+        if cfg.kv_heads != H:
+            heads = lambda x: x.reshape(x.shape[:-1] + (cfg.kv_heads, D))
+            entry = lambda x: x.reshape(x.shape[:-2] + (-1,))
         if cfg.kv_page_size > 0:
             P, N = cfg.kv_page_size, cfg.kv_pages
             if block_tables is None:
@@ -898,29 +1011,30 @@ class Attention(nn.Module):
                         return q, s
                     kq, ks = q8(k)
                     vq, vs = q8(v)
-                    write(ck, kq)
-                    write(cv, vq)
+                    write(ck, entry(kq))
+                    write(cv, entry(vq))
                     write(ksc, ks)
                     write(vsc, vs)
                 else:
-                    write(ck, k.astype(cfg.dtype))
-                    write(cv, v.astype(cfg.dtype))
+                    write(ck, entry(k.astype(cfg.dtype)))
+                    write(cv, entry(v.astype(cfg.dtype)))
                 write(cpos, pos)
-            if attends_pool_in_place(B, L, N, P):
+            if attends_pool_in_place(B, L, N, P, score_bytes(cfg, S)):
                 if int8_kv:
                     # Dequant the pool where it lies: int8 entries x
                     # the per-token scale plane, in f32, then the
                     # compute dtype (what the gathered form does to
                     # each row's view).
                     with jax.named_scope("kv_dequant"):
-                        pk = (own(ck, flat=True).astype(jnp.float32)
+                        pk = (heads(own(ck, flat=True)).astype(jnp.float32)
                               * own(ksc, flat=True)[..., None, None]
                               ).astype(cfg.dtype)
-                        pv = (own(cv, flat=True).astype(jnp.float32)
+                        pv = (heads(own(cv, flat=True)).astype(jnp.float32)
                               * own(vsc, flat=True)[..., None, None]
                               ).astype(cfg.dtype)
                 else:
-                    pk, pv = own(ck, flat=True), own(cv, flat=True)
+                    pk, pv = (heads(own(ck, flat=True)),
+                              heads(own(cv, flat=True)))
                 return self._attend_pool(q, positions, block_tables, pk, pv,
                                          own(cpos))
             # Gather each row's logical view [L] through its table.
@@ -934,7 +1048,7 @@ class Attention(nn.Module):
                     rows = var.value[layer, pt]      # [B, nblk, P, ...]
                     return rows.reshape(B, L, *rows.shape[3:])
 
-                gk, gv = view(ck), view(cv)
+                gk, gv = heads(view(ck)), heads(view(cv))
                 if int8_kv:
                     # Dequant-on-gather: int8 entries x the per-token
                     # scale plane, in f32 (one multiply per gathered
@@ -953,13 +1067,28 @@ class Attention(nn.Module):
                 at = i[:, None] + jnp.arange(
                     S, dtype=jnp.int32)[None]                   # [B, S]
                 ck.value = ck.value.at[layer, rows, at].set(
-                    k.astype(cfg.dtype))
+                    entry(k.astype(cfg.dtype)))
                 cv.value = cv.value.at[layer, rows, at].set(
-                    v.astype(cfg.dtype))
+                    entry(v.astype(cfg.dtype)))
                 cpos.value = cpos.value.at[layer, rows, at].set(positions)
                 cur.value = cur.value.at[layer].set(i + S)
-            gk, gv, gp = own(ck), own(cv), own(cpos)
+            gk, gv, gp = heads(own(ck)), heads(own(cv)), own(cpos)
 
+        if cfg.kv_heads != H:
+            # Grouped heads: the view stays as it lies, a token's
+            # key/value heads side by side (_blocks).
+            with jax.named_scope("scores"):
+                scores = jnp.einsum("bqhf,bkf->bhqk", self._blocks(q),
+                                    gk.reshape(B, gk.shape[1], -1))
+                mask = ((gp >= 0)[:, None, :]
+                        & (gp[:, None, :] <= positions[:, :, None]))
+                scores = jnp.where(mask[:, None], scores,
+                                   jnp.finfo(scores.dtype).min)
+                probs = jax.nn.softmax(scores.astype(jnp.float32), -1)
+            with jax.named_scope("pv"):
+                return self._own_block(jnp.einsum(
+                    "bhqk,bkf->bqhf", probs.astype(cfg.dtype),
+                    gv.reshape(B, gv.shape[1], -1)))
         with jax.named_scope("scores"):
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, gk)  # [B,H,S,L]
             kp = gp[:, None, None, :]                      # [B,1,1,L]
@@ -971,6 +1100,33 @@ class Attention(nn.Module):
         with jax.named_scope("pv"):
             return jnp.einsum("bhqk,bkhd->bqhd",
                               probs.astype(cfg.dtype), gv)
+
+    def _blocks(self, q):
+        """Grouped heads' queries against keys that lie side by side,
+        n_kv_heads x D a token: q [B, S, H, D] -> [B, S, H, n_kv_heads
+        x D], a head's numbers in its key/value head's block and zeros
+        in the others, so that one product over the whole entry scores
+        every head against its own keys. The entry is never split into
+        heads: [8, 64] as minor dimensions pads every head to 128 lanes,
+        and the chip's compiler made each gathered view over in that
+        layout (2.6 ms a view a decode step at 64 rows: my chip run
+        p1, PR 40). The zeros cost n_kv_heads times the products'
+        operations, which a decode step does not feel."""
+        B, S, H, D = q.shape
+        n = self.cfg.kv_heads
+        eye = jnp.eye(n, dtype=q.dtype)
+        return jnp.einsum("bsngd,nm->bsngmd", q.reshape(B, S, n, H // n, D),
+                          eye).reshape(B, S, H, n * D)
+
+    def _own_block(self, mixed):
+        """What ``_blocks``' queries mixed from values side by side,
+        [B, S, H, n_kv_heads x D]: each head's own block, [B, S, H, D]."""
+        B, S, H, F = mixed.shape
+        n = self.cfg.kv_heads
+        eye = jnp.eye(n, dtype=mixed.dtype)
+        return jnp.einsum("bsngmd,nm->bsngd",
+                          mixed.reshape(B, S, n, H // n, n, F // n),
+                          eye).reshape(B, S, H, F // n)
 
     def _attend_pool(self, q, positions, block_tables, pk, pv, kpos):
         """Paged attention over the pool in place: ``pk``/``pv``
@@ -992,6 +1148,17 @@ class Attention(nn.Module):
             qp = positions[:, None, :, None]                 # [B,1,S,1]
             mask = (jnp.repeat(member, P, axis=1)[:, None, None, :]
                     & (kp >= 0) & (kp <= qp))                # [B,1,S,NP]
+        if pk.shape[1] != q.shape[2]:   # grouped heads (_blocks)
+            with jax.named_scope("scores"):
+                scores = jnp.einsum("bqhf,kf->bhqk", self._blocks(q),
+                                    pk.reshape(N * P, -1),
+                                    preferred_element_type=jnp.float32)
+                scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
+                probs = jax.nn.softmax(scores, -1)
+            with jax.named_scope("pv"):
+                return self._own_block(jnp.einsum(
+                    "bhqk,kf->bqhf", probs.astype(self.cfg.dtype),
+                    pv.reshape(N * P, -1)))
         with jax.named_scope("scores"):
             scores = jnp.einsum("bqhd,khd->bhqk", q, pk,
                                 preferred_element_type=jnp.float32)
@@ -1002,16 +1169,36 @@ class Attention(nn.Module):
                               probs.astype(self.cfg.dtype), pv)
 
 
+def score_bytes(cfg: TransformerConfig, window: int) -> float:
+    """For ``attends_pool_in_place``: the bytes of scores a cached
+    position costs a row (its query heads x the window's tokens,
+    written and read as float32 and once as probabilities), over the
+    bytes of the position's own keys and values."""
+    item = 1 if cfg.kv_quant == "int8" else jnp.dtype(cfg.dtype).itemsize
+    return (10.0 * cfg.n_heads * window
+            / (2 * cfg.kv_heads * cfg.head_dim * item))
+
+
 def attends_pool_in_place(batch: int, max_seq_len: int, kv_pages: int,
-                          page_size: int) -> bool:
+                          page_size: int, score_bytes: float) -> bool:
     """Whether paged decode attention scores the pool in place
     (``kv_pages * page_size`` K/V positions a query row) or gathers
-    each row's logical view (``max_seq_len`` positions a row): in
-    place when the pool is no larger than the batch's logical view, so
-    the cheaper operand is the one streamed. Read off shapes that are
-    static when the program is traced; the engine reports the outcome
-    per program (``kfx_lm_attend_positions``)."""
-    return batch * max_seq_len >= kv_pages * page_size
+    each row's logical view (``max_seq_len`` positions a row): the
+    form that moves fewer bytes. In place every row scores the whole
+    pool, so a position costs its keys and values once and ``batch``
+    rows of scores (``score_bytes``, the function above); gathered it
+    costs them three times (read, written into the view, read) and one
+    row of scores. With a key/value head a query head and wide heads
+    the scores hardly count, and the pool is scored in place until it
+    is some three times the batch's logical view; with few key/value
+    heads under many query heads and many rows the scores are the
+    larger part (64 rows x 32 heads over 8 x 64-wide key/value heads:
+    1 GB of float32 scores a layer a step in place) and the rows
+    gather. Read off shapes that are static when the program is
+    traced; the engine reports the outcome per program
+    (``kfx_lm_attend_positions``)."""
+    pool, view = kv_pages * page_size, batch * max_seq_len
+    return view * (3.0 + score_bytes) >= pool * (1.0 + batch * score_bytes)
 
 
 def init_cache(cfg: TransformerConfig, batch: int = 0):
@@ -1029,8 +1216,19 @@ def init_cache(cfg: TransformerConfig, batch: int = 0):
     ``cfg.layer_runs``, each leaf that run's layers. Made out here
     because the layer scan carries the cache, and what a scan carries
     cannot come into being inside it."""
-    return {name: {"attn": _attn_cache(cfg, n, batch)}
-            for name, _, n in cfg.layer_runs}
+    if not cfg.has_slot_state:
+        return {name: {"attn": _attn_cache(cfg, n, batch)}
+                for name, _, n in cfg.layer_runs}
+    from .ssm import init_state
+
+    rows = cfg.state_slots if cfg.kv_page_size > 0 else batch
+    if rows < 1:
+        raise ValueError(
+            "slot state beside a paged pool needs state_slots; the "
+            "dense layout init_cache(cfg, batch)")
+    return {name: {"ssm": init_state(cfg, n, rows)} if kind == "mamba"
+            else {"attn": _attn_cache(cfg, n, batch)}
+            for name, kind, n in cfg.layer_runs}
 
 
 def latent_entry_width(cfg: TransformerConfig) -> int:
@@ -1046,7 +1244,7 @@ def latent_entry_width(cfg: TransformerConfig) -> int:
 
 def _attn_cache(cfg: TransformerConfig, n: int, batch: int):
     """The attention cache leaves of a run of ``n`` layers."""
-    H, D = cfg.n_heads, cfg.head_dim
+    H, D = cfg.kv_heads, cfg.head_dim
     if cfg.kv_lora_rank > 0:
         if cfg.kv_page_size < 1:
             raise ValueError("latent attention is cached in pages "
@@ -1077,8 +1275,11 @@ def _attn_cache(cfg: TransformerConfig, n: int, batch: int):
                              "init_cache(cfg, batch)")
         rows, kv_dtype = (n, batch, cfg.max_seq_len), cfg.dtype
         attn = {"cache_index": jnp.zeros((n, batch), jnp.int32)}
-    attn.update(cached_key=jnp.zeros(rows + (H, D), kv_dtype),
-                cached_value=jnp.zeros(rows + (H, D), kv_dtype),
+    # Grouped heads: a token's key/value heads side by side, in whole
+    # 128-lane tiles (8 x 64 declared [8, 64] pads every head to 128).
+    entry = (H, D) if H == cfg.n_heads else (H * D,)
+    attn.update(cached_key=jnp.zeros(rows + entry, kv_dtype),
+                cached_value=jnp.zeros(rows + entry, kv_dtype),
                 cached_pos=jnp.full(rows, -1, jnp.int32))
     return attn
 
@@ -1202,15 +1403,19 @@ class Block(nn.Module):
     """One decoder layer. Scan-shaped: returns (carry, per-layer output)."""
 
     cfg: TransformerConfig
-    kind: str = ""   # a run of cfg.layer_pattern: "dense" or "expert"
+    # a run of cfg.layer_pattern: "dense", "expert", "mamba", "attention"
+    kind: str = ""
 
     @nn.compact
     def __call__(self, x, positions, block_tables=None,
                  write_locations=None, lora=None, adapter_ids=None,
-                 layer=0, experts=None):
+                 layer=0, experts=None, slots=None):
         cfg = self.cfg
         lora = lora or {}
         norm = lambda name: RMSNorm(cfg.dtype, cfg.norm_eps, name=name)
+        # what a mixer or an FFN gives, as it joins the residual
+        scaled = (lambda y: y) if cfg.residual_multiplier == 1.0 \
+            else (lambda y: y * cfg.residual_multiplier)
 
         def sp_shard(y):
             """Sequence-dim activation sharding between matmul regions:
@@ -1227,7 +1432,13 @@ class Block(nn.Module):
 
         x = sp_shard(x)
         counts = {}
-        if cfg.kv_lora_rank > 0:
+        if self.kind == "mamba":
+            from .ssm import Mamba2
+
+            y, counts["ssm"] = Mamba2(cfg, name="ssm")(
+                norm("ln1")(x), positions, slots, layer)
+            x = x + scaled(y)
+        elif cfg.kv_lora_rank > 0:
             from .latent import LatentAttention
 
             y, seen = LatentAttention(cfg, name="attn")(
@@ -1237,9 +1448,9 @@ class Block(nn.Module):
             if seen is not None:
                 counts["sparse"] = seen
         else:
-            x = x + Attention(cfg, name="attn")(
+            x = x + scaled(Attention(cfg, name="attn")(
                 norm("ln1")(x), positions, block_tables,
-                write_locations, lora.get("attn"), adapter_ids, layer)
+                write_locations, lora.get("attn"), adapter_ids, layer))
         x = sp_shard(x)
         h = norm("ln2")(x)
         if self.kind == "expert":
@@ -1251,8 +1462,8 @@ class Block(nn.Module):
         if cfg.n_experts > 0 and not self.kind:
             x = x + MoEFFN(cfg, name="moe")(h)
         else:
-            x = x + DenseFFN(cfg, name="mlp")(h, lora.get("mlp"),
-                                              adapter_ids)
+            x = x + scaled(DenseFFN(cfg, name="mlp")(
+                h, lora.get("mlp"), adapter_ids))
         return x, counts or None
 
 
@@ -1264,8 +1475,11 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, train: bool = False, positions=None,
                  return_hidden: bool = False, block_tables=None,
-                 write_locations=None, lora=None, adapter_ids=None):
+                 write_locations=None, lora=None, adapter_ids=None,
+                 slots=None):
         cfg = self.cfg
+        # ``slots`` [B]: each row's slot in the leaves indexed by slot
+        # (models/ssm.py; None: row i is slot i).
         # Multi-tenant LoRA serving args (serving/adapters.py): ``lora``
         # is the per-projection adapter STACK pytree (leaves carry a
         # leading layers axis the scan slices) and ``adapter_ids`` [B]
@@ -1297,6 +1511,8 @@ class TransformerLM(nn.Module):
                 x, P(AXIS_DATA, AXIS_CTX, None))
         else:
             x = embed(tokens)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         if positions is None:
             positions = jnp.broadcast_to(
                 jnp.arange(tokens.shape[1], dtype=jnp.int32), tokens.shape)
@@ -1388,6 +1604,12 @@ class TransformerLM(nn.Module):
             run_args, run_axes = args, in_axes
             if cfg.decode:
                 run_args += (jnp.arange(n, dtype=jnp.int32),)
+            if kind == "mamba":
+                if not cfg.decode:
+                    run_args += (jnp.arange(n, dtype=jnp.int32),)
+                    run_axes += (0,)
+                run_args += (None, slots)
+                run_axes += (nn.broadcast, nn.broadcast)
             if kind == "expert":
                 # The held experts of every expert layer, in one stack
                 # a matrix, outside the scan (models/experts.py).
@@ -1422,6 +1644,8 @@ class TransformerLM(nn.Module):
             self.sow("counts", "moe", sum(c.sum(0) for c in counts["moe"]))
         if "sparse" in counts:
             self.sow("counts", "sparse", jnp.concatenate(counts["sparse"]))
+        if "ssm" in counts:   # models/ssm.py COUNTS, summed
+            self.sow("counts", "ssm", sum(c.sum(0) for c in counts["ssm"]))
 
         x = RMSNorm(cfg.dtype, cfg.norm_eps, name="ln_f")(x)
         if return_hidden:
@@ -1431,14 +1655,19 @@ class TransformerLM(nn.Module):
             # whole. lm_head params still exist (created at init via the
             # normal path); the train loop consumes them directly.
             return x
-        if cfg.quant == "int8":
+        if cfg.tie_embeddings:
+            head = embed.attend
+        elif cfg.quant == "int8":
             head = QuantDenseGeneral((cfg.vocab_size,), axis=(-1,),
                                      dtype=cfg.dtype, name="lm_head")
         else:
             head = nn.Dense(cfg.vocab_size, use_bias=False,
                             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                             name="lm_head")
-        return head(x).astype(jnp.float32)
+        logits = head(x).astype(jnp.float32)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
 
 
 # ---------------------------------------------------------------------------

@@ -137,7 +137,8 @@ class FlightRecorder:
         0. ``stalled_s`` sums prefill dispatch times the request
         waited through while decoding; on a backend that dispatches
         asynchronously (the TPU) those are enqueue times, and the
-        prefill's device time shows as a longer decode chunk."""
+        prefill's device time shows as a longer decode chunk. ``slot``
+        is where the request last ran (-1: never admitted)."""
         t_done = req.t_done or time.monotonic()
         t_admit = req.t_admitted or t_done
         t_first = req.t_first or t_done
@@ -156,6 +157,7 @@ class FlightRecorder:
             "decode_s": round(decode, 6),
             "stalled_s": round(float(req.stall_s), 6),
             "spec_accept": None if accept is None else round(accept, 4),
+            "slot": int(req.slot),
         }
 
     def retire(self, req) -> None:

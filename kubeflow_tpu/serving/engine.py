@@ -347,7 +347,7 @@ class Request:
                  "t_first", "stall_s", "preempts", "spec_prop",
                  "spec_acc", "_flight", "qos", "deadline", "on_token",
                  "tenant", "meter_skip", "_usage", "it_admitted",
-                 "t_prefill_end", "prefill_iters")
+                 "t_prefill_end", "prefill_iters", "slot")
 
     _rid_counter = itertools.count(1)
 
@@ -413,6 +413,10 @@ class Request:
         self.it_admitted = 0
         self.t_prefill_end = 0.0
         self.prefill_iters = 0
+        # The slot of the latest admission (-1 before the first): with
+        # slot-indexed state, where DecodeEngine.slot_state() finds
+        # what the request left behind.
+        self.slot = -1
         self.stall_s = 0.0            # stall seconds while active
         self.preempts = 0
         self.spec_prop = 0            # draft tokens proposed for us
@@ -716,6 +720,15 @@ _COUNT_FAMILIES = {
         "call).",
     "kfx_lm_moe_max_rows_total":
         "Rows of the fullest held expert, summed over dispatches.",
+    "kfx_lm_ssm_row_updates_total":
+        "Rows whose recurrent state a decode step advanced, summed over "
+        "steps and state-space layers.",
+    "kfx_lm_ssm_prefill_tokens_total":
+        "Prompt tokens run through the chunked scan, summed over "
+        "state-space layers.",
+    "kfx_lm_state_resets_total":
+        "Rows that started from an empty state (a sequence's first "
+        "token), summed over state-space layers.",
 }
 
 
@@ -733,7 +746,7 @@ class DecodeEngine:
                  request_timeout_s: float = 50.0,
                  kv_page_size: int = 32,
                  kv_pages: Optional[int] = None,
-                 prefix_cache: bool = True,
+                 prefix_cache: Optional[bool] = None,
                  draft_layers: int = 0,
                  propose_tokens: int = 4,
                  draft_kv_pages: Optional[int] = None,
@@ -810,8 +823,38 @@ class DecodeEngine:
         # follow it (the draft cfg derives from self.cfg below).
         self.cfg = dataclasses.replace(
             base, kv_page_size=ps, kv_pages=self.n_pages,
-            kv_quant=kv_quant or base.kv_quant)
+            kv_quant=kv_quant or base.kv_quant,
+            state_slots=n_slots if base.has_slot_state else 0)
         self.name = name
+        if prefix_cache is None:
+            # On, where the configuration can take it; asked for by
+            # name where it cannot, it is refused below.
+            prefix_cache = not base.has_slot_state
+        if base.has_slot_state:
+            # A row's state lies in leaves indexed by slot, beside its
+            # pages: what takes a row's state to BE its pages is
+            # refused by name. (Preemption by recompute works: a
+            # sequence's first token starts from zeros: models/ssm.py.)
+            for asked, feature, why in (
+                    (prefix_cache, "the prefix cache",
+                     "a page match says nothing of the state at that "
+                     "position (KFX_LM_PREFIX_CACHE=0)"),
+                    (draft_layers > 0, "speculative decoding",
+                     "a rejected proposal cannot be rolled back out of "
+                     "a state"),
+                    (bool(adapters), "LoRA adapters",
+                     "the adapter stacks have no state-space targets"),
+                    (bool(models), "the weight pool",
+                     "it has not been driven with state beside pages"),
+                    (role != "mixed" or kv_peer_send is not None
+                     or kv_offload_pages > 0,
+                     "KV offload, migration and transfer",
+                     "they move pages, and a slot's state would have to "
+                     "move with them")):
+                if asked:
+                    raise ValueError(
+                        f"{feature} cannot take a configuration with "
+                        f"slot state ('mamba' layers): {why}")
         if base.layer_pattern or base.kv_lora_rank > 0:
             # What still assumes one run of layers named "layers" or
             # K/V leaves a head: refused by name, none runs wrong.
@@ -1068,6 +1111,12 @@ class DecodeEngine:
 
         # -- device state (touched only by the loop thread after start)
         self._cache = self._init_cache()
+        # HBM a slot's recurrent state takes whatever its request's
+        # length: the leaves indexed by slot (models/ssm.py), all
+        # layers. 0 for a configuration whose rows are their pages.
+        self.state_bytes_per_slot = sum(
+            int(np.prod(x.shape[2:])) * x.shape[0] * x.dtype.itemsize
+            for _, x in self._cache_leaves("ssm"))
         self._logbuf = self._init_logbuf()
         self._draft_cache = self._init_cache(draft=True) if self.spec \
             else None
@@ -1216,21 +1265,56 @@ class DecodeEngine:
         concurrent-admission multiplier at a fixed pool byte budget
         (docs/serving.md HBM accounting)."""
         c = self.cfg
-        if c.kv_lora_rank > 0:
-            import jax
-
+        if c.kv_lora_rank > 0 or c.has_slot_state:
             # Latent attention: what the leaves hold a token (latent +
-            # rotary, the indexer's key, int8 scales), all layers.
-            return int(sum(
+            # rotary, the indexer's key, int8 scales), all layers. Slot
+            # state: the attention layers' leaves alone.
+            return sum(
                 int(np.prod(x.shape[3:])) * x.shape[0] * x.dtype.itemsize
-                for path, x in jax.tree_util.tree_flatten_with_path(
-                    self._cache_specs())[0]
-                if getattr(path[-1], "key", "") != "cached_pos"))
+                for name, x in self._cache_leaves("attn")
+                if name != "cached_pos")
         if c.kv_quant == "int8":
-            return (2 * c.n_layers * c.n_heads * c.head_dim
+            return (2 * c.n_layers * c.kv_heads * c.head_dim
                     + 2 * c.n_layers * 4 + 4)
         item = np.dtype(c.dtype).itemsize
-        return 2 * c.n_layers * c.n_heads * c.head_dim * item + 4
+        return 2 * c.n_layers * c.kv_heads * c.head_dim * item + 4
+
+    def _cache_leaves(self, module: str):
+        """(leaf name, spec) of the cache leaves under ``module``
+        ("attn": paged; "ssm": indexed by slot)."""
+        import jax
+
+        return [(getattr(path[-1], "key", ""), x) for path, x in
+                jax.tree_util.tree_flatten_with_path(self._cache_specs())[0]
+                if any(getattr(k, "key", "") == module for k in path)]
+
+    def slot_state(self, slot: int) -> Dict[str, np.ndarray]:
+        """Host copies of what ``slot`` holds in the leaves indexed by
+        slot, every state-space layer in the model's order: ``state``
+        [layers, H, P, N] and ``conv`` [layers, taps x width]. A slot
+        keeps what its last request left until another takes it (a
+        sequence's first token starts from zeros: models/ssm.py).
+        Read on the loop thread at an iteration boundary, where no
+        dispatch holds the donated cache. What a snapshot of a row's
+        state would start from (ROADMAP B-I.5); today the
+        benchmark's comparison with its reference reads it."""
+        if not self.cfg.has_slot_state:
+            raise ValueError(
+                f"engine {self.name} holds no slot state (no 'mamba' "
+                "layers): a row's state is its pages")
+        if not 0 <= slot < self.n_slots:
+            raise ValueError(f"slot {slot} of {self.n_slots}")
+
+        def read():
+            out: Dict[str, List[np.ndarray]] = {}
+            for run, kind, _ in self.cfg.layer_runs:
+                if kind == "mamba":
+                    for name, leaf in self._cache[run]["ssm"].items():
+                        out.setdefault(name, []).append(
+                            np.asarray(leaf[:, slot]))
+            return {k: np.concatenate(v) for k, v in out.items()}
+
+        return self._run_on_loop(read)
 
     def _quant_labels(self) -> Tuple[str, str]:
         """(weights, kv) label values for the ``kfx_lm_quant_mode``
@@ -1439,6 +1523,16 @@ class DecodeEngine:
                   "KV-cache bytes per cached token (entries + "
                   "quantization scales + position id).").set(
                       self.kv_bytes_per_token, model=self.name)
+        # What a slot holds beside pages (0 where rows are their pages).
+        reg.gauge("kfx_lm_state_bytes_per_slot",
+                  "Recurrent-state bytes a slot holds whatever its "
+                  "request's length (leaves indexed by slot).").set(
+                      self.state_bytes_per_slot, model=self.name)
+        reg.gauge("kfx_lm_state_slots_in_use",
+                  "Slots whose recurrent state belongs to a request in "
+                  "flight.").set(
+                      self._active_count() if self.cfg.has_slot_state
+                      else 0, model=self.name)
         # Info-style gauge: constant 1, the mode rides the labels (the
         # Prometheus _info idiom) — alerts join on weights/kv instead
         # of parsing a free-form string.
@@ -1678,7 +1772,7 @@ class DecodeEngine:
         fn.__name__ = fn.__qualname__ = f"run_kfx_{what}"
         return fn
 
-    def _report_attend(self, program: str, batch: int,
+    def _report_attend(self, program: str, batch: int, window: int,
                        draft: bool = False) -> None:
         """``kfx_lm_attend_positions{model,program}``: the K/V
         positions every query row of a compiled program scores, set
@@ -1690,11 +1784,12 @@ class DecodeEngine:
         ``kfx_lm_kv_pages_free`` it says when the in-place form pays
         for an empty pool: its cost follows the pool's size, not the
         live tokens."""
-        from ..models.transformer import attends_pool_in_place
+        from ..models.transformer import attends_pool_in_place, score_bytes
 
         cfg = self.draft_cfg if draft else self.cfg
         in_place = attends_pool_in_place(
-            batch, cfg.max_seq_len, cfg.kv_pages, cfg.kv_page_size)
+            batch, cfg.max_seq_len, cfg.kv_pages, cfg.kv_page_size,
+            score_bytes(cfg, window))
         self._reg().gauge(
             "kfx_lm_attend_positions",
             "K/V positions a query row scores in a compiled program "
@@ -1754,6 +1849,9 @@ class DecodeEngine:
 
         model, counted = self.model, self._counted
         mutable = ["cache"] + (["counts"] if counted else [])
+        # The leaves indexed by slot are read and written at the
+        # prompt's slot; the decode chunk's row i is slot i.
+        slot_state = self.cfg.has_slot_state
 
         def run(params, cache, logbuf, tokens, table, slot, true_len,
                 start, lora, aid):
@@ -1774,7 +1872,8 @@ class DecodeEngine:
             logits, vars_ = model.apply(
                 {"params": params, "cache": cache}, tokens,
                 positions=pos, block_tables=table, lora=lora,
-                adapter_ids=aid, mutable=mutable)
+                adapter_ids=aid, mutable=mutable, **(
+                    {"slots": slot[None]} if slot_state else {}))
             last = jax.lax.dynamic_slice_in_dim(
                 logits, true_len - 1, 1, axis=1)[0, 0]  # [V]
             logbuf = jax.lax.dynamic_update_slice_in_dim(
@@ -1798,7 +1897,7 @@ class DecodeEngine:
             self._lora_specs(),
             jax.ShapeDtypeStruct((1,), np.int32),
         )
-        self._report_attend(f"prefill_{P}", 1)
+        self._report_attend(f"prefill_{P}", 1, P)
         return self._report_temp(f"prefill_{P}", jax.jit(
             self._named(run, f"prefill_{P}"),
             donate_argnums=donate).lower(*specs).compile())
@@ -1904,7 +2003,7 @@ class DecodeEngine:
             self._lora_specs(),
             sds((B,), np.int32),      # adapter ids
         )
-        self._report_attend("decode_chunk", B)
+        self._report_attend("decode_chunk", B, 1)
         return self._report_temp("decode_chunk", jax.jit(
             self._named(run, "decode_chunk"),
             donate_argnums=donate).lower(*specs).compile())
@@ -2100,7 +2199,7 @@ class DecodeEngine:
             self._lora_specs(draft=True),
             jax.ShapeDtypeStruct((1,), np.int32),
         )
-        self._report_attend(f"draft_prefill_{P}", 1, draft=True)
+        self._report_attend(f"draft_prefill_{P}", 1, P, draft=True)
         return self._report_temp(f"draft_prefill_{P}", jax.jit(
             self._named(run, f"draft_prefill_{P}"),
             donate_argnums=donate).lower(*specs).compile())
@@ -2368,8 +2467,8 @@ class DecodeEngine:
             self._lora_specs(draft=True),
             sds((B,), np.int32),      # adapter ids
         )
-        self._report_attend("spec_step", B)
-        self._report_attend("spec_step_draft", B, draft=True)
+        self._report_attend("spec_step", B, k + 1)    # the verify window
+        self._report_attend("spec_step_draft", B, 1, draft=True)
         return self._report_temp("spec_step", jax.jit(
             self._named(run, "spec_step"),
             donate_argnums=donate).lower(*specs).compile())
@@ -2407,16 +2506,15 @@ class DecodeEngine:
                                        self.cfg.max_seq_len)
             self._prefill_for(chunk_bucket)
         for b in buckets if buckets is not None else self.prompt_buckets:
-            if self.cfg.kv_lora_rank > 0 and 0 < chunk_bucket < b:
-                # A latent configuration's long-context buckets: no
-                # admission dispatches them (a tail longer than the
-                # chunk goes in chunks), and at 32 k tokens such a
-                # program does not fit beside the pool. (The same holds
-                # for the dense block's; whether set-up should skip
-                # them there is a perf change with pairs of its own.)
-                continue
-            self._prefill_for(int(b))
+            if not 0 < chunk_bucket < b:
+                # No admission dispatches a bucket over the chunk's: a
+                # tail longer than the chunk goes in chunks
+                # (_admit_resolved), so such a program would only be
+                # compiled and held (at 32 k tokens it does not fit
+                # beside the pool).
+                self._prefill_for(int(b))
             if self.spec:
+                # (The draft prefills a prompt whole: _admit_draft.)
                 self._draft_prefill_for(int(b))
         with self._exec_lock:
             return (len(self._prefill_exec)
@@ -3060,6 +3158,11 @@ class DecodeEngine:
             raise ValueError(
                 f"engine {self.name} hosts a weight pool: migrated "
                 "pages would decode under the peer's weights")
+        if self.cfg.has_slot_state:
+            raise ValueError(
+                f"engine {self.name} holds slot state ('mamba' layers): "
+                "migration moves pages, and a slot's state would have "
+                "to move with them")
         send = send if send is not None else self._peer_send
         if send is None:
             raise ValueError(
@@ -3162,6 +3265,10 @@ class DecodeEngine:
             raise kvtransfer.TransferError(
                 f"engine {self.name} hosts a weight pool: imported "
                 "pages would decode under a different model's weights")
+        if self.cfg.has_slot_state:
+            raise kvtransfer.TransferError(
+                f"engine {self.name} holds slot state ('mamba' layers): "
+                "an import brings pages, and no state for the slot")
         inj = chaos.draw("kv.transfer", target=self.name)
         if inj is not None:
             if inj.delay > 0:
@@ -4031,6 +4138,7 @@ class DecodeEngine:
         self._aids[slot] = aid
         self._wids[slot] = wid    # slot owns the weight-pool pin now
         self._slots[slot] = req
+        req.slot = slot
         if self.spec:
             self._admit_draft(req, slot, full, n)
 
@@ -4215,6 +4323,7 @@ class DecodeEngine:
         self._aids[slot] = aid
         self._wids[slot] = wid    # slot owns the weight-pool pin now
         self._slots[slot] = req
+        req.slot = slot
         self._prefilling[slot] = {
             "req": req, "full": full, "n": n, "next": matched,
             "key": key, "reg_block": len(shared),
@@ -4796,6 +4905,11 @@ class DecodeEngine:
         reg = self._reg()
         reg.counter("kfx_lm_engine_chunks_total",
                     "Decode-chunk dispatches.").inc(1, model=self.name)
+        with self._phase("engine.bookkeeping"):
+            # Before the tokens go out: a client that holds its last
+            # token finds the layers' counts of it in /metrics, and
+            # they grow with the chunk counter, not a delivery later.
+            self._flush_counts()
         emitted = 0
         with self._phase("engine.deliver"):
             for slot, req in enumerate(self._slots):
@@ -4825,7 +4939,6 @@ class DecodeEngine:
                 reg.counter("kfx_lm_generated_tokens_total",
                             "Tokens generated since startup.").inc(
                                 emitted, model=self.name)
-            self._flush_counts()
             self._touch_gauges()
 
     @property
@@ -4839,7 +4952,8 @@ class DecodeEngine:
         c = self.cfg
         return tuple(what for what, on in (
             ("sparse", c.kv_lora_rank > 0 and c.index_topk > 0),
-            ("moe", any(k == "expert" for _, k, _ in c.layer_runs)))
+            ("moe", any(k == "expert" for _, k, _ in c.layer_runs)),
+            ("ssm", c.has_slot_state))
             if on)
 
     def _keep_counts(self, out, n: int):
@@ -4856,14 +4970,16 @@ class DecodeEngine:
         so every program enqueued before it has run and no read here
         waits."""
         sums = {"sparse": np.zeros(2, np.int64),
-                "moe": np.zeros(4, np.int64)}
+                "moe": np.zeros(4, np.int64),
+                "ssm": np.zeros(3, np.int64)}
         for counts in self._counts_pending:
             for what, c in zip(self._counted, counts):
                 c = np.asarray(c, np.int64)
                 sums[what] += c.reshape(-1, c.shape[-1]).sum(0)
         self._counts_pending.clear()
         reg = self._reg()
-        values = list(sums["sparse"]) + list(sums["moe"])
+        values = list(sums["sparse"]) + list(sums["moe"]) \
+            + list(sums["ssm"])
         for (family, text), v in zip(_COUNT_FAMILIES.items(), values):
             reg.counter(family, text).inc(int(v), model=self.name)
 

@@ -82,6 +82,7 @@ def export_lm(directory: str, cfg, params, quantize: str = "") -> str:
     d = dataclasses.asdict(cfg)
     d["dtype"] = jnp.dtype(cfg.dtype).name
     d["param_dtype"] = jnp.dtype(cfg.param_dtype).name
+    d["ssm_state_dtype"] = jnp.dtype(cfg.ssm_state_dtype).name
     meta: Dict[str, Any] = {"framework": "lm",
                             "format_version": FORMAT_VERSION,
                             "config": d}
@@ -180,8 +181,10 @@ class LMPredictor(Predictor):
         self.kv_page_size = int(
             os.environ.get("KFX_LM_KV_PAGE_SIZE", "32"))
         self.kv_pages = int(os.environ.get("KFX_LM_KV_PAGES", "0"))
-        self.prefix_cache = \
-            os.environ.get("KFX_LM_PREFIX_CACHE", "1") != "0"
+        # (None: the engine's default, on where the configuration
+        # can take it.)
+        self.prefix_cache = {None: None, "0": False}.get(
+            os.environ.get("KFX_LM_PREFIX_CACHE"), True)
         # Chunked prefill (docs/serving.md): prompt tails longer than
         # this admit in page-multiple chunks, one chunk dispatch per
         # engine iteration, bounding the decode stall a long prompt
@@ -483,6 +486,21 @@ class LMPredictor(Predictor):
         if self._engine is None or self._engine.flight is None:
             return None
         return self._engine.flight.requests()
+
+    def slot_state(self, slot: int) -> bytes:
+        """The /debug/state payload: what ``slot`` holds in the leaves
+        indexed by slot (``DecodeEngine.slot_state``), as an .npz of
+        float32 arrays. ValueError where the configuration has no such leaves."""
+        import io
+
+        if self._engine is None:
+            raise ValueError(f"model {self.name} is not loaded")
+        buf = io.BytesIO()
+        # (bfloat16 and the float8s are no .npy types: as float32)
+        np.savez(buf, **{
+            k: v.astype(np.float32)
+            for k, v in self._engine.slot_state(slot).items()})
+        return buf.getvalue()
 
     def pooled_models(self) -> Dict[str, bool]:
         """{model name: resident-in-HBM?} over the weight pool's full

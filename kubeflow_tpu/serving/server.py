@@ -404,10 +404,10 @@ class ModelServer:
                                 "application/json",
                                 extra_headers=extra_headers)
 
-            def _send_text(self, code: int, text: str, ctype: str,
+            def _send_text(self, code: int, text, ctype: str,
                            extra_headers: Optional[Dict[str, str]] = None
                            ) -> None:
-                body = text.encode()
+                body = text if isinstance(text, bytes) else text.encode()
                 self.send_response(code)
                 self.send_header("Content-Type", ctype)
                 self.send_header("Content-Length", str(len(body)))
@@ -682,6 +682,23 @@ class ModelServer:
                                        "or KFX_FLIGHT=0)"})
             else:
                 h._send(200, {"models": snaps})
+        elif path.startswith("/debug/state?"):
+            # What a slot holds in the leaves indexed by slot (a
+            # configuration with state-space layers), as an .npz:
+            # /debug/state?model=<name>&slot=<i>.
+            from urllib.parse import parse_qs, urlsplit
+
+            q = parse_qs(urlsplit(path).query)
+            p = self.predictors.get((q.get("model") or [""])[0])
+            fn = getattr(p, "slot_state", None)
+            try:
+                if fn is None:
+                    raise ValueError("no such model, or it holds no "
+                                     "slot state")
+                h._send_text(200, fn(int((q.get("slot") or ["-1"])[0])),
+                             "application/octet-stream")
+            except ValueError as e:
+                h._send(404, {"error": str(e)})
         elif path == "/metrics" or path.startswith("/metrics?"):
             # Prometheus exposition by default (the reference model
             # servers are Prometheus-scrapable); JSON via ?format=json.

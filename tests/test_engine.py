@@ -410,8 +410,11 @@ class TestPagedKV:
 def small_pool_engine(tiny_lm):
     """A pool smaller than its slots' logical view (8 pages of 16
     against 4 slots x 64): the decode chunk attends the pool in place
-    under the page-membership mask, the prefill programs (one row: 64
-    < 128) gather — models/transformer.py ``attends_pool_in_place``."""
+    under the page-membership mask; of the prefill programs (one row:
+    a view of 64 under a pool of 128) the 8-token one does too (a
+    gather moves a position three times) and the longer ones, whose
+    scores outweigh that, gather — models/transformer.py
+    ``attends_pool_in_place``."""
     from kubeflow_tpu.obs.metrics import MetricsRegistry
     from kubeflow_tpu.serving.engine import DecodeEngine
 
@@ -444,8 +447,8 @@ class TestPoolInPlace:
             "kfx_lm_attend_positions", "")
         assert gauge.value(model="lm-pool",
                            program="decode_chunk") == 8 * 16
-        assert {v for lab, v in gauge.samples()
-                if lab["program"].startswith("prefill_")} == {64}
+        assert gauge.value(model="lm-pool", program="prefill_8") == 8 * 16
+        assert gauge.value(model="lm-pool", program="prefill_32") == 64
 
     def test_a_prefix_cache_hit_shares_a_page_between_rows(
             self, tiny_lm, small_pool_engine):
@@ -1125,13 +1128,15 @@ class TestSpeculative:
 
     @pytest.mark.parametrize("program, positions", [
         ("spec_step", 8 * 16), ("spec_step_draft", 8 * 16),
-        ("prefill_8", 64), ("draft_prefill_8", 64)])
+        ("prefill_8", 8 * 16), ("draft_prefill_8", 8 * 16),
+        ("prefill_32", 64), ("draft_prefill_32", 64)])
     def test_attend_positions_of_both_pools(self, spec_pool_engine,
                                             program, positions):
         """The fused step's verify window and draft steps (4 rows)
-        attend their 8-page pools in place; both models' prefill
-        programs (one row) gather ``max_seq_len``."""
-        spec_pool_engine.warm([8])
+        attend their 8-page pools in place, and so do both models'
+        8-token prefill programs (one row); their 32-token ones gather
+        ``max_seq_len``."""
+        spec_pool_engine.warm([8, 32])
         assert spec_pool_engine._reg().gauge(
             "kfx_lm_attend_positions", "").value(
                 model="lm-sp", program=program) == positions

@@ -55,6 +55,7 @@ class _FakeReq:
         self.t_done = 102.0
         self.t_prefill_end = 100.9
         self.prefill_iters = 2
+        self.slot = 0
         for k, v in kw.items():
             setattr(self, k, v)
 

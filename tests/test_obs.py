@@ -446,16 +446,22 @@ class TestModelServerMetrics:
                     assert r.status == 200
                     assert r.headers["X-Kfx-Trace-Id"] == \
                         "feedface00000001"
-            with urllib.request.urlopen(f"{base}/metrics",
-                                        timeout=10) as r:
-                text = r.read().decode()
+            # A request's time is observed once its response is out:
+            # the fifth may still be on its way into the histogram.
+            for _ in range(40):
+                with urllib.request.urlopen(f"{base}/metrics",
+                                            timeout=10) as r:
+                    text = r.read().decode()
+                parsed = parse_prom_text(text)
+                counts = [v for lab, v in
+                          parsed["kfx_serving_request_seconds_count"]
+                          if lab.get("model") == "echo"]
+                if counts and counts[0] == 5:
+                    break
+                time.sleep(0.05)
             assert validate_exposition(text) == []
             assert "kfx_serving_request_seconds_bucket" in text
             assert 'model="echo"' in text
-            parsed = parse_prom_text(text)
-            counts = [v for lab, v in
-                      parsed["kfx_serving_request_seconds_count"]
-                      if lab.get("model") == "echo"]
             assert counts and counts[0] == 5
             with urllib.request.urlopen(f"{base}/metrics?format=json",
                                         timeout=10) as r:

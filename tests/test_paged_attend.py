@@ -25,9 +25,18 @@ from kubeflow_tpu.models.transformer import (Attention, Block, RMSNorm,
                                              TransformerConfig,
                                              TransformerLM,
                                              attends_pool_in_place,
-                                             init_cache)
+                                             init_cache, score_bytes)
 
 B, H, D, P, L, N = 4, 2, 16, 4, 16, 12       # B*L = 64 >= N*P = 48
+
+
+def _score(dtype, window=1, kv_quant=""):
+    """``score_bytes`` of this file's heads (what a cached position's
+    scores cost a row, over its keys and values)."""
+    return score_bytes(TransformerConfig(
+        vocab_size=8, d_model=H * D, n_heads=H, head_dim=D, n_layers=1,
+        d_ff=8, dtype=dtype, kv_page_size=P, kv_pages=N,
+        kv_quant=kv_quant), window)
 
 
 class _Attend(Attention):
@@ -154,7 +163,8 @@ def _attend(trap, dtype, args):
 def test_in_place_agrees_with_the_gathered_form(trap, dtype, monkeypatch):
     spec = TRAPS[trap]
     *args, active = _pool(spec, dtype)
-    assert attends_pool_in_place(B, L, N, P)
+    assert attends_pool_in_place(B, L, N, P, _score(
+        dtype, spec.get("S", 1), spec.get("kv_quant", "")))
     in_place, cache_a = _attend(spec, dtype, args)
     monkeypatch.setattr(transformer, "attends_pool_in_place",
                         lambda *shape: False)
@@ -204,18 +214,25 @@ def test_the_trap_is_one_without_membership(trap):
                   - np.asarray(without)[active]).max() > 1e-2
 
 
-@pytest.mark.parametrize("batch, max_seq_len, kv_pages, page, in_place", [
-    (16, 1536, 288, 32, True),      # the serving cell's decode chunk
-    (1, 1536, 288, 32, False),      # and its prefill programs
-    (4, 64, 16, 16, True),          # an engine's default pool: equality
-    (1, 64, 4, 16, True),           # one slot, one row's worth of pages
-    (4, 64, 17, 16, False),         # a pool larger than the logical view
-], ids=["cell_decode", "cell_prefill", "default_pool", "one_row",
-        "oversized_pool"])
+@pytest.mark.parametrize(
+    "batch, max_seq_len, kv_pages, page, window, in_place", [
+        (16, 1536, 288, 32, 1, True),    # the serving cell's decode chunk
+        (1, 1536, 288, 32, 256, False),  # and its prefill programs
+        (4, 64, 16, 16, 1, True),        # an engine's default pool: equality
+        (1, 64, 4, 16, 1, True),         # one slot, one row's worth of pages
+        (4, 64, 17, 16, 1, True),        # a pool somewhat over the view: a
+                                         # gather moves a position three times
+        (4, 64, 52, 16, 1, False),       # a pool over three times the view
+    ], ids=["cell_decode", "cell_prefill", "default_pool", "one_row",
+            "larger_pool", "oversized_pool"])
 def test_the_form_is_read_off_the_shapes(batch, max_seq_len, kv_pages, page,
-                                         in_place):
-    assert attends_pool_in_place(batch, max_seq_len, kv_pages,
-                                 page) is in_place
+                                         window, in_place):
+    """With the serving cell's heads (32 x 128 a token, bfloat16)."""
+    score = score_bytes(TransformerConfig(
+        vocab_size=8, d_model=4096, n_heads=32, head_dim=128, n_layers=1,
+        d_ff=8), window)
+    assert attends_pool_in_place(batch, max_seq_len, kv_pages, page,
+                                 score) is in_place
 
 
 # -- the carried cache against one pool a layer ------------------------------
@@ -232,7 +249,8 @@ def _lm(rows, kv_quant=""):
         n_layers=LAYERS, d_ff=48, max_seq_len=L, dtype=jnp.float32,
         attn_impl="xla", decode=True, kv_page_size=P, kv_pages=N,
         kv_quant=kv_quant)
-    assert attends_pool_in_place(rows, L, N, P) is (rows == B)
+    assert attends_pool_in_place(
+        rows, L, N, P, score_bytes(cfg, 1)) is (rows == B)
     params = TransformerLM(dataclasses.replace(cfg, decode=False)).init(
         jax.random.PRNGKey(3), jnp.zeros((1, 4), jnp.int32))["params"]
     return cfg, params

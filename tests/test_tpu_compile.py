@@ -112,14 +112,14 @@ def test_in_place_decode_attention_compiles_for_v5e(one_chip):
     import flax.linen as nn
 
     from kubeflow_tpu.models.transformer import (
-        Attention, TransformerConfig, attends_pool_in_place)
+        Attention, TransformerConfig, attends_pool_in_place, score_bytes)
 
     B, H, D, L, P, N = 16, 32, 128, 1536, 32, 288
-    assert attends_pool_in_place(B, L, N, P)
     cfg = TransformerConfig(
         vocab_size=256, d_model=H * D, n_heads=H, head_dim=D, n_layers=1,
         d_ff=256, max_seq_len=L, dtype=jnp.bfloat16, decode=True,
         kv_page_size=P, kv_pages=N)
+    assert attends_pool_in_place(B, L, N, P, score_bytes(cfg, 1))
 
     class Attend(Attention):
         @nn.compact
@@ -284,6 +284,86 @@ def test_latent_pool_is_written_in_place_at_the_cells_size(one_chip,
     assert "ragged-dot" in text
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 2 << 30
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < int(15.75 * 2 ** 30))
+
+
+@pytest.mark.parametrize("program", ["decode_chunk", "prefill_256"])
+def test_slot_state_is_written_in_place_at_the_cells_size(one_chip,
+                                                          monkeypatch,
+                                                          program):
+    """The engine's programs for
+    ``benchmark/configs/granite-4.0-h-micro.json`` as the cell serves it
+    (64 slots, 4096 pages of 32, every layer and width the published
+    one): they compile for the chip, fit it beside 6.4 GB of weights,
+    and update the slots' state and the grouped K/V pool where they
+    lie, through all nine scans of the runs. What the first forms cost
+    (AOT, PR 40): one scan over the four periods with a scan a run
+    inside it sliced a whole period's kernels out of their stacks every
+    step (a copy of all the weights a token); the convolution's window declared
+    [..., 3, 4352] and the K/V entry [..., 8, 64] were padded to tiles
+    and copied whole a dispatch (1.3 GB and 2 x 1.0 GB), and the
+    published in_proj as one kernel of 8512 columns was copied into a
+    padded layout a dispatch (1.25 GB)."""
+    import re
+
+    from benchmark import kfx_adapter_granitemoehybrid as A
+    from benchmark.manifest import BENCH_DIR, load_json
+    from kubeflow_tpu.models.transformer import (
+        TransformerConfig, TransformerLM, init_cache)
+    from kubeflow_tpu.serving.engine import DecodeEngine
+
+    published = load_json(os.path.join(BENCH_DIR, "configs",
+                                       "granite-4.0-h-micro.json"))
+    serving = published["serving"]
+    L, P, N = (serving["max_seq_len"], serving["kv_page_size"],
+               serving["kv_pages"])
+    cfg = TransformerConfig(**A.transformer_kwargs(
+        published, max_seq_len=L, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, decode=True, kv_page_size=P, kv_pages=N,
+        state_slots=serving["slots"]))
+    eng = object.__new__(DecodeEngine)
+    eng.cfg, eng.model, eng.name = cfg, TransformerLM(cfg), "aot"
+    eng.n_slots, eng.chunk_tokens, eng.n_blocks = serving["slots"], 8, L // P
+    eng._donate, eng._apool, eng._registry = True, None, None
+    tree, _ = A.host_views(published, jnp.bfloat16)   # shapes: never touched
+    eng.params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+    eng._cache = jax.eval_shape(lambda: init_cache(cfg))
+    jit = jax.jit
+
+    class ForTheChip:
+        def __init__(self, fn, **kw):
+            self.jitted = jit(fn, **kw)
+
+        def lower(self, *specs):
+            return self.jitted.lower(*jax.tree_util.tree_map(
+                lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                               sharding=one_chip), specs))
+
+    monkeypatch.setattr(jax, "jit", ForTheChip)
+    compiled = eng._build_decode() if program == "decode_chunk" \
+        else eng._build_prefill(256)
+    monkeypatch.undo()
+    text = compiled.as_text()
+    assert f"jit_run_kfx_{program}" in text.splitlines()[0]
+    carried = "|".join(re.escape(leaf) for leaf in (
+        "f32[5,64,64,64,128]", "f32[9,64,64,64,128]",
+        "f32[4,64,64,64,128]",                           # the state
+        "bf16[5,64,13056]", "bf16[9,64,13056]",
+        "bf16[4,64,13056]",                              # the windows
+        f"bf16[1,{N},{P},512]"))                         # grouped K/V
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(rf"= ({carried})\S* (copy|copy-done)\(", line)]
+    assert not copies, copies
+    # no stack of kernels is copied or sliced out whole
+    kernels = r"bf16\[[459],(2048,8448|2048,16384|8192,2048|4096,2048)\]"
+    assert not re.search(
+        rf"= {kernels}\S* (copy|copy-done|fusion|dynamic-slice)\(", text)
+    # every carried leaf is an argument its result aliases
+    assert text.splitlines()[0].count("may-alias") >= 20
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < int(0.8 * 2 ** 30)
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < int(15.75 * 2 ** 30))
 
