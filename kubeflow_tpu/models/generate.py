@@ -64,23 +64,63 @@ def decode_config(cfg: TransformerConfig,
         max_seq_len=max_len or cfg.max_seq_len)
 
 
-@jax.named_scope("sample")  # the sampler's ops by name in a trace
 def _sample(logits: jnp.ndarray, rng, temperature, top_k) -> jnp.ndarray:
-    """logits [B, V] -> token ids [B]. temperature/top_k are TRACED
-    scalars (sampling knobs never trigger a recompile — they are
-    client-controlled on the serving path): temperature<=0 selects
-    greedy, top_k<=0 disables the top-k filter."""
+    """logits [B, V] -> token ids [B]: the one-row formula. temperature/
+    top_k are TRACED scalars (sampling knobs never trigger a recompile —
+    they are client-controlled on the serving path): temperature<=0
+    selects greedy, top_k<=0 disables the top-k filter. A part that is
+    not asked for is not built: ``rng`` None gives the argmax alone,
+    ``top_k`` None the draw without the filter — the three forms
+    ``sample_rows`` picks between, once a step."""
     V = logits.shape[-1]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    if rng is None:
+        return greedy
     scaled = logits / jnp.maximum(temperature, 1e-6)
-    # k-th largest per row via a dynamic slice into the sorted row
-    # (start index clamps when top_k <= 0, and the mask is disabled).
-    srt = jnp.sort(scaled, axis=-1)
-    kth = jax.lax.dynamic_slice_in_dim(
-        srt, jnp.maximum(V - top_k, 0), 1, axis=-1)  # [B, 1]
-    masked = jnp.where((top_k > 0) & (scaled < kth), -jnp.inf, scaled)
-    sampled = jax.random.categorical(rng, masked, axis=-1).astype(jnp.int32)
+    if top_k is not None:
+        # k-th largest per row via a dynamic slice into the sorted row
+        # (start index clamps when top_k <= 0, and the mask is disabled).
+        srt = jnp.sort(scaled, axis=-1)
+        kth = jax.lax.dynamic_slice_in_dim(
+            srt, jnp.maximum(V - top_k, 0), 1, axis=-1)  # [B, 1]
+        scaled = jnp.where((top_k > 0) & (scaled < kth), -jnp.inf, scaled)
+    sampled = jax.random.categorical(rng, scaled, axis=-1).astype(jnp.int32)
     return jnp.where(temperature <= 0.0, greedy, sampled)
+
+
+def sample_needs(temperature, top_k, live) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """What a step's rows ask of the sampler, as two scalars: whether
+    any live row draws (temperature > 0) and whether one of those also
+    filters (top_k > 0). ``live`` [B] gates both: a slot keeps a retired
+    request's knobs, and its token is never emitted."""
+    drawing = live & ~(temperature <= 0.0)
+    return jnp.any(drawing), jnp.any(drawing & (top_k > 0))
+
+
+@jax.named_scope("sample")  # the sampler's ops by name in a trace
+def sample_rows(logits: jnp.ndarray, keys, temperature, top_k,
+                live) -> jnp.ndarray:
+    """logits [B, V], keys [B, 2], temperature/top_k/live [B] -> token
+    ids [B]: ``_sample`` a row with its own key and knobs, in the form
+    the step's live rows need. The choice is made once for the batch, on
+    the device, OUTSIDE the vmap (a cond on a batched predicate lowers
+    to a select that runs every side): all greedy -> the argmax and
+    nothing else; draws but no top_k -> no vocabulary sort. Every live
+    row gets the token the full form gives it, to the last bit: a greedy
+    row is the argmax in every form, and with top_k <= 0 the full form's
+    mask is off. Every form is ``_sample``'s, so what wraps that one
+    function (benchmark/tests/broken_replica.py) sees every token."""
+    draws, sorts = sample_needs(temperature, top_k, live)
+
+    def rows(filtered: bool):
+        return lambda: jax.vmap(
+            lambda l, kk, t, tk: _sample(
+                l[None], kk, t, tk if filtered else None)[0]
+        )(logits, keys, temperature, top_k)
+
+    return jax.lax.switch(
+        draws.astype(jnp.int32) + sorts.astype(jnp.int32),
+        [lambda: _sample(logits, None, None, None), rows(False), rows(True)])
 
 
 class LMGenerator:
@@ -109,7 +149,7 @@ class LMGenerator:
     # -- the compiled path --------------------------------------------------
     def _generate_fn(self, prompt_pad: int, max_new: int):
         """One compile per (batch, prompt bucket, max_new bucket);
-        sampling knobs ride in as traced scalars. ``params`` is a jit
+        sampling knobs ride in as traced arrays. ``params`` is a jit
         ARGUMENT, never a closure: a closed-over param tree is embedded
         in the lowered program as constants — 1.9G of MLIR at the base
         preset, which broke the remote-compile transport (and bloated
@@ -117,8 +157,10 @@ class LMGenerator:
         model, cfg = self.model, self.cfg
 
         @jax.jit
-        def run(params, tokens, true_len, rng, temperature, top_k):
-            """tokens [B, prompt_pad] (right-padded), true_len [B]."""
+        def run(params, tokens, true_len, rngs, temperature, top_k):
+            """tokens [B, prompt_pad] (right-padded), true_len [B];
+            rngs [B, 2], temperature [B], top_k [B]: a row's stream
+            and knobs are its own, as a slot's are in the engine."""
             B = tokens.shape[0]
             pos = jnp.arange(prompt_pad, dtype=jnp.int32)[None, :]
             pos = jnp.where(pos < true_len[:, None], pos, -1)
@@ -136,16 +178,18 @@ class LMGenerator:
                 axis=1)[:, 0]  # [B, V]
 
             def step(carry, _):
-                cache, prev_logits, cur_pos, rng = carry
-                rng, sub = jax.random.split(rng)
-                tok = _sample(prev_logits, sub, temperature, top_k)
+                cache, prev_logits, cur_pos, rngs = carry
+                split = jax.vmap(jax.random.split)(rngs)  # [B, 2, 2]
+                rngs, sub = split[:, 0], split[:, 1]
+                tok = sample_rows(prev_logits, sub, temperature, top_k,
+                                  jnp.ones((B,), bool))
                 logits, vars_ = model.apply(
                     {"params": params, "cache": cache}, tok[:, None],
                     positions=cur_pos[:, None], mutable=["cache"])
-                return ((vars_["cache"], logits[:, 0], cur_pos + 1, rng),
+                return ((vars_["cache"], logits[:, 0], cur_pos + 1, rngs),
                         tok)
 
-            init = (cache, last, true_len, rng)
+            init = (cache, last, true_len, rngs)
             _, toks = jax.lax.scan(step, init, None, length=max_new)
             return toks.T  # [B, max_new]
 
@@ -184,7 +228,10 @@ class LMGenerator:
         if fn is None:
             fn = self._generate_fn(pad, new_bucket)
             self._compiled[key] = fn
+        # Row i draws from seed + i, as DecodeEngine.generate seeds its
+        # requests: the oracle's sampled rows are the engine's too.
+        rngs = jax.vmap(jax.random.PRNGKey)(seed + jnp.arange(B))
         out = fn(self.params, jnp.asarray(tokens), jnp.asarray(true_len),
-                 jax.random.PRNGKey(seed),
-                 jnp.float32(temperature), jnp.int32(top_k))
+                 rngs, jnp.full((B,), temperature, jnp.float32),
+                 jnp.full((B,), top_k, jnp.int32))
         return np.asarray(out)[:, :max_new_tokens].tolist()

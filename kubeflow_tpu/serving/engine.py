@@ -701,8 +701,9 @@ class PrefixCache:
 
 
 # The counter families of the sparse selection (models/latent.py
-# COUNTS) and of the routed experts (models/experts.py COUNTS), in
-# those orders, with their help texts. The layers count on the device.
+# COUNTS), of the routed experts (models/experts.py COUNTS), of the
+# state-space layers and of the decode chunk's sampler, in those orders,
+# with their help texts. The programs count on the device.
 _COUNT_FAMILIES = {
     "kfx_lm_sparse_cached_positions_total":
         "Cached positions query tokens could attend, summed over tokens "
@@ -729,6 +730,15 @@ _COUNT_FAMILIES = {
     "kfx_lm_state_resets_total":
         "Rows that started from an empty state (a sequence's first "
         "token), summed over state-space layers.",
+    "kfx_lm_sample_steps_total":
+        "Decode steps run (chunks times the chunk's tokens).",
+    "kfx_lm_sample_draw_steps_total":
+        "Decode steps in which an active row drew its token "
+        "(temperature > 0); in the others the sampler took the argmax "
+        "and nothing else.",
+    "kfx_lm_sample_sort_steps_total":
+        "Decode steps in which an active drawing row set a top_k, so "
+        "the sampler sorted the vocabulary.",
 }
 
 
@@ -875,8 +885,9 @@ class DecodeEngine:
                         f"{feature} cannot take this configuration "
                         f"(layer_pattern {base.layer_pattern!r}, "
                         f"kv_lora_rank {base.kv_lora_rank}): {why}")
-        # What the programs' layers counted (_counted), one tuple a
-        # dispatch, on the device until flushed.
+        # What the programs' layers (_counted) and the decode chunk's
+        # sampler counted, one tuple a dispatch, on the device until
+        # flushed.
         self._counts_pending: List[Any] = []
         self.n_slots = n_slots
         self.chunk_tokens = chunk_tokens
@@ -1560,8 +1571,8 @@ class DecodeEngine:
                   "Prompt tokens admitted (cumulative; denominator of "
                   "the prefill-skipped fraction).").set(
                       st["prompt_tokens"], model=self.name)
-        # The selection's and the routed experts' families exist (at 0)
-        # for every configuration; they grow in _flush_counts.
+        # The layers' and the sampler's families exist (at 0) for every
+        # configuration; they grow in _flush_counts.
         for family, text in _COUNT_FAMILIES.items():
             reg.counter(family, text).inc(0, model=self.name)
         # Chunked-prefill families, pre-seeded (counter at 0; the
@@ -1917,19 +1928,11 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..models.generate import _sample
+        from ..models.generate import sample_needs, sample_rows
 
         model, k = self.model, self.chunk_tokens
         counted = self._counted
         mutable = ["cache"] + (["counts"] if counted else [])
-
-        def sample_slots(logits, keys, temp, topk):
-            # vmap the shared one-row sampler: per-slot RNG stream AND
-            # per-slot client knobs (two requests in one chunk may ask
-            # for different temperatures).
-            return jax.vmap(
-                lambda l, kk, t, tk: _sample(l[None], kk, t, tk)[0]
-            )(logits, keys, temp, topk)
 
         def run(params, cache, logbuf, tables, pos, loc, active,
                 produced, rngs, temp, topk, stop, max_new, lora, aids):
@@ -1937,7 +1940,12 @@ class DecodeEngine:
                 cache, logits, pos, loc, active, produced, rngs = carry
                 split = jax.vmap(jax.random.split)(rngs)  # [B, 2, 2]
                 next_rngs, sub = split[:, 0], split[:, 1]
-                tok = sample_slots(logits, sub, temp, topk)  # [B]
+                # The shared sampler, per-slot RNG stream AND per-slot
+                # client knobs (two requests in one chunk may ask for
+                # different temperatures), in the form this step's
+                # active rows need: all greedy, the argmax alone.
+                tok = sample_rows(logits, sub, temp, topk, active)  # [B]
+                draws, sorts = sample_needs(temp, topk, active)
                 is_stop = (stop >= 0) & (tok == stop)
                 # The stop token itself is never emitted: the slot
                 # retires and the request returns the tokens before it.
@@ -1971,7 +1979,8 @@ class DecodeEngine:
                 pos2 = jnp.where(active, pos + 1, pos)
                 loc2 = jnp.where(active, loc + 1, loc)
                 out = (tok, emit) + tuple(
-                    vars_["counts"][what][0] for what in counted)
+                    vars_["counts"][what][0] for what in counted) + (
+                        jnp.stack([True, draws, sorts]).astype(jnp.int32),)
                 return ((vars_["cache"], logits3, pos2, loc2,
                          active2, produced2, next_rngs), out)
 
@@ -2250,16 +2259,11 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..models.generate import _sample
+        from ..models.generate import sample_rows
 
         model, draft_model = self.model, self.draft_model
         B, k = self.n_slots, self.propose_tokens
         V = self.cfg.vocab_size
-
-        def sample_slots(logits, keys, temp, topk):
-            return jax.vmap(
-                lambda l, kk, t, tk: _sample(l[None], kk, t, tk)[0]
-            )(logits, keys, temp, topk)
 
         def warp(logits, temp, topk):
             """Per-slot warped next-token probs [B, S, V]: temperature
@@ -2337,7 +2341,7 @@ class DecodeEngine:
                     write_locations=eff_loc[:, None], lora=dlora,
                     adapter_ids=aids, mutable=["cache"])
                 lg = logits[:, 0]
-                nxt = sample_slots(lg, sub, temp, topk)
+                nxt = sample_rows(lg, sub, temp, topk, active)
                 return ((vars_["cache"], nxt, dpos + 1, dloc + 1,
                          next_rngs), (nxt, lg))
 
@@ -4958,9 +4962,9 @@ class DecodeEngine:
 
     def _keep_counts(self, out, n: int):
         """The first ``n`` outputs of a model program; what its layers
-        counted beside them waits, on the device, for
-        ``_flush_counts``."""
-        if self._counted:
+        (and, after them, the decode chunk's sampler) counted beside
+        them waits, on the device, for ``_flush_counts``."""
+        if len(out) > n:
             self._counts_pending.append(out[n:])
         return out[:n]
 
@@ -4971,15 +4975,17 @@ class DecodeEngine:
         waits."""
         sums = {"sparse": np.zeros(2, np.int64),
                 "moe": np.zeros(4, np.int64),
-                "ssm": np.zeros(3, np.int64)}
+                "ssm": np.zeros(3, np.int64),
+                "sample": np.zeros(3, np.int64)}
+        # A prefill hands back its layers' counts, a decode chunk the
+        # sampler's after them.
         for counts in self._counts_pending:
-            for what, c in zip(self._counted, counts):
+            for what, c in zip(self._counted + ("sample",), counts):
                 c = np.asarray(c, np.int64)
                 sums[what] += c.reshape(-1, c.shape[-1]).sum(0)
         self._counts_pending.clear()
         reg = self._reg()
-        values = list(sums["sparse"]) + list(sums["moe"]) \
-            + list(sums["ssm"])
+        values = [v for c in sums.values() for v in c]
         for (family, text), v in zip(_COUNT_FAMILIES.items(), values):
             reg.counter(family, text).inc(int(v), model=self.name)
 
@@ -5020,7 +5026,7 @@ class DecodeEngine:
                     self._lora_tree(),
                     np.ascontiguousarray(self._aids))
             (self._cache, self._logbuf, pos, loc, active, produced,
-             rngs, toks, emits) = out
+             rngs, toks, emits) = self._keep_counts(out, 9)
             with self._phase("engine.device_wait"):
                 toks = np.asarray(toks)
                 emits = np.asarray(emits)

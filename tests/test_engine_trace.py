@@ -166,12 +166,12 @@ def _scoped(text, scope):
 
 @pytest.mark.parametrize("scope", [
     "/attn/", "/mlp/", "/lm_head/", "/kv_write/", "/kv_member/",
-    "/scores/", "/pv/", "/vmap(sample)/"])
+    "/scores/", "/pv/", "/sample/cond/"])
 def test_the_decode_program_names_its_parts(traced, scope):
     """``op_name`` metadata of the compiled decode chunk: the module
     scopes flax gives (attn, mlp, lm_head) and the named scopes inside
-    the decode attention and the sampler (the engine vmaps the sampler
-    over its slots, and a scope under ``vmap`` reads ``vmap(<scope>)``).
+    the decode attention and the sampler (whose scope stands outside
+    the ``vmap``: the step picks the sampler's form once for the batch).
     The engine's default pool is its slots' logical view (4 x 64 = 16
     x 16), so the chunk attends the pool in place: ``kv_member``, and
     no gather."""

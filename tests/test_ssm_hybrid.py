@@ -451,7 +451,7 @@ def test_export_round_trips_the_new_keys(tmp_path, tiny):
 
 GLM_PARENT = {
     "decode_chunk":
-        "1907f94d2ef4a279130f270cdc5badd08d29f33fba781a00cccfb9a71fba27dd",
+        "7207114ec17c4c79de7cc6c2bb83791f64bbeaab0f6b8fb30b0ebcec38d58974",
     "prefill_16":
         "f951ede5b3effdb551e864e522a81ceea63a9851061468916aeef7b6e5e68da0",
 }
@@ -461,7 +461,10 @@ GLM_PARENT = {
 def glm_programs():
     """{program: sha256 of its StableHLO} of the tiny ``glm_moe_dsa``
     engine (2 slots, 30 pages of 8, chunked prefill 16): made on the
-    parent commit (81be731) with this function. The dense block's
+    parent commit (81be731) with this function; ``decode_chunk``'s was
+    made again in PR 45, on 15b9f16 with that PR's sampler (one choice
+    of its form a step, and the counts of it), ``prefill_16``'s stands:
+    nothing else moved. The dense block's
     programs, serving and training, are held by
     tests/test_dense_program_guard.py."""
     from kubeflow_tpu.serving import engine as E

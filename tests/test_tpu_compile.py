@@ -362,6 +362,14 @@ def test_slot_state_is_written_in_place_at_the_cells_size(one_chip,
         rf"= {kernels}\S* (copy|copy-done|fusion|dynamic-slice)\(", text)
     # every carried leaf is an argument its result aliases
     assert text.splitlines()[0].count("may-alias") >= 20
+    if program == "decode_chunk":
+        # the sampler's three forms are still a conditional on the chip,
+        # and the vocabulary sort stands in its third branch only (PR 45)
+        assert re.search(r" conditional\(.*branch_computations="
+                         r"\{[^},]+,[^},]+,[^},]+\}", text)
+        sorts = [line for line in text.splitlines() if " sort(" in line]
+        assert sorts and all("/sample/cond/branch_2_fun/" in line
+                             for line in sorts), sorts
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < int(0.8 * 2 ** 30)
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
