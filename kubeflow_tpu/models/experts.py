@@ -9,7 +9,15 @@ experts this program holds ``held_experts`` = (first, count): it
 computes what its own experts give for the tokens routed to them and
 leaves out what the others would add; on one chip the layer runs
 without its exchange, and nothing stands in for the absent chips.
-``n_shared_experts`` run for every token.
+``n_shared_experts`` run for every token (0: none).
+
+A second router form, ``cfg.router == "softmax"``: float32 logits with
+no bias, the ``expert_top_k`` largest chosen by logit, their weights
+the softmax over the chosen (the softmax over all, renormalised over
+the chosen, is the same numbers). With ``cfg.early_router`` the router
+reads what ``Block`` hands it, the residual stream as it entered the
+layer, and the experts the FFN's normalised input as ever.
+``cfg.expert_act`` gates an expert ("silu", or "relu": ReGLU).
 
 Dropless: no capacity, no token's result depends on what else is in
 the batch. The (token, choice) pairs routed here are sorted by expert
@@ -38,16 +46,22 @@ from .transformer import DenseFFN, TransformerConfig
 
 # What a call counts beside its result, int32 [4], in this order
 # (the engine's kfx_lm_moe_* counters): (token, choice) pairs routed,
-# those routed to experts held here, dispatches (one a layer), and
-# the rows of the fullest held expert.
-COUNTS = ("assignments", "assignments_held", "dispatches", "max_rows")
+# those routed to experts held here, dispatches (one a layer), the
+# rows of the fullest held expert, and the held experts that got rows
+# (whose matrices a dispatch reads).
+COUNTS = ("assignments", "assignments_held", "dispatches", "max_rows",
+          "experts_hit")
 
 
-def route(cfg: TransformerConfig, x, gate, bias):
-    """(chosen experts [T, K], their weights [T, K] float32) of tokens
-    x [T, D]."""
+def route(cfg: TransformerConfig, x, gate, bias=None):
+    """(chosen experts [T, K], their weights [T, K] float32) of what
+    the router reads, x [T, D]; ``bias`` is the sigmoid form's."""
     with jax.default_matmul_precision("highest"):
-        scores = jax.nn.sigmoid(x.astype(jnp.float32) @ gate)
+        logits = x.astype(jnp.float32) @ gate
+    if cfg.router == "softmax":
+        best, chosen = jax.lax.top_k(logits, cfg.expert_top_k)
+        return chosen, jax.nn.softmax(best, -1)
+    scores = jax.nn.sigmoid(logits)
     _, chosen = jax.lax.top_k(scores + bias, cfg.expert_top_k)
     weights = jnp.take_along_axis(scores, chosen, -1)
     weights = weights / jnp.sum(weights, -1, keepdims=True)
@@ -58,22 +72,29 @@ class RoutedExperts(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x, valid, wi, wo, layer=0):
+    def __call__(self, x, valid, wi, wo, layer=0, routed_from=None):
         """x [B, S, D]; valid [B, S] marks real tokens (pads are routed
         nowhere and counted nowhere); wi [layers, held, D, 2F] and wo
         [layers, held, F, D] the held experts of every expert layer,
-        ``layer`` this one's index. Returns (y [B, S, D], counts)."""
+        ``layer`` this one's index; ``routed_from`` [B, S, D] what the
+        router reads where that is not x. Returns (y [B, S, D],
+        counts)."""
         cfg = self.cfg
         B, S, D = x.shape
         T, K, F = B * S, cfg.expert_top_k, cfg.expert_d_ff
         first, count = cfg.held_experts
         gate = self.param("gate", nn.initializers.lecun_normal(),
                           (D, cfg.n_routed_experts), jnp.float32)
-        bias = self.param("gate_bias", nn.initializers.zeros,
-                          (cfg.n_routed_experts,), jnp.float32)
+        bias = None
+        if cfg.router == "sigmoid":
+            bias = self.param("gate_bias", nn.initializers.zeros,
+                              (cfg.n_routed_experts,), jnp.float32)
+        act = nn.relu if cfg.expert_act == "relu" else nn.silu
         tokens = x.reshape(T, D)
         with jax.named_scope("route"):
-            chosen, weights = route(cfg, tokens, gate, bias)
+            chosen, weights = route(
+                cfg, tokens if routed_from is None
+                else routed_from.reshape(T, D), gate, bias)
         with jax.named_scope("dispatch"):
             here = ((chosen >= first) & (chosen < first + count)
                     & valid.reshape(T, 1))
@@ -91,7 +112,7 @@ class RoutedExperts(nn.Module):
                 rows, wi.reshape(-1, D, 2 * F).astype(cfg.dtype), groups),
                 2, -1)
             out = jax.lax.ragged_dot(
-                nn.silu(gated) * up,
+                act(gated) * up,
                 wo.reshape(-1, F, D).astype(cfg.dtype), groups)
             # Back to the pairs' order, weighed; a row behind the
             # groups holds nothing this program computed.
@@ -110,5 +131,6 @@ class RoutedExperts(nn.Module):
                                  name="shared")(x)
         counts = jnp.stack([K * jnp.sum(valid, dtype=jnp.int32),
                             jnp.sum(here, dtype=jnp.int32),
-                            jnp.int32(1), jnp.max(sizes)])
+                            jnp.int32(1), jnp.max(sizes),
+                            jnp.sum(sizes > 0, dtype=jnp.int32)])
         return y, counts

@@ -92,12 +92,13 @@ class TransformerConfig:
     flash_min_seq: int = 1024
     flash_max_seq: int = 4096
     # Sequence-chunked cross-entropy: >0 makes the train loop apply
-    # lm_head + softmax per chunk of this many tokens (lax.scan with a
-    # rematted chunk body), so the [B, S, vocab] f32 logits never
-    # materialise whole — at base/b8/S=2048 that transient is ~3G of
-    # the 15.75G HBM, exactly the headroom the save_flash remat policy
-    # needs. Costs one lm_head recompute in the backward (~2% of step
-    # FLOPs at base). 0 = whole-sequence logits (unchanged path).
+    # lm_head + softmax per chunk of this many tokens, in one lax.scan
+    # that makes the gradients beside the loss (a hand-written rule:
+    # parallel/lm_train.py ``_chunked_ce``), so the [B, S, vocab] f32
+    # logits never materialise whole — at base/b8/S=2048 that transient
+    # is ~3G of the 15.75G HBM, exactly the headroom the save_flash
+    # remat policy needs — and each chunk's logits are computed once,
+    # not again in the backward. 0 = whole-sequence logits.
     loss_chunk: int = 0
     # Autoregressive decoding: every attention layer keeps a KV cache
     # ("cache" collection) of max_seq_len slots and calls attend the new
@@ -228,6 +229,28 @@ class TransformerConfig:
     ssm_chunk: int = 256
     ssm_state_dtype: Any = jnp.float32
     state_slots: int = 0
+    # "full" and "window" layers: runs of these two kinds may recur in
+    # ``layer_pattern`` like "mamba" / "attention". A "full" layer's
+    # attention sees every earlier position and has no position term;
+    # a "window" layer's rotates q and k (``rope_base``) and sees the
+    # last ``window`` positions, the query's own among them. Either
+    # takes the FFN the configuration states: the routed experts where
+    # ``n_routed_experts`` > 0, else the SwiGLU of width ``d_ff``.
+    # Paged, the window runs' leaves are a pool of their own,
+    # ``window_pages`` pages indexed by position (the serving engine
+    # sets it, as it sets ``kv_pages``, and frees a row's pages behind
+    # its window).
+    window: int = 0
+    window_pages: int = 0
+    # The router's form: "sigmoid" as above; "softmax": float32 logits
+    # with no bias, the ``expert_top_k`` largest chosen by logit, their
+    # weights the softmax over the chosen. ``early_router``: the router
+    # reads the residual stream as it enters the layer, before ``ln1``
+    # and attention, not the FFN's normalised input. ``expert_act``
+    # gates an expert: "silu" or "relu".
+    router: str = "sigmoid"
+    early_router: bool = False
+    expert_act: str = "silu"
 
     def __post_init__(self):
         # A configuration read back from JSON brings lists.
@@ -237,10 +260,11 @@ class TransformerConfig:
                            tuple(int(n) for n in self.held_experts))
         object.__setattr__(self, "ssm_state_dtype",
                            jnp.dtype(self.ssm_state_dtype))
+        kinds = [k for k, _ in self.layer_pattern]
         if self.layer_pattern:
-            kinds = [k for k, _ in self.layer_pattern]
             once = [k for k in kinds if k in ("dense", "expert")]
-            if (set(kinds) - {"dense", "expert", "mamba", "attention"}
+            if (set(kinds) - {"dense", "expert", "mamba", "attention",
+                              "full", "window"}
                     or len(set(once)) != len(once)
                     or any(a == b for a, b in zip(kinds, kinds[1:]))
                     or any(n < 1 for _, n in self.layer_pattern)
@@ -249,9 +273,9 @@ class TransformerConfig:
                 raise ValueError(
                     f"layer_pattern {self.layer_pattern!r}: runs of "
                     "'dense' / 'expert' (each kind once) or 'mamba' / "
-                    "'attention' (a kind may recur, never twice in a "
-                    f"row), counts >= 1 adding up to n_layers "
-                    f"{self.n_layers}")
+                    "'attention' / 'full' / 'window' (a kind may recur, "
+                    "never twice in a row), counts >= 1 adding up to "
+                    f"n_layers {self.n_layers}")
             if "mamba" in kinds:
                 if min(self.ssm_heads, self.ssm_head_dim, self.ssm_state,
                        self.ssm_groups, self.ssm_chunk) < 1 \
@@ -268,6 +292,36 @@ class TransformerConfig:
                         "weights, and stand beside plain attention and "
                         "dense FFNs only (lora_rank, quant, kv_lora_rank "
                         "unset; no 'expert' run)")
+            if {"full", "window"} & set(kinds):
+                if "window" in kinds and self.window < 1:
+                    raise ValueError(
+                        "a 'window' layer needs window >= 1 (the "
+                        "positions a query sees, its own among them)")
+                if self.lora_rank or self.quant or self.kv_lora_rank \
+                        or self.cp > 1 \
+                        or set(kinds) - {"full", "window"}:
+                    raise ValueError(
+                        "'full' / 'window' layers stand beside each "
+                        "other alone, with plain attention (lora_rank, "
+                        "quant, kv_lora_rank, cp unset)")
+        if self.expert_layers:
+            first, count = self.held_experts
+            if (self.n_routed_experts < 1 or self.expert_d_ff < 1
+                    or count < 1 or first < 0
+                    or first + count > self.n_routed_experts
+                    or not 1 <= self.expert_top_k
+                    <= self.n_routed_experts):
+                raise ValueError(
+                    "a layer with routed experts needs n_routed_experts, "
+                    "expert_d_ff, expert_top_k and held_experts "
+                    "(first, count) inside the routed range; got "
+                    f"{self.n_routed_experts}, {self.expert_d_ff}, "
+                    f"{self.expert_top_k}, {self.held_experts}")
+            if self.router not in ("sigmoid", "softmax") \
+                    or self.expert_act not in ("silu", "relu"):
+                raise ValueError(
+                    f"router {self.router!r} is 'sigmoid' or 'softmax', "
+                    f"expert_act {self.expert_act!r} 'silu' or 'relu'")
         if self.tie_embeddings and (self.quant or self.loss_chunk):
             raise ValueError(
                 "tie_embeddings has no lm_head for int8 weights or the "
@@ -284,19 +338,6 @@ class TransformerConfig:
                 "grouped key/value heads (n_kv_heads < n_heads) are "
                 "served by the plain attention alone: not with latent "
                 "attention, LoRA, int8 weights or ring attention")
-            if "expert" in kinds:
-                first, count = self.held_experts
-                if (self.n_routed_experts < 1 or self.expert_d_ff < 1
-                        or count < 1 or first < 0
-                        or first + count > self.n_routed_experts
-                        or not 1 <= self.expert_top_k
-                        <= self.n_routed_experts):
-                    raise ValueError(
-                        "an 'expert' layer needs n_routed_experts, "
-                        "expert_d_ff, expert_top_k and held_experts "
-                        "(first, count) inside the routed range; got "
-                        f"{self.n_routed_experts}, {self.expert_d_ff}, "
-                        f"{self.expert_top_k}, {self.held_experts}")
         if self.kv_lora_rank > 0:
             if min(self.q_lora_rank, self.qk_nope_head_dim,
                    self.qk_rope_head_dim, self.v_head_dim) < 1 \
@@ -326,8 +367,9 @@ class TransformerConfig:
                 "attn_impl='ring' needs the sequence axis sharded: set "
                 "cp>1 (ring attention rotates K/V over the 'ctx' mesh "
                 "axis; with cp=1 there is no ring)")
-        if self.kv_page_size < 0 or self.kv_pages < 0:
-            raise ValueError("kv_page_size / kv_pages must be >= 0")
+        if min(self.kv_page_size, self.kv_pages, self.window_pages) < 0:
+            raise ValueError(
+                "kv_page_size / kv_pages / window_pages must be >= 0")
         if self.kv_page_size > 0:
             if self.max_seq_len % self.kv_page_size:
                 raise ValueError(
@@ -337,6 +379,10 @@ class TransformerConfig:
             if self.kv_pages < 1:
                 raise ValueError(
                     "kv_pages must be >= 1 when kv_page_size > 0")
+            if "window" in kinds and self.window_pages < 1:
+                raise ValueError(
+                    "'window' layers are cached in a pool of their own: "
+                    "window_pages must be >= 1 when kv_page_size > 0")
         if self.quant not in ("", "int8"):
             raise ValueError(
                 f"unknown quant {self.quant!r} (expected '' or 'int8')")
@@ -377,6 +423,24 @@ class TransformerConfig:
             runs.append((f"{k}_layers" + (str(seen[k]) if seen[k] > 1
                                           else ""), k, n))
         return tuple(runs)
+
+    def runs_experts(self, kind: str) -> bool:
+        """Whether a run of ``kind`` carries the routed experts."""
+        return kind == "expert" or (kind in ("full", "window")
+                                    and self.n_routed_experts > 0)
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers with routed experts, over all runs: the length of the
+        ``expert_wi`` / ``expert_wo`` stacks."""
+        return sum(n for k, n in self.layer_pattern
+                   if self.runs_experts(k))
+
+    @property
+    def has_window_pages(self) -> bool:
+        """Whether the paged cache has a second class of page: the
+        pool of the "window" runs."""
+        return any(k == "window" for k, _ in self.layer_pattern)
 
     @property
     def has_slot_state(self) -> bool:
@@ -662,16 +726,23 @@ def _lora_apply(mdl, cfg, name, y, inp, lora, adapter_ids):
     return y + delta.reshape(y.shape).astype(y.dtype)
 
 
-def attention_path(cfg: TransformerConfig, seq_len: int) -> str:
+def attention_path(cfg: TransformerConfig, seq_len: int,
+                   window: int = 0) -> str:
     """The attention implementation a training or prefill forward over
     ``seq_len`` tokens takes — "ring", "flash" or "dense". The one rule
     ``Attention`` dispatches on; runners log it, so which kernel ran is
-    read off the worker's log."""
+    read off the worker's log. ``window`` > 0 is a "window" layer's."""
     if cfg.cp > 1:
         # Context-parallel: the only seq-sharded kernel.
         return "ring"
     if not Attention(cfg)._use_flash(seq_len):
         return "dense"
+    if window:
+        raise ValueError(
+            f"the flash kernels are causal only: a 'window' layer "
+            f"(window {window}) at {seq_len} tokens needs "
+            "attn_impl='naive' (the band is a term of the dense mask "
+            "and of the decode cache's)")
     if cfg.kv_heads != cfg.n_heads:
         raise ValueError(
             f"the flash kernels take one key/value head a query head: "
@@ -683,6 +754,11 @@ def attention_path(cfg: TransformerConfig, seq_len: int) -> str:
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    # A layer kind's own facts ("full" / "window" runs): whether q and
+    # k rotate (None: ``cfg.rope``), and how far a query sees (0:
+    # every earlier position; n: the last n, its own among them).
+    rotate: Optional[bool] = None
+    window: int = 0
 
     def _use_flash(self, seq_len: int) -> bool:
         cfg = self.cfg
@@ -762,7 +838,7 @@ class Attention(nn.Module):
         q = tagged_heads("attn_q", hproj("query"))
         k = tagged_heads("attn_k", hproj("key", cfg.kv_heads))
         v = tagged_heads("attn_v", hproj("value", cfg.kv_heads))
-        if cfg.rope:
+        if cfg.rope if self.rotate is None else self.rotate:
             # RoPE with absolute positions (pads carry -1; their rows
             # are masked out of every decode-mode attention, so the
             # garbage rotation never contributes).
@@ -776,7 +852,8 @@ class Attention(nn.Module):
         _probe("attn_k", k)
         _probe("attn_v", v)
 
-        path = "decode" if cfg.decode else attention_path(cfg, S)
+        path = "decode" if cfg.decode \
+            else attention_path(cfg, S, self.window)
         if path == "decode":
             out = self._decode_attend(q, k, v, positions, block_tables,
                                       write_locations, layer)
@@ -841,6 +918,9 @@ class Attention(nn.Module):
                         for a in (k, v))
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
             mask = nn.make_causal_mask(jnp.zeros((B, S)), dtype=jnp.bool_)
+            if self.window:
+                at = jnp.arange(S)
+                mask = mask & (at[None, :] > at[:, None] - self.window)
             scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
             probs = jax.nn.softmax(scores.astype(jnp.float32), -1)
             out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(cfg.dtype), v)
@@ -966,7 +1046,10 @@ class Attention(nn.Module):
             heads = lambda x: x.reshape(x.shape[:-1] + (cfg.kv_heads, D))
             entry = lambda x: x.reshape(x.shape[:-2] + (-1,))
         if cfg.kv_page_size > 0:
-            P, N = cfg.kv_page_size, cfg.kv_pages
+            # A "window" layer's leaves are the window runs' own pool,
+            # its tables and locations that pool's (TransformerLM).
+            P = cfg.kv_page_size
+            N = cfg.window_pages if self.window else cfg.kv_pages
             if block_tables is None:
                 raise ValueError(
                     "paged decode (kv_page_size > 0) requires block_tables")
@@ -1019,7 +1102,13 @@ class Attention(nn.Module):
                     write(ck, entry(k.astype(cfg.dtype)))
                     write(cv, entry(v.astype(cfg.dtype)))
                 write(cpos, pos)
-            if attends_pool_in_place(B, L, N, P, score_bytes(cfg, S)):
+            # A window layer's view is bounded: the blocks that hold
+            # the last ``window`` positions of the row's first query,
+            # through those of its last (a row's queries are S
+            # positions in a row; locations are positions here).
+            nblk = block_tables.shape[1]
+            nview, in_place = paged_view(cfg, B, S, self.window, nblk)
+            if in_place:
                 if int8_kv:
                     # Dequant the pool where it lies: int8 entries x
                     # the per-token scale plane, in f32, then the
@@ -1042,6 +1131,15 @@ class Attention(nn.Module):
             # are masked to exactly-0 probability via position -1, so
             # the garbage never contributes) and force position -1.
             with jax.named_scope("kv_gather"):
+                if nview < nblk:
+                    first = jnp.min(jnp.where(
+                        pos >= 0, pos, jnp.iinfo(jnp.int32).max), 1)
+                    first = jnp.clip((first - (self.window - 1)) // P,
+                                     0, nblk - nview)          # [B]
+                    block_tables = jnp.take_along_axis(
+                        block_tables, first[:, None] + jnp.arange(
+                            nview, dtype=first.dtype), axis=1)
+                    L = nview * P
                 pt = jnp.clip(block_tables, 0, N - 1)    # [B, nblk]
 
                 def view(var):
@@ -1082,6 +1180,9 @@ class Attention(nn.Module):
                                     gk.reshape(B, gk.shape[1], -1))
                 mask = ((gp >= 0)[:, None, :]
                         & (gp[:, None, :] <= positions[:, :, None]))
+                if self.window:
+                    mask = mask & (gp[:, None, :]
+                                   > positions[:, :, None] - self.window)
                 scores = jnp.where(mask[:, None], scores,
                                    jnp.finfo(scores.dtype).min)
                 probs = jax.nn.softmax(scores.astype(jnp.float32), -1)
@@ -1094,6 +1195,8 @@ class Attention(nn.Module):
             kp = gp[:, None, None, :]                      # [B,1,1,L]
             qp = positions[:, None, :, None]               # [B,1,S,1]
             mask = (kp >= 0) & (kp <= qp)
+            if self.window:
+                mask = mask & (kp > qp - self.window)
             scores = jnp.where(mask, scores,
                                jnp.finfo(scores.dtype).min)
             probs = jax.nn.softmax(scores.astype(jnp.float32), -1)
@@ -1148,6 +1251,8 @@ class Attention(nn.Module):
             qp = positions[:, None, :, None]                 # [B,1,S,1]
             mask = (jnp.repeat(member, P, axis=1)[:, None, None, :]
                     & (kp >= 0) & (kp <= qp))                # [B,1,S,NP]
+            if self.window:
+                mask = mask & (kp > qp - self.window)
         if pk.shape[1] != q.shape[2]:   # grouped heads (_blocks)
             with jax.named_scope("scores"):
                 scores = jnp.einsum("bqhf,kf->bhqk", self._blocks(q),
@@ -1177,6 +1282,20 @@ def score_bytes(cfg: TransformerConfig, window: int) -> float:
     item = 1 if cfg.kv_quant == "int8" else jnp.dtype(cfg.dtype).itemsize
     return (10.0 * cfg.n_heads * window
             / (2 * cfg.kv_heads * cfg.head_dim * item))
+
+
+def paged_view(cfg: TransformerConfig, batch: int, seq: int, window: int,
+               blocks: int) -> Tuple[int, bool]:
+    """(blocks of a row's view, whether the pool is scored in place
+    instead) of a paged attention call of ``seq`` queries a row over
+    tables of ``blocks`` blocks; ``window`` > 0 is a "window" layer's,
+    whose view holds the window of the row's first query through its
+    last and whose pool is the window class's."""
+    P = cfg.kv_page_size
+    view = min(blocks, (seq + window - 2) // P + 2) if window else blocks
+    return view, attends_pool_in_place(
+        batch, view * P, cfg.window_pages if window else cfg.kv_pages, P,
+        score_bytes(cfg, seq))
 
 
 def attends_pool_in_place(batch: int, max_seq_len: int, kv_pages: int,
@@ -1217,8 +1336,11 @@ def init_cache(cfg: TransformerConfig, batch: int = 0):
     because the layer scan carries the cache, and what a scan carries
     cannot come into being inside it."""
     if not cfg.has_slot_state:
-        return {name: {"attn": _attn_cache(cfg, n, batch)}
-                for name, _, n in cfg.layer_runs}
+        # The "window" runs' leaves are a pool of their own.
+        return {name: {"attn": _attn_cache(
+                    cfg, n, batch,
+                    cfg.window_pages if kind == "window" else cfg.kv_pages)}
+                for name, kind, n in cfg.layer_runs}
     from .ssm import init_state
 
     rows = cfg.state_slots if cfg.kv_page_size > 0 else batch
@@ -1227,7 +1349,7 @@ def init_cache(cfg: TransformerConfig, batch: int = 0):
             "slot state beside a paged pool needs state_slots; the "
             "dense layout init_cache(cfg, batch)")
     return {name: {"ssm": init_state(cfg, n, rows)} if kind == "mamba"
-            else {"attn": _attn_cache(cfg, n, batch)}
+            else {"attn": _attn_cache(cfg, n, batch, cfg.kv_pages)}
             for name, kind, n in cfg.layer_runs}
 
 
@@ -1242,14 +1364,15 @@ def latent_entry_width(cfg: TransformerConfig) -> int:
     return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
 
 
-def _attn_cache(cfg: TransformerConfig, n: int, batch: int):
-    """The attention cache leaves of a run of ``n`` layers."""
+def _attn_cache(cfg: TransformerConfig, n: int, batch: int, pages: int):
+    """The attention cache leaves of a run of ``n`` layers; paged, a
+    pool of ``pages`` pages."""
     H, D = cfg.kv_heads, cfg.head_dim
     if cfg.kv_lora_rank > 0:
         if cfg.kv_page_size < 1:
             raise ValueError("latent attention is cached in pages "
                              "(kv_page_size > 0)")
-        rows = (n, cfg.kv_pages, cfg.kv_page_size)
+        rows = (n, pages, cfg.kv_page_size)
         int8_kv = cfg.kv_quant == "int8"
         attn = {"cached_latent": jnp.zeros(
                     rows + (latent_entry_width(cfg),),
@@ -1262,7 +1385,7 @@ def _attn_cache(cfg: TransformerConfig, n: int, batch: int):
                 rows + (cfg.index_head_dim,), cfg.dtype)
         return attn
     if cfg.kv_page_size > 0:
-        rows = (n, cfg.kv_pages, cfg.kv_page_size)
+        rows = (n, pages, cfg.kv_page_size)
         int8_kv = cfg.kv_quant == "int8"
         kv_dtype = jnp.int8 if int8_kv else cfg.dtype
         attn = {}
@@ -1403,8 +1526,11 @@ class Block(nn.Module):
     """One decoder layer. Scan-shaped: returns (carry, per-layer output)."""
 
     cfg: TransformerConfig
-    # a run of cfg.layer_pattern: "dense", "expert", "mamba", "attention"
+    # a run of cfg.layer_pattern: "dense", "expert", "mamba",
+    # "attention", "full", "window"
     kind: str = ""
+    # where this run's layers begin in the routed experts' stacks
+    first_expert_layer: int = 0
 
     @nn.compact
     def __call__(self, x, positions, block_tables=None,
@@ -1432,7 +1558,30 @@ class Block(nn.Module):
 
         x = sp_shard(x)
         counts = {}
-        if self.kind == "mamba":
+        entered = x   # what an early router reads
+        if self.kind in ("full", "window"):
+            window = cfg.window if self.kind == "window" else 0
+            with jax.named_scope("attn_window" if window else "attn_full"):
+                x = x + scaled(Attention(
+                    cfg, rotate=window > 0, window=window, name="attn")(
+                        norm("ln1")(x), positions, block_tables,
+                        write_locations, layer=layer))
+            if window and cfg.decode:
+                # What this layer's queries hold as context, what of
+                # it they see, and the cached positions the call scored
+                # for a live row: its view's width, whatever the row
+                # holds (the engine's kfx_lm_window_* counters).
+                held = jnp.where(positions >= 0, positions + 1, 0)
+                scored = cfg.max_seq_len
+                if cfg.kv_page_size > 0:
+                    view, in_place = paged_view(cfg, *x.shape[:2], window,
+                                                block_tables.shape[1])
+                    scored = cfg.kv_page_size * (
+                        cfg.window_pages if in_place else view)
+                counts["window"] = jnp.stack(
+                    [jnp.sum(held), jnp.sum(jnp.minimum(held, window)),
+                     scored * jnp.sum(jnp.any(positions >= 0, 1))])
+        elif self.kind == "mamba":
             from .ssm import Mamba2
 
             y, counts["ssm"] = Mamba2(cfg, name="ssm")(
@@ -1453,11 +1602,14 @@ class Block(nn.Module):
                 write_locations, lora.get("attn"), adapter_ids, layer))
         x = sp_shard(x)
         h = norm("ln2")(x)
-        if self.kind == "expert":
+        if cfg.runs_experts(self.kind):
             from .experts import RoutedExperts
 
             y, counts["moe"] = RoutedExperts(cfg, name="moe")(
-                h, positions >= 0, *experts, layer)
+                h, positions >= 0, *experts,
+                layer + self.first_expert_layer
+                if self.first_expert_layer else layer,
+                entered if cfg.early_router else None)
             return x + y, counts
         if cfg.n_experts > 0 and not self.kind:
             x = x + MoEFFN(cfg, name="moe")(h)
@@ -1476,10 +1628,12 @@ class TransformerLM(nn.Module):
     def __call__(self, tokens, train: bool = False, positions=None,
                  return_hidden: bool = False, block_tables=None,
                  write_locations=None, lora=None, adapter_ids=None,
-                 slots=None):
+                 slots=None, window_tables=None):
         cfg = self.cfg
         # ``slots`` [B]: each row's slot in the leaves indexed by slot
-        # (models/ssm.py; None: row i is slot i).
+        # (models/ssm.py; None: row i is slot i). ``window_tables``: a
+        # row's block table in the "window" runs' own pool, indexed by
+        # position (a page holds positions, not locations, there).
         # Multi-tenant LoRA serving args (serving/adapters.py): ``lora``
         # is the per-projection adapter STACK pytree (leaves carry a
         # leading layers axis the scan slices) and ``adapter_ids`` [B]
@@ -1600,8 +1754,11 @@ class TransformerLM(nn.Module):
             # train step has no cache and keeps its program as it was.
             in_axes += (0,)
         counts = {}
+        experts, expert_layers = None, 0
         for name, kind, n in cfg.layer_runs:
             run_args, run_axes = args, in_axes
+            if kind == "window" and cfg.kv_page_size > 0:
+                run_args = (positions, window_tables, positions) + args[3:]
             if cfg.decode:
                 run_args += (jnp.arange(n, dtype=jnp.int32),)
             if kind == "mamba":
@@ -1610,18 +1767,24 @@ class TransformerLM(nn.Module):
                     run_axes += (0,)
                 run_args += (None, slots)
                 run_axes += (nn.broadcast, nn.broadcast)
-            if kind == "expert":
-                # The held experts of every expert layer, in one stack
-                # a matrix, outside the scan (models/experts.py).
+            first_expert_layer = expert_layers
+            if cfg.runs_experts(kind):
+                # The held experts of every expert layer, all runs', in
+                # one stack a matrix, outside the scans (models/
+                # experts.py); a run's layers lie there in the stack's
+                # order, from ``first_expert_layer`` on.
                 held, F = cfg.held_experts[1], cfg.expert_d_ff
                 stack = lambda name, *shape: self.param(
                     name, nn.initializers.lecun_normal(),
-                    (n, held) + shape, cfg.param_dtype)
+                    (cfg.expert_layers, held) + shape, cfg.param_dtype)
+                if experts is None:
+                    experts = (stack("expert_wi", cfg.d_model, 2 * F),
+                               stack("expert_wo", F, cfg.d_model))
+                expert_layers += n
                 if not cfg.decode:
                     run_args += (jnp.arange(n, dtype=jnp.int32),)
                     run_axes += (0,)
-                run_args += ((stack("expert_wi", cfg.d_model, 2 * F),
-                              stack("expert_wo", F, cfg.d_model)),)
+                run_args += (experts,)
                 run_axes += (nn.broadcast,)
             ScanBlock = nn.scan(
                 block,
@@ -1632,8 +1795,9 @@ class TransformerLM(nn.Module):
                 length=n,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )
-            x, ys = ScanBlock(cfg, *((kind,) if kind else ()), name=name)(
-                x, *run_args)
+            x, ys = ScanBlock(cfg, *((kind,) if kind else ()), *(
+                (first_expert_layer,) if first_expert_layer else ()),
+                name=name)(x, *run_args)
             for what, per_layer in (ys or {}).items():
                 counts.setdefault(what, []).append(per_layer)
         # What this call's layers counted, for the engine's counters:
@@ -1644,6 +1808,8 @@ class TransformerLM(nn.Module):
             self.sow("counts", "moe", sum(c.sum(0) for c in counts["moe"]))
         if "sparse" in counts:
             self.sow("counts", "sparse", jnp.concatenate(counts["sparse"]))
+        if "window" in counts:   # Block, a layer
+            self.sow("counts", "window", jnp.concatenate(counts["window"]))
         if "ssm" in counts:   # models/ssm.py COUNTS, summed
             self.sow("counts", "ssm", sum(c.sum(0) for c in counts["ssm"]))
 

@@ -132,6 +132,24 @@ bounded-drift, not byte-exact — the f32 engine remains the parity
 oracle, and ``kfx_lm_kv_bytes_per_token`` / ``kfx_lm_quant_mode``
 gauges make the mode scrape-visible.
 
+A second page class (PR 46): a configuration with "window" layers
+(models/transformer.py: attention over the last ``window`` positions)
+keeps those layers' cache leaves in a pool of their own, its pages
+indexed by POSITION, with a second ``BlockManager`` and a second block
+table a row (``_wmgr``, ``_wtables``), sized for every slot's worst
+case: the blocks of a window, one more where it straddles a page, and
+those of the tokens a dispatch writes. After every prompt dispatch and
+decode chunk a row gives back the pages whose positions all lie behind
+its next query's window (``_free_behind_window``; recycled pages'
+position ids are invalidated before reuse by that class's own reset
+program), so a row of any length holds a window's worth there, and a
+window layer's gathered view is the window plus the dispatch's tokens,
+whatever ``max_seq_len``. Admission, chunked prefill, the chunk-boundary
+page budget, preemption by recompute and release account for both
+classes; the prefix cache, speculation and KV offload / migration /
+transfer are refused by name. Nothing selects it: the classes follow
+from the configuration.
+
 Self-healing (serving-fleet robustness): the loop keeps a progress
 **heartbeat** (monotonic iteration counter + last-completed-iteration
 timestamp, ``heartbeat()``) so the model server's /healthz is a real
@@ -179,6 +197,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import itertools
 import threading
 import time
@@ -721,6 +740,9 @@ _COUNT_FAMILIES = {
         "call).",
     "kfx_lm_moe_max_rows_total":
         "Rows of the fullest held expert, summed over dispatches.",
+    "kfx_lm_moe_experts_hit_total":
+        "Held experts that received rows (whose matrices the grouped "
+        "product reads), summed over dispatches.",
     "kfx_lm_ssm_row_updates_total":
         "Rows whose recurrent state a decode step advanced, summed over "
         "steps and state-space layers.",
@@ -730,6 +752,17 @@ _COUNT_FAMILIES = {
     "kfx_lm_state_resets_total":
         "Rows that started from an empty state (a sequence's first "
         "token), summed over state-space layers.",
+    "kfx_lm_window_cached_positions_total":
+        "Positions query tokens hold as context, summed over tokens and "
+        "window layers.",
+    "kfx_lm_window_attended_positions_total":
+        "Positions of that context inside the window (what a window "
+        "layer's query reads), summed over tokens and window layers.",
+    "kfx_lm_window_gathered_positions_total":
+        "Positions of the window class's pool a window layer's attention "
+        "scored for a row (the width of the row's gathered view, or the "
+        "whole pool where it attends in place), summed over the rows of "
+        "a call and window layers.",
     "kfx_lm_sample_steps_total":
         "Decode steps run (chunks times the chunk's tokens).",
     "kfx_lm_sample_draw_steps_total":
@@ -739,6 +772,20 @@ _COUNT_FAMILIES = {
     "kfx_lm_sample_sort_steps_total":
         "Decode steps in which an active drawing row set a top_k, so "
         "the sampler sorted the vocabulary.",
+}
+
+
+# Of those, the ones with a twin that grows by the decode chunks' counts
+# alone (a prompt dispatch hits every expert and holds one row): what a
+# decode STEP read, over ``kfx_lm_sample_steps_total`` steps.
+_DECODE_TWINS = {
+    "kfx_lm_moe_experts_hit_total": "kfx_lm_decode_experts_hit_total",
+    "kfx_lm_window_cached_positions_total":
+        "kfx_lm_decode_window_cached_positions_total",
+    "kfx_lm_window_attended_positions_total":
+        "kfx_lm_decode_window_attended_positions_total",
+    "kfx_lm_window_gathered_positions_total":
+        "kfx_lm_decode_window_gathered_positions_total",
 }
 
 
@@ -831,15 +878,52 @@ class DecodeEngine:
         # models/transformer.py quantize-on-write / dequant-on-gather.
         # Independent of weight quant; both the target and draft pools
         # follow it (the draft cfg derives from self.cfg below).
+        # The second page class: the "window" runs' pool, its pages
+        # indexed by position. A row holds the blocks of its window,
+        # one more where the window straddles a page, and those of the
+        # tokens a dispatch writes before the pages behind the window
+        # are given back (a prompt chunk, or a whole bucket where the
+        # prefill is not chunked): every slot's worst case fits, so
+        # this class never turns a row away that the first admits.
+        self.window_pages = 0
+        if base.has_window_pages:
+            wrote = -(-int(prefill_chunk_tokens) // ps) * ps \
+                if prefill_chunk_tokens > 0 else max(8, L // 2)
+            self.window_pages = n_slots * min(
+                self.n_blocks, -(-base.window // ps) + 2
+                + -(-max(wrote, chunk_tokens) // ps))
         self.cfg = dataclasses.replace(
             base, kv_page_size=ps, kv_pages=self.n_pages,
+            window_pages=self.window_pages,
             kv_quant=kv_quant or base.kv_quant,
             state_slots=n_slots if base.has_slot_state else 0)
         self.name = name
         if prefix_cache is None:
             # On, where the configuration can take it; asked for by
             # name where it cannot, it is refused below.
-            prefix_cache = not base.has_slot_state
+            prefix_cache = not (base.has_slot_state
+                                or base.has_window_pages)
+        if base.has_window_pages:
+            # What reads or moves a row's pages as ONE table over ONE
+            # pool is refused by name; none runs wrong. (Preemption by
+            # recompute works: both classes' pages go back, and the
+            # row is prefilled again from its first token.)
+            for asked, feature, why in (
+                    (prefix_cache, "the prefix cache",
+                     "a matched page of the first class says nothing "
+                     "of the window layers' pages, which a row gives "
+                     "back as it moves on (KFX_LM_PREFIX_CACHE=0)"),
+                    (draft_layers > 0, "speculative decoding",
+                     "a rejected proposal is rolled back in one pool, "
+                     "by location"),
+                    (role != "mixed" or kv_peer_send is not None
+                     or kv_offload_pages > 0,
+                     "KV offload, migration and transfer",
+                     "they move the pages of one block table")):
+                if asked:
+                    raise ValueError(
+                        f"{feature} cannot take a configuration with a "
+                        f"second page class ('window' layers): {why}")
         if base.has_slot_state:
             # A row's state lies in leaves indexed by slot, beside its
             # pages: what takes a row's state to BE its pages is
@@ -962,6 +1046,9 @@ class DecodeEngine:
 
         # -- pool bookkeeping (touched only by the loop thread)
         self._mgr = BlockManager(self.n_pages, ps)
+        self._wmgr: Optional[BlockManager] = \
+            BlockManager(self.window_pages, ps) if self.window_pages \
+            else None
         self._prefix: Optional[PrefixCache] = \
             PrefixCache(self._mgr) if prefix_cache else None
         self._prompt_tokens = 0  # prompt tokens admitted (for skip frac)
@@ -1135,6 +1222,10 @@ class DecodeEngine:
         B = n_slots
         self._tables = np.full((B, self.n_blocks), -1, np.int32)
         self._slot_pages: List[List[int]] = [[] for _ in range(B)]
+        # The window class: a row's table over the window pool, by
+        # position, and the first block it may still hold.
+        self._wtables = np.full((B, self.n_blocks), -1, np.int32)
+        self._wfirst = np.zeros((B,), np.int32)
         self._pos = np.zeros((B,), np.int32)       # next decode position
         self._loc = np.zeros((B,), np.int32)       # next decode write loc
         self._max_loc = np.zeros((B,), np.int32)   # last writable loc
@@ -1197,6 +1288,7 @@ class DecodeEngine:
         self._spec_exec: Any = None
         self._reset_exec: Any = None
         self._draft_reset_exec: Any = None
+        self._window_reset_exec: Any = None
         self._copy_exec: Any = None
         self._gather_exec: Any = None
         self._scatter_exec: Any = None
@@ -1276,6 +1368,10 @@ class DecodeEngine:
         concurrent-admission multiplier at a fixed pool byte budget
         (docs/serving.md HBM accounting)."""
         c = self.cfg
+        if c.has_window_pages:
+            # Both classes: what a token costs for as long as its row
+            # lives, and what it costs while it lies inside the window.
+            return sum(self.kv_bytes_per_token_by_class.values())
         if c.kv_lora_rank > 0 or c.has_slot_state:
             # Latent attention: what the leaves hold a token (latent +
             # rotary, the indexer's key, int8 scales), all layers. Slot
@@ -1298,6 +1394,31 @@ class DecodeEngine:
         return [(getattr(path[-1], "key", ""), x) for path, x in
                 jax.tree_util.tree_flatten_with_path(self._cache_specs())[0]
                 if any(getattr(k, "key", "") == module for k in path)]
+
+    @property
+    def _window_runs(self) -> frozenset:
+        """The runs whose cache leaves are the window class's pool."""
+        return frozenset(name for name, kind, _ in self.cfg.layer_runs
+                         if kind == "window")
+
+    def _class_leaves(self, window: bool):
+        """(leaf name, spec) of the paged leaves of one page class."""
+        import jax
+
+        runs = self._window_runs
+        return [(getattr(path[-1], "key", ""), x) for path, x in
+                jax.tree_util.tree_flatten_with_path(self._cache_specs())[0]
+                if (getattr(path[0], "key", "") in runs) == window]
+
+    @functools.cached_property
+    def kv_bytes_per_token_by_class(self) -> Dict[str, int]:
+        """``kv_bytes_per_token`` of each page class, {"full",
+        "window"}: the entries and scales its layers hold a token (the
+        position-id words left out, as for the latent cache)."""
+        return {cls: sum(
+            int(np.prod(x.shape[3:])) * x.shape[0] * x.dtype.itemsize
+            for name, x in self._class_leaves(cls == "window")
+            if name != "cached_pos") for cls in ("full", "window")}
 
     def slot_state(self, slot: int) -> Dict[str, np.ndarray]:
         """Host copies of what ``slot`` holds in the leaves indexed by
@@ -1324,6 +1445,53 @@ class DecodeEngine:
                         out.setdefault(name, []).append(
                             np.asarray(leaf[:, slot]))
             return {k: np.concatenate(v) for k, v in out.items()}
+
+        return self._run_on_loop(read)
+
+    def row_kv(self, slot: int, layer: int) -> Dict[str, np.ndarray]:
+        """Host copies of the keys and values the live row in ``slot``
+        holds in ``layer``'s pages, as its attention reads them (int8
+        entries times their scales): ``positions`` [n] ascending,
+        ``key`` and ``value`` [n, heads x head_dim]. A window layer's
+        row holds what it has not given back. Read on the loop thread
+        at an iteration boundary, like ``slot_state``; today the
+        benchmark's comparison with its reference reads it."""
+        if not 0 <= slot < self.n_slots:
+            raise ValueError(f"slot {slot} of {self.n_slots}")
+        first = 0
+        for run, kind, n in self.cfg.layer_runs:
+            if first <= layer < first + n:
+                break
+            first += n
+        else:
+            raise ValueError(f"layer {layer} of {self.cfg.n_layers}")
+        if self.page_size <= 0 or kind == "mamba" \
+                or self.cfg.kv_lora_rank > 0:
+            raise ValueError(
+                f"layer {layer} of engine {self.name} holds no paged "
+                "keys and values")
+
+        def read():
+            if self._slots[slot] is None:
+                raise ValueError(f"no request is live in slot {slot}")
+            table = (self._wtables if kind == "window"
+                     else self._tables)[slot]
+            pages = table[table >= 0]
+            leaves = self._cache[run]["attn"]
+            take = lambda name: np.asarray(
+                leaves[name][layer - first, pages]).astype(np.float32)
+            pos = np.asarray(leaves["cached_pos"][layer - first, pages]
+                             ).reshape(-1)
+            order = np.argsort(pos, kind="stable")[np.sum(pos < 0):]
+            out = {"positions": pos[order]}
+            for name, scale in (("key", "key_scale"),
+                                ("value", "value_scale")):
+                x = take("cached_" + name)
+                x = x.reshape(-1, x.shape[-1])
+                if scale in leaves:
+                    x = x * take(scale).reshape(-1, 1)
+                out[name] = x[order]
+            return out
 
         return self._run_on_loop(read)
 
@@ -1430,8 +1598,12 @@ class DecodeEngine:
                 int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
                 for x in jax.tree_util.tree_leaves(tree)))
 
+        window = {k: v for k, v in self._cache.items()
+                  if k in self._window_runs}
         out = {
             "params": nbytes(self.params),
+            # (both page classes; the window runs' share is reported
+            # beside the total below, not added to it)
             "kv_pool": nbytes(self._cache),
             "logits": nbytes(self._logbuf),
             "draft": (nbytes(self.draft_params)
@@ -1445,6 +1617,8 @@ class DecodeEngine:
             if self._wpool is not None else 0,
         }
         out["total"] = sum(out.values())
+        if window:
+            out["kv_pool_window"] = nbytes(window)
         return out
 
     def _spec_accept_rate(self, window_s: float = 30.0) -> float:
@@ -1471,7 +1645,11 @@ class DecodeEngine:
         created headroom."""
         held = len({pg for i, r in enumerate(self._slots)
                     if r is not None for pg in self._slot_pages[i]})
-        return self.n_slots * held / float(self.n_pages)
+        share = held / float(self.n_pages)
+        if self._wmgr is not None:   # the fuller class decides
+            share = max(share, 1.0 - self._wmgr.n_free
+                        / float(self.window_pages))
+        return self.n_slots * share
 
     def _touch_gauges(self) -> None:
         reg = self._reg()
@@ -1501,12 +1679,31 @@ class DecodeEngine:
         # `scrape_metrics --require` already sees the families.
         for family, help_text in self._SHED_HELP.items():
             reg.counter(family, help_text).inc(0, model=self.name)
-        reg.gauge("kfx_lm_kv_pages",
-                  "KV cache pages in the engine's pool.").set(
-                      self.n_pages, model=self.name)
-        reg.gauge("kfx_lm_kv_pages_free",
-                  "KV cache pages on the free list.").set(
-                      self._mgr.n_free, model=self.name)
+        # One pool: the families as ever. Two page classes: each
+        # family a series a class, and the pools' bytes beside them.
+        by_class = {"": (self.n_pages, self._mgr.n_free)}
+        if self._wmgr is not None:
+            by_class = {"full": by_class[""],
+                        "window": (self.window_pages, self._wmgr.n_free)}
+        for cls, (pages, free) in by_class.items():
+            labels = dict(model=self.name, **({"class": cls} if cls else {}))
+            reg.gauge("kfx_lm_kv_pages",
+                      "KV cache pages in the engine's pool.").set(
+                          pages, **labels)
+            reg.gauge("kfx_lm_kv_pages_free",
+                      "KV cache pages on the free list.").set(
+                          free, **labels)
+        if self._wmgr is not None:
+            for cls, per_token in self.kv_bytes_per_token_by_class.items():
+                reg.gauge("kfx_lm_kv_pool_bytes",
+                          "Bytes of a page class's pool (entries and "
+                          "scales).").set(
+                              by_class[cls][0] * self.page_size * per_token,
+                              model=self.name, **{"class": cls})
+            reg.counter("kfx_lm_window_pages_freed_total",
+                        "Window-class pages given back because every "
+                        "position in them lay behind the row's window."
+                        ).inc(0, model=self.name)
         # KV transfer-plane families (serving/kvtransfer.py), seeded
         # so a pre-migration scrape already sees them: migrations by
         # reason, pages shipped/adopted, the host offload tier's
@@ -1530,10 +1727,15 @@ class DecodeEngine:
                           0.0, n=0, model=self.name)
         # Engine truth, not a derived number: capacity planning
         # reads pool bytes = kv_pages x page_size x this gauge.
-        reg.gauge("kfx_lm_kv_bytes_per_token",
-                  "KV-cache bytes per cached token (entries + "
-                  "quantization scales + position id).").set(
-                      self.kv_bytes_per_token, model=self.name)
+        for cls, per_token in (
+                self.kv_bytes_per_token_by_class.items()
+                if self._wmgr is not None
+                else (("", self.kv_bytes_per_token),)):
+            reg.gauge("kfx_lm_kv_bytes_per_token",
+                      "KV-cache bytes per cached token (entries + "
+                      "quantization scales + position id).").set(
+                          per_token, model=self.name,
+                          **({"class": cls} if cls else {}))
         # What a slot holds beside pages (0 where rows are their pages).
         reg.gauge("kfx_lm_state_bytes_per_slot",
                   "Recurrent-state bytes a slot holds whatever its "
@@ -1575,6 +1777,9 @@ class DecodeEngine:
         # configuration; they grow in _flush_counts.
         for family, text in _COUNT_FAMILIES.items():
             reg.counter(family, text).inc(0, model=self.name)
+            if family in _DECODE_TWINS:
+                reg.counter(_DECODE_TWINS[family], text + " Decode chunks "
+                            "alone.").inc(0, model=self.name)
         # Chunked-prefill families, pre-seeded (counter at 0; the
         # histogram family registered with a zero-count observe) so a
         # pre-traffic `scrape_metrics --require` already sees them.
@@ -1771,6 +1976,34 @@ class DecodeEngine:
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
             self._lora_tree(draft))
 
+    # A model program's block tables: one array [rows, blocks], or,
+    # with a second page class, the pair (full, window), each class's
+    # table over its own pool. The three below are the spec, the
+    # model's keywords (inside a program) and the host's argument.
+    def _table_specs(self, rows: int):
+        import jax
+
+        one = jax.ShapeDtypeStruct((rows, self.n_blocks), np.int32)
+        return (one, one) if self.cfg.has_window_pages else one
+
+    def _tables_kw(self, tables):
+        if not self.cfg.has_window_pages:
+            return {"block_tables": tables}
+        return {"block_tables": tables[0], "window_tables": tables[1]}
+
+    def _tables_arg(self, slot: Optional[int] = None):
+        pick = (lambda t: np.ascontiguousarray(t)) if slot is None \
+            else (lambda t: np.ascontiguousarray(t[slot])[None, :])
+        if self._wmgr is None:
+            return pick(self._tables)
+        # Both tables as copies: a row's change as soon as the program
+        # is enqueued (_free_behind_window; the next prompt chunk's
+        # pages), and a backend may read the host's array in place,
+        # after the call has returned (the CPU's does: a lone row's
+        # prompt chunks, enqueued one behind the other, then read the
+        # tables of a later chunk).
+        return np.array(pick(self._tables)), np.array(pick(self._wtables))
+
     @staticmethod
     def _named(fn, what: str):
         """``fn`` under kfx's own name, which ``jax.jit`` gives the
@@ -1863,6 +2096,7 @@ class DecodeEngine:
         # The leaves indexed by slot are read and written at the
         # prompt's slot; the decode chunk's row i is slot i.
         slot_state = self.cfg.has_slot_state
+        tables_kw = self._tables_kw
 
         def run(params, cache, logbuf, tokens, table, slot, true_len,
                 start, lora, aid):
@@ -1882,8 +2116,8 @@ class DecodeEngine:
             pos = jnp.where(pos < true_len, start + pos, -1)
             logits, vars_ = model.apply(
                 {"params": params, "cache": cache}, tokens,
-                positions=pos, block_tables=table, lora=lora,
-                adapter_ids=aid, mutable=mutable, **(
+                positions=pos, lora=lora,
+                adapter_ids=aid, mutable=mutable, **tables_kw(table), **(
                     {"slots": slot[None]} if slot_state else {}))
             last = jax.lax.dynamic_slice_in_dim(
                 logits, true_len - 1, 1, axis=1)[0, 0]  # [V]
@@ -1901,7 +2135,7 @@ class DecodeEngine:
             jax.ShapeDtypeStruct((self.n_slots, self.cfg.vocab_size),
                                  np.float32),
             jax.ShapeDtypeStruct((1, P), np.int32),
-            jax.ShapeDtypeStruct((1, self.n_blocks), np.int32),
+            self._table_specs(1),
             jax.ShapeDtypeStruct((), np.int32),
             jax.ShapeDtypeStruct((), np.int32),
             jax.ShapeDtypeStruct((), np.int32),
@@ -1933,6 +2167,7 @@ class DecodeEngine:
         model, k = self.model, self.chunk_tokens
         counted = self._counted
         mutable = ["cache"] + (["counts"] if counted else [])
+        tables_kw = self._tables_kw
 
         def run(params, cache, logbuf, tables, pos, loc, active,
                 produced, rngs, temp, topk, stop, max_new, lora, aids):
@@ -1964,7 +2199,7 @@ class DecodeEngine:
                 eff_loc = jnp.where(active, loc, -1).astype(jnp.int32)
                 logits2, vars_ = model.apply(
                     {"params": params, "cache": cache}, feed[:, None],
-                    positions=eff_pos[:, None], block_tables=tables,
+                    positions=eff_pos[:, None], **tables_kw(tables),
                     write_locations=eff_loc[:, None], lora=lora,
                     adapter_ids=aids, mutable=mutable)
                 # The logits CARRY is active-gated like the cache
@@ -1999,7 +2234,7 @@ class DecodeEngine:
                                    self.params),
             self._cache_specs(),
             sds((B, V), np.float32),
-            sds((B, self.n_blocks), np.int32),  # block tables
+            self._table_specs(B),     # block tables
             sds((B,), np.int32),      # pos
             sds((B,), np.int32),      # loc
             sds((B,), np.bool_),      # active
@@ -2017,13 +2252,17 @@ class DecodeEngine:
             self._named(run, "decode_chunk"),
             donate_argnums=donate).lower(*specs).compile())
 
-    def _reset_fn(self, draft: bool = False):
+    def _reset_fn(self, draft: bool = False, window: bool = False):
         """Compiled page invalidation: sets cached position ids to -1
         for every page selected by a [n_pages] mask (ONE compile per
         pool; the mask is data). Recycled pages pass through here
         before reuse, so a new tenant can never attend a previous
-        request's KV — in either pool."""
-        attr = "_draft_reset_exec" if draft else "_reset_exec"
+        request's KV — in either pool. ``window``: the window class's
+        pool (its runs' leaves, its page count); the other call leaves
+        those alone."""
+        attr = "_draft_reset_exec" if draft else \
+            "_window_reset_exec" if window else "_reset_exec"
+        window_runs = self._window_runs
         with self._exec_lock:
             fn = getattr(self, attr)
         if fn is not None:
@@ -2036,17 +2275,20 @@ class DecodeEngine:
             leaves = []
             for path, leaf in flat:
                 name = getattr(path[-1], "key", str(path[-1]))
-                if name == "cached_pos":  # [layers, N, P]
+                if name == "cached_pos" and window == (
+                        getattr(path[0], "key", "") in window_runs):
                     leaf = jnp.where(mask[None, :, None], -1, leaf)
-                leaves.append(leaf)
+                leaves.append(leaf)   # ^ [layers, N, P]
             return jax.tree_util.tree_unflatten(treedef, leaves)
 
-        n = self.draft_n_pages if draft else self.n_pages
+        n = self.draft_n_pages if draft else \
+            self.window_pages if window else self.n_pages
         donate = (0,) if self._donate else ()
         specs = (self._cache_specs(draft),
                  jax.ShapeDtypeStruct((n,), np.bool_))
         fn = self._build(
-            jax.jit(self._named(run, "kv_reset"),
+            jax.jit(self._named(run, "kv_reset_window" if window
+                                else "kv_reset"),
                     donate_argnums=donate).lower(*specs).compile)
         with self._exec_lock:
             if getattr(self, attr) is None:
@@ -2497,6 +2739,8 @@ class DecodeEngine:
         # both would otherwise pay their one-time compile inside a
         # serving request.
         self._reset_fn()
+        if self._wmgr is not None:
+            self._reset_fn(window=True)
         if self._prefix is not None:
             self._copy_fn()
         from ..models.generate import pow2_bucket
@@ -2858,14 +3102,64 @@ class DecodeEngine:
             self._draft_mgr.dirty.clear()
         return pages
 
+    def _place_window_blocks(self, slot: int, lo: int, hi: int) -> None:
+        """Give ``slot`` a window-class page for every block of
+        positions ``lo..hi`` it does not hold yet, recycled pages'
+        position ids invalidated first. All or nothing: a shortfall
+        raises PageAllocError and places none."""
+        if self._wmgr is None or hi < lo:
+            return
+        ps = self.page_size
+        row = self._wtables[slot]
+        want = [b for b in range(lo // ps, hi // ps + 1) if row[b] < 0]
+        if not want:
+            return
+        row[want] = self._wmgr.alloc(len(want))
+        if self._wmgr.dirty:
+            mask = np.zeros((self.window_pages,), np.bool_)
+            mask[list(self._wmgr.dirty)] = True
+            self._cache = self._reset_fn(window=True)(self._cache, mask)
+            self._wmgr.dirty.clear()
+
+    def _free_behind_window(self, slot: int, next_pos: int) -> None:
+        """Give back the window-class pages of ``slot`` whose positions
+        all lie behind the window of its next query, the one at
+        ``next_pos``: that query sees ``next_pos - window + 1`` on."""
+        if self._wmgr is None:
+            return
+        keep = max(0, next_pos - self.cfg.window + 1) // self.page_size
+        first = int(self._wfirst[slot])
+        if keep <= first:
+            return
+        row = self._wtables[slot, first:keep]
+        gone = row[row >= 0]
+        row[:] = -1
+        self._wfirst[slot] = keep
+        if gone.size:
+            self._wmgr.decref(gone.tolist())
+            self._reg().counter(
+                "kfx_lm_window_pages_freed_total",
+                "Window-class pages given back because every position "
+                "in them lay behind the row's window.").inc(
+                    int(gone.size), model=self.name)
+
+    def _release_window(self, slot: int) -> None:
+        if self._wmgr is not None:
+            row = self._wtables[slot]
+            self._wmgr.decref(row[row >= 0].tolist())
+            row[:] = -1
+            self._wfirst[slot] = 0
+
     def _release_slot(self, slot: int) -> None:
         """Return a slot's page references to the pool (pages still
         pinned by the prefix cache or other slots survive; the rest go
         back to the free list and will be invalidated before reuse).
-        Draft pages are slot-private, so they always free whole."""
+        Draft pages are slot-private, so they always free whole, as
+        are the window class's."""
         self._mgr.decref(self._slot_pages[slot])
         self._slot_pages[slot] = []
         self._tables[slot, :] = -1
+        self._release_window(slot)
         self._active[slot] = False
         self._release_draft(slot)
         self._pending[slot] = -1
@@ -3167,6 +3461,10 @@ class DecodeEngine:
                 f"engine {self.name} holds slot state ('mamba' layers): "
                 "migration moves pages, and a slot's state would have "
                 "to move with them")
+        if self._wmgr is not None:
+            raise ValueError(
+                f"engine {self.name} has a second page class ('window' "
+                "layers): migration moves the pages of one block table")
         send = send if send is not None else self._peer_send
         if send is None:
             raise ValueError(
@@ -3273,6 +3571,10 @@ class DecodeEngine:
             raise kvtransfer.TransferError(
                 f"engine {self.name} holds slot state ('mamba' layers): "
                 "an import brings pages, and no state for the slot")
+        if self._wmgr is not None:
+            raise kvtransfer.TransferError(
+                f"engine {self.name} has a second page class ('window' "
+                "layers): an import brings the pages of one block table")
         inj = chaos.draw("kv.transfer", target=self.name)
         if inj is not None:
             if inj.delay > 0:
@@ -4051,6 +4353,13 @@ class DecodeEngine:
         except PageAllocError:
             self._mgr.decref(pinned)  # back to their cache/slot refs
             raise
+        try:
+            # A row is admitted only if both classes can serve it: the
+            # window class holds the whole tail while it is written.
+            self._place_window_blocks(slot, matched, n - 1)
+        except PageAllocError:
+            self._mgr.decref(pinned + pages)
+            raise
         row = np.full((self.n_blocks,), -1, np.int32)
         for j, pg in enumerate(shared):
             row[j] = pg
@@ -4079,7 +4388,9 @@ class DecodeEngine:
                 self._cache, self._logbuf = self._keep_counts(fn(
                     self.params if wid < 0 else self._wpool.tree(wid),
                     self._cache, self._logbuf, tokens,
-                    row[None, :], np.int32(slot), np.int32(len(tail)),
+                    row[None, :] if self._wmgr is None else (
+                        row[None, :], np.array(self._wtables[slot])[None]),
+                    np.int32(slot), np.int32(len(tail)),
                     np.int32(matched), self._lora_tree(),
                     np.full((1,), aid, np.int32)), 2)
             except Exception as e:
@@ -4092,6 +4403,7 @@ class DecodeEngine:
                     self._fail_inflight(e)
                 else:
                     self._mgr.decref(pinned + pages)
+                    self._release_window(slot)
                 raise
         # A monolithic prefill is decode stall for every active slot —
         # the head-of-line blocking the chunked path exists to bound.
@@ -4103,6 +4415,7 @@ class DecodeEngine:
             self._mgr.decref([cow[0]])
         self._tables[slot] = row
         self._slot_pages[slot] = shared + pages
+        self._free_behind_window(slot, n)
         # Register this prompt's pages for future admissions: every
         # full prompt page not already cached, chained after the
         # matched prefix, plus the partially-filled boundary page.
@@ -4388,6 +4701,7 @@ class DecodeEngine:
                         pg = self._alloc_pages(1)[0]
                         self._tables[slot, b] = pg
                         self._slot_pages[slot].append(pg)
+                self._place_window_blocks(slot, start, start + length - 1)
                 break
             except PageAllocError as e:
                 victims = [s for s, r in enumerate(self._slots)
@@ -4414,9 +4728,7 @@ class DecodeEngine:
             try:
                 self._cache, self._logbuf = self._keep_counts(fn(
                     self._params_for(slot), self._cache, self._logbuf,
-                    tokens,
-                    np.ascontiguousarray(
-                        self._tables[slot])[None, :],
+                    tokens, self._tables_arg(slot),
                     np.int32(slot), np.int32(length), np.int32(start),
                     self._lora_tree(),
                     np.full((1,), int(self._aids[slot]), np.int32)), 2)
@@ -4436,6 +4748,7 @@ class DecodeEngine:
             self.flight.event(req, "prefill_chunk", start=start,
                               tokens=length)
         cur["next"] = start + length
+        self._free_behind_window(slot, start + length)
         self._register_prefix_pages(slot, cur, final=last)
         if last:
             self._finish_prefill(slot)
@@ -4590,6 +4903,9 @@ class DecodeEngine:
                             pg = self._alloc_pages(1)[0]
                             self._tables[slot, b] = pg
                             self._slot_pages[slot].append(pg)
+                    # The window class's pages lie by position.
+                    at = int(self._pos[slot])
+                    self._place_window_blocks(slot, at, at + hi - lo)
                 return
             except PageAllocError:
                 # Victims include mid-prefill slots: their pages are
@@ -4884,7 +5200,7 @@ class DecodeEngine:
                 with self._phase("engine.decode.enqueue"):
                     out = self._decode()(
                         self.params, self._cache, self._logbuf,
-                        np.ascontiguousarray(self._tables), self._pos,
+                        self._tables_arg(), self._pos,
                         self._loc, self._active, self._produced,
                         self._rngs, self._temp, self._topk, self._stop,
                         self._max_new, self._lora_tree(),
@@ -4904,6 +5220,10 @@ class DecodeEngine:
                     self._rngs = np.array(rngs)
                     toks = np.asarray(toks)    # [k, B]
                     emits = np.asarray(emits)  # [k, B] bool
+                if self._wmgr is not None:
+                    for slot in np.flatnonzero(self._active):
+                        self._free_behind_window(
+                            int(slot), int(self._pos[slot]))
             else:
                 toks, emits = self._decode_grouped()
         reg = self._reg()
@@ -4956,8 +5276,9 @@ class DecodeEngine:
         c = self.cfg
         return tuple(what for what, on in (
             ("sparse", c.kv_lora_rank > 0 and c.index_topk > 0),
-            ("moe", any(k == "expert" for _, k, _ in c.layer_runs)),
-            ("ssm", c.has_slot_state))
+            ("moe", c.expert_layers > 0),
+            ("ssm", c.has_slot_state),
+            ("window", c.has_window_pages))
             if on)
 
     def _keep_counts(self, out, n: int):
@@ -4974,20 +5295,30 @@ class DecodeEngine:
         so every program enqueued before it has run and no read here
         waits."""
         sums = {"sparse": np.zeros(2, np.int64),
-                "moe": np.zeros(4, np.int64),
+                "moe": np.zeros(5, np.int64),
                 "ssm": np.zeros(3, np.int64),
+                "window": np.zeros(3, np.int64),
                 "sample": np.zeros(3, np.int64)}
+        decode = {what: np.zeros_like(c) for what, c in sums.items()}
         # A prefill hands back its layers' counts, a decode chunk the
         # sampler's after them.
         for counts in self._counts_pending:
+            a_chunk = len(counts) > len(self._counted)
             for what, c in zip(self._counted + ("sample",), counts):
                 c = np.asarray(c, np.int64)
-                sums[what] += c.reshape(-1, c.shape[-1]).sum(0)
+                c = c.reshape(-1, c.shape[-1]).sum(0)
+                sums[what] += c
+                if a_chunk:
+                    decode[what] += c
         self._counts_pending.clear()
         reg = self._reg()
-        values = [v for c in sums.values() for v in c]
-        for (family, text), v in zip(_COUNT_FAMILIES.items(), values):
+        flat = lambda d: [v for c in d.values() for v in c]
+        for (family, text), v, of_chunks in zip(
+                _COUNT_FAMILIES.items(), flat(sums), flat(decode)):
             reg.counter(family, text).inc(int(v), model=self.name)
+            if family in _DECODE_TWINS:
+                reg.counter(_DECODE_TWINS[family]).inc(
+                    int(of_chunks), model=self.name)
 
     def _decode_grouped(self):
         """One decode chunk across every active slot, in WEIGHT-POOL
@@ -5020,7 +5351,7 @@ class DecodeEngine:
             with self._phase("engine.decode.enqueue"):
                 out = fn(
                     self._wpool.tree(wid), self._cache, self._logbuf,
-                    np.ascontiguousarray(self._tables), self._pos,
+                    self._tables_arg(), self._pos,
                     self._loc, gmask, self._produced, self._rngs,
                     self._temp, self._topk, self._stop, self._max_new,
                     self._lora_tree(),
@@ -5068,6 +5399,10 @@ class DecodeEngine:
         self._tables[:, :] = -1
         self._slot_pages = [[] for _ in range(self.n_slots)]
         self._mgr = BlockManager(self.n_pages, self.page_size)
+        if self._wmgr is not None:
+            self._wmgr = BlockManager(self.window_pages, self.page_size)
+            self._wtables[:, :] = -1
+            self._wfirst[:] = 0
         if self._prefix is not None:
             self._prefix = PrefixCache(self._mgr)
         self._draft_tables[:, :] = -1

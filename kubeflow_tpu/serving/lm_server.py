@@ -502,6 +502,18 @@ class LMPredictor(Predictor):
             for k, v in self._engine.slot_state(slot).items()})
         return buf.getvalue()
 
+    def row_kv(self, slot: int, layer: int) -> bytes:
+        """The /debug/kv payload: the keys and values the live row in
+        ``slot`` holds in ``layer``'s pages (``DecodeEngine.row_kv``),
+        as an .npz. ValueError where there is no such row or layer."""
+        import io
+
+        if self._engine is None:
+            raise ValueError(f"model {self.name} is not loaded")
+        buf = io.BytesIO()
+        np.savez(buf, **self._engine.row_kv(slot, layer))
+        return buf.getvalue()
+
     def pooled_models(self) -> Dict[str, bool]:
         """{model name: resident-in-HBM?} over the weight pool's full
         source set (docs/serving.md "Weights as a fleet resource") —

@@ -682,20 +682,26 @@ class ModelServer:
                                        "or KFX_FLIGHT=0)"})
             else:
                 h._send(200, {"models": snaps})
-        elif path.startswith("/debug/state?"):
-            # What a slot holds in the leaves indexed by slot (a
-            # configuration with state-space layers), as an .npz:
-            # /debug/state?model=<name>&slot=<i>.
+        elif path.startswith(("/debug/state?", "/debug/kv?")):
+            # As an .npz: what a slot holds in the leaves indexed by
+            # slot (a configuration with state-space layers),
+            # /debug/state?model=<name>&slot=<i>; the keys and values
+            # the live row in a slot holds in one layer's pages,
+            # /debug/kv?model=<name>&slot=<i>&layer=<l>.
             from urllib.parse import parse_qs, urlsplit
 
             q = parse_qs(urlsplit(path).query)
             p = self.predictors.get((q.get("model") or [""])[0])
-            fn = getattr(p, "slot_state", None)
+            what, keys = ("slot_state", ("slot",)) \
+                if path.startswith("/debug/state?") \
+                else ("row_kv", ("slot", "layer"))
+            fn = getattr(p, what, None)
             try:
                 if fn is None:
-                    raise ValueError("no such model, or it holds no "
-                                     "slot state")
-                h._send_text(200, fn(int((q.get("slot") or ["-1"])[0])),
+                    raise ValueError("no such model, or it has none to "
+                                     "read")
+                h._send_text(200, fn(*(int((q.get(k) or ["-1"])[0])
+                                       for k in keys)),
                              "application/octet-stream")
             except ValueError as e:
                 h._send(404, {"error": str(e)})

@@ -451,9 +451,9 @@ def test_export_round_trips_the_new_keys(tmp_path, tiny):
 
 GLM_PARENT = {
     "decode_chunk":
-        "7207114ec17c4c79de7cc6c2bb83791f64bbeaab0f6b8fb30b0ebcec38d58974",
+        "7af0b9a8a02b18ff4b0bfe4246c6eaabe2edfe40b575d81d75ad9e215a0b2885",
     "prefill_16":
-        "f951ede5b3effdb551e864e522a81ceea63a9851061468916aeef7b6e5e68da0",
+        "5d3dcd8c6a2627940da2d5e6966baf94d8db2d6fc746b59b06327efe6725c1fa",
 }
 
 
@@ -463,8 +463,12 @@ def glm_programs():
     engine (2 slots, 30 pages of 8, chunked prefill 16): made on the
     parent commit (81be731) with this function; ``decode_chunk``'s was
     made again in PR 45, on 15b9f16 with that PR's sampler (one choice
-    of its form a step, and the counts of it), ``prefill_16``'s stands:
-    nothing else moved. The dense block's
+    of its form a step, and the counts of it). Both were made again in
+    PR 46, whose routed experts hand back a fifth count (the held
+    experts that got rows, models/experts.py COUNTS): with that one
+    count taken out again, PR 46's tree lowered both to the hashes
+    before it (7207114e..., f951ede5...), so nothing else of these
+    programs moved. The dense block's
     programs, serving and training, are held by
     tests/test_dense_program_guard.py."""
     from kubeflow_tpu.serving import engine as E

@@ -288,6 +288,82 @@ def test_latent_pool_is_written_in_place_at_the_cells_size(one_chip,
             < int(15.75 * 2 ** 30))
 
 
+@pytest.mark.parametrize("program", ["decode_chunk", "prefill_1024"])
+def test_two_page_classes_fit_the_chip_at_the_cells_size(one_chip,
+                                                         monkeypatch,
+                                                         program):
+    """The engine's programs for
+    ``benchmark/configs/smallthinker-21b-a3b.json`` as the cell serves
+    it (32 slots; the full layers' pool of 3072 pages of 64 and the
+    window layers' of 32 x 82; every width the published one, two
+    periods): they compile for the chip, fit it beside 7.9 GB of
+    weights, update both pools where they lie through the four scans of
+    the runs, and hand the routed experts' stacks (all eight layers')
+    to the grouped product whole. A window layer's gathered view is 65
+    blocks a decode step and 81 a prompt chunk of 1024, whatever
+    ``max_seq_len``."""
+    import re
+
+    from benchmark import kfx_adapter_smallthinker as A
+    from benchmark.manifest import BENCH_DIR, load_json
+    from kubeflow_tpu.models.transformer import (
+        TransformerConfig, TransformerLM, init_cache)
+    from kubeflow_tpu.serving.engine import DecodeEngine
+
+    published = load_json(os.path.join(BENCH_DIR, "configs",
+                                       "smallthinker-21b-a3b.json"))
+    serving = published["serving"]
+    L, P, N = (serving["max_seq_len"], serving["kv_page_size"],
+               serving["kv_pages"])
+    W = serving["slots"] * (4096 // P + 2 + serving["prefill_chunk"] // P)
+    cfg = TransformerConfig(**A.transformer_kwargs(
+        published, max_seq_len=L, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, decode=True, kv_page_size=P, kv_pages=N,
+        window_pages=W))
+    eng = object.__new__(DecodeEngine)
+    eng.cfg, eng.model, eng.name = cfg, TransformerLM(cfg), "aot"
+    eng.n_slots, eng.chunk_tokens, eng.n_blocks = serving["slots"], 8, L // P
+    eng._donate, eng._apool, eng._registry = True, None, None
+    tree, _ = A.host_views(published, jnp.bfloat16)   # shapes: never touched
+    eng.params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+    eng._cache = jax.eval_shape(lambda: init_cache(cfg))
+    jit = jax.jit
+
+    class ForTheChip:
+        def __init__(self, fn, **kw):
+            self.jitted = jit(fn, **kw)
+
+        def lower(self, *specs):
+            return self.jitted.lower(*jax.tree_util.tree_map(
+                lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                               sharding=one_chip), specs))
+
+    monkeypatch.setattr(jax, "jit", ForTheChip)
+    compiled = eng._build_decode() if program == "decode_chunk" \
+        else eng._build_prefill(1024)
+    monkeypatch.undo()
+    text = compiled.as_text()
+    assert f"jit_run_kfx_{program}" in text.splitlines()[0]
+    pools = "|".join(re.escape(f"bf16[{n},{pages},{P},512]")
+                     for n, pages in ((1, N), (3, W)))
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(rf"= ({pools})\S* (copy|copy-done)\(", line)]
+    assert not copies, copies
+    experts = r"bf16\[(8,64|512),(2560,1536|768,2560)\]"
+    assert not re.search(
+        rf"= {experts}\S* (fusion|copy|copy-done|dynamic-slice)\(", text)
+    assert "ragged-dot" in text
+    # the window layers' view: 65 (a step) or 81 (a chunk) blocks of 64
+    view = (1 + 4096 - 2) // P + 2 if program == "decode_chunk" \
+        else (1024 + 4096 - 2) // P + 2
+    assert re.search(rf"[\[,]({view * P}|{view},{P}),512[,\]]", text)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2 << 30
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < int(15.75 * 2 ** 30))
+
+
 @pytest.mark.parametrize("program", ["decode_chunk", "prefill_256"])
 def test_slot_state_is_written_in_place_at_the_cells_size(one_chip,
                                                           monkeypatch,
