@@ -19,10 +19,13 @@ writer never takes a lock on the hot path, and snapshot readers (the
 model server's HTTP threads, including the heartbeat path while the
 loop is wedged) copy with ``list(deque)`` which is safe against a
 concurrent append (worst case the copy misses/doubles one edge
-record). Crucially the loop appends its record at the END of an
-iteration — before ``_iterations`` advances — and the chaos wedge
-stalls mid-iteration, so a wedged engine's ring is frozen at the last
-completed iteration: exactly the forensic picture a postmortem wants.
+record). Crucially the loop appends an iteration's record only once
+the iteration is COMPLETE — at its end where no row is left decoding,
+else with the rest of what the iteration owes, behind the next chunk's
+enqueue (``DecodeEngine._pay_owed``) — and the chaos wedge stalls
+mid-iteration after paying what is owed, so a wedged engine's ring is
+frozen at the last completed iteration plus the wedge's own record of
+the one in flight: exactly the forensic picture a postmortem wants.
 
 Sizing: one record is a small dict (~10 keys, slot lists bounded by
 ``n_slots``); at the default 2048 records and 4 slots that is well

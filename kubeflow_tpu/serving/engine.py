@@ -202,8 +202,8 @@ import itertools
 import threading
 import time
 from collections import OrderedDict, defaultdict, deque
-from typing import (Any, Callable, Dict, List, Optional, Sequence, Tuple,
-                    Union)
+from typing import (Any, Callable, Deque, Dict, List, Optional, Sequence,
+                    Tuple, Union)
 
 import numpy as np
 
@@ -389,7 +389,8 @@ class Request:
         # BEFORE prefill — infeasible requests shed, never time out.
         self.deadline = deadline
         # Streaming sink: called with each generated token id on the
-        # LOOP thread as it lands, then with None at retirement.
+        # LOOP thread when its chunk is handed out (_pay_owed: behind
+        # the next chunk's enqueue), then with None at retirement.
         # Preemption-by-recompute never re-fires already-notified
         # tokens — ``tokens`` only grows (recompute re-prefills, it
         # does not re-emit), so a token streams exactly once.
@@ -789,6 +790,27 @@ _DECODE_TWINS = {
 }
 
 
+class _Handout:
+    """What one iteration of the loop owes the world outside it, in the
+    order it is paid (``DecodeEngine._pay_owed``): the counts the
+    programs made up to its dispatch (``chunks`` of them decode chunks
+    or verify windows), then for every row its new tokens to the
+    request's sink and, where the row is done, the request's finish,
+    then the token counter, the gauges and the iteration's flight
+    record (``iteration`` None: tokens handed out ahead of the
+    iteration's dispatch, which bring no record)."""
+
+    __slots__ = ("iteration", "chunks", "counts", "rows", "emitted")
+
+    def __init__(self, iteration: Optional[int], chunks: int = 0,
+                 counts: Sequence[Any] = ()):
+        self.iteration = iteration
+        self.chunks = chunks
+        self.counts = counts
+        self.rows: List[Tuple[Request, List[int], bool]] = []
+        self.emitted = 0
+
+
 class DecodeEngine:
     """Owns the paged KV pool, the block tables, the prefix cache, the
     compiled prefill/decode functions and the decode-loop thread. One
@@ -973,6 +995,13 @@ class DecodeEngine:
         # sampler counted, one tuple a dispatch, on the device until
         # flushed.
         self._counts_pending: List[Any] = []
+        # Hand-outs owed (_Handout, oldest first). A decode chunk's
+        # tokens land in their requests as soon as the host has them;
+        # what the chunk owes beyond that is paid once the NEXT chunk
+        # is enqueued, so that it runs while the device works. Nothing
+        # here ever waits on a parked loop, and no request in the
+        # queue is owed anything (_pay_owed's callers).
+        self._owed: Deque[_Handout] = deque()
         self.n_slots = n_slots
         self.chunk_tokens = chunk_tokens
         # Chunked prefill: admit prompt tails in page-multiple chunks,
@@ -1868,7 +1897,7 @@ class DecodeEngine:
         now = time.monotonic()
         with self._cond:
             busy = (self._active_count() > 0 or len(self._queue) > 0
-                    or self._admitting is not None)
+                    or self._admitting is not None or bool(self._owed))
         stalled_s = now - self._last_progress
         compiling = self._building > 0
         return {
@@ -1904,9 +1933,11 @@ class DecodeEngine:
                 # A preemption-by-recompute mid-drain re-queues its
                 # request, and a request mid-admission is in a slot in
                 # all but timing; both are in-flight work, not new
-                # admissions, so drain waits for them too.
+                # admissions, so drain waits for them too, and for the
+                # last chunk's tokens to be handed out (_owed).
                 empty = (self._active_count() == 0 and not self._queue
-                         and self._admitting is None)
+                         and self._admitting is None
+                         and not self._owed)
             if empty or time.monotonic() >= deadline:
                 return empty
             time.sleep(0.02)
@@ -1930,7 +1961,10 @@ class DecodeEngine:
         # append — record the in-flight iteration first so the ring's
         # last entry shows what was on the device when the loop hung
         # (the record a postmortem needs; its ``it`` matches the frozen
-        # heartbeat counter).
+        # heartbeat counter). What the last chunk owes goes out ahead
+        # of it: its tokens are not held by the stall, and its record
+        # precedes this one.
+        self._pay_owed(overlapped=False)
         if self.flight is not None:
             self._record_flight()
         stall = inj.delay if inj.delay > 0 else 30.0
@@ -3236,12 +3270,15 @@ class DecodeEngine:
         """Drain pending control jobs (loop thread, iteration start).
         Job exceptions are captured into the waiter's box by the job
         wrapper itself — a refused import must fail the TRANSFER, not
-        the engine."""
+        the engine. A job sees a quiesced boundary: what the last
+        chunk owes is paid first (an export finishes its request, and
+        the tokens come before the end marker)."""
         while True:
             with self._cond:
                 if not self._control:
                     return
                 job = self._control.popleft()
+            self._pay_owed(overlapped=False)
             job()
 
     def _gather_fn(self):
@@ -3920,7 +3957,7 @@ class DecodeEngine:
             with self._cond:
                 while (not self._stopped and not self._queue
                        and self._active_count() == 0
-                       and not self._control):
+                       and not self._control and not self._owed):
                     # A weight pool with an idle window must keep
                     # ticking while parked, or a fully-idle replica
                     # would never run the scale-to-zero sweep below.
@@ -3944,7 +3981,9 @@ class DecodeEngine:
         """One iteration of the loop, between two parks on the
         condition variable. The loop thread marks what it is doing
         phase by phase (``_phase``): control, admit, prefill.enqueue,
-        decode.enqueue, device_wait, deliver, bookkeeping."""
+        decode.enqueue, then deliver and bookkeeping for the chunk
+        BEFORE (``_pay_owed``: the hand-out runs while the device
+        works on the chunk just enqueued), then device_wait."""
         with self._phase("engine.control"):
             # KV-transfer control jobs first (export snapshots,
             # import installs): they are slot-state surgery and
@@ -3966,6 +4005,7 @@ class DecodeEngine:
         # decode chunk's engine.device_wait.
         self._iter_stall = 0.0
         had_active = bool(self._active.any())
+        dispatched = False
         with self._phase("engine.admit"):
             self._admit_ready()
         if self._active_count():
@@ -3997,10 +4037,16 @@ class DecodeEngine:
                 # handoff never decodes a token here.
                 self._handoff_ready()
             if bool(self._active.any()):
-                self._decode_once()
+                dispatched = self._decode_once()
+        if not dispatched:
+            # No dispatch's hand-out: the iteration owes its flight
+            # record and the gauges alone.
+            self._owed.append(_Handout(self._iterations))
+        if not self._active.any():
+            # No row is decoding, so no chunk will be enqueued behind
+            # what is owed (the loop may be about to park): paid now.
+            self._pay_owed(overlapped=False)
         with self._phase("engine.bookkeeping"):
-            if self.flight is not None:
-                self._record_flight()
             # The progress heartbeat: one completed iteration. A
             # loop stuck inside a dispatch (or the wedge stall
             # above) never reaches this line, so /healthz sees the
@@ -4085,12 +4131,15 @@ class DecodeEngine:
         self._wpool.evict_idle(self.model_idle_s,
                                keep=self.model_default)
 
-    def _record_flight(self) -> None:
-        """Append this iteration's flight record (loop thread, end of
-        iteration — so a wedge mid-iteration leaves the ring frozen at
-        the last COMPLETED tick, which is what a postmortem reads).
-        Queue depth is read without the lock: a one-record-stale depth
-        is fine for forensics and keeps the hot path lock-free."""
+    def _record_flight(self, iteration: Optional[int] = None) -> None:
+        """Append an iteration's flight record (loop thread, with the
+        rest of what the iteration owes: at its end, or behind the next
+        chunk's enqueue — either way a wedge mid-iteration leaves the
+        ring frozen at the last COMPLETED tick, which is what a
+        postmortem reads). The slots and the queue are read as they
+        are when it is written, the depth without the lock: a
+        one-record-stale picture is fine for forensics and keeps the
+        hot path lock-free."""
         active, prefilling = [], []
         for slot, r in enumerate(self._slots):
             if r is None:
@@ -4100,7 +4149,8 @@ class DecodeEngine:
             elif self._active[slot]:
                 active.append((slot, r.rid))
         self.flight.record_iteration(
-            iteration=self._iterations,
+            iteration=(self._iterations if iteration is None
+                       else iteration),
             active=active, prefilling=prefilling,
             pages_free=self._mgr.n_free,
             draft_pages_free=(self._draft_mgr.n_free
@@ -4164,8 +4214,6 @@ class DecodeEngine:
                 self._admitting = None
             if requeued:
                 break
-        with self._phase("engine.bookkeeping"):
-            self._touch_gauges()
 
     def _resolve_adapter(self, req: Request) -> int:
         """The request's adapter id for this admission: acquire (and
@@ -4927,6 +4975,10 @@ class DecodeEngine:
                                   self._slots[s].t_enqueue))
 
     def _preempt(self, slot: int) -> None:
+        # Paid before the row goes back to the queue: any thread may
+        # finish a queued request (drain, close, a batch request shed
+        # at submit), and the tokens it is owed come before that.
+        self._pay_owed(overlapped=False)
         req = self._slots[slot]
         if self._active[slot]:
             # Stash the live RNG stream so re-admission resumes it
@@ -5020,41 +5072,102 @@ class DecodeEngine:
         tok = int(np.searchsorted(np.cumsum(probs), u))
         return min(tok, logits.shape[-1] - 1), np.asarray(nxt, np.uint32)
 
-    def _emit_host(self, slot: int, toks: List[int]) -> int:
-        """Append emitted tokens to the slot's request, honoring the
+    def _land(self, h: _Handout, slot: int, fresh: List[int],
+              done: bool) -> None:
+        """What the next enqueue needs of a row's new tokens, at once:
+        they join the request (a preempted row re-prefills from them),
+        the first one stamps ``t_first`` where it lands, and a row that
+        is done gives up its slot and pages for the next admission.
+        What the world outside is owed for them, the sink's tokens and
+        the request's finish, goes on the hand-out ``h``."""
+        req = self._slots[slot]
+        if fresh:
+            req.tokens.extend(fresh)
+            h.emitted += len(fresh)
+            if req.t_first == 0.0:
+                req.t_first = time.monotonic()
+                if self.flight is not None:
+                    self.flight.event(req, "first_token")
+        if done:
+            self._slots[slot] = None
+            self._release_slot(slot)
+        if done or (fresh and req.on_token is not None):
+            h.rows.append((req, fresh, done))
+
+    def _pay_owed(self, overlapped: bool) -> None:
+        """THE hand-out routine (loop thread): pay, oldest first, what
+        the iterations owe (_Handout). Called right after a decode
+        chunk's enqueue (``overlapped``: the device works on that chunk
+        meanwhile), and with nothing enqueued wherever waiting would
+        let something overtake the tokens or leave them on a parked
+        loop: at the end of an iteration that leaves no row decoding,
+        ahead of a control job, a preemption, a wedge and
+        ``_fail_inflight``. A request's tokens reach its sink in order
+        and before its end marker on every path."""
+        reg = self._reg()
+        while self._owed:
+            h = self._owed.popleft()
+            try:
+                if h.chunks:
+                    with self._phase("engine.bookkeeping"):
+                        # Before the tokens go out: a client that holds
+                        # its last token finds the layers' counts of it
+                        # in /metrics, and they grow with the chunk
+                        # counter, not a delivery later.
+                        self._flush_counts(h.counts, h.chunks)
+            finally:
+                # Whatever the registry does, a request that left its
+                # slot is finished here or nowhere.
+                with self._phase("engine.deliver"):
+                    for req, fresh, done in h.rows:
+                        for t in fresh:
+                            req._notify(t)
+                        if done:
+                            req._finish()
+            with self._phase("engine.bookkeeping"):
+                if h.emitted:
+                    reg.counter("kfx_lm_generated_tokens_total",
+                                "Tokens generated since startup.").inc(
+                                    h.emitted, model=self.name)
+                if h.chunks:
+                    reg.counter(
+                        "kfx_lm_engine_handouts_total",
+                        "Decode-chunk / verify hand-outs (tokens to the "
+                        "sinks, finishes, counts, gauges), by whether "
+                        "the next chunk was enqueued first (overlapped="
+                        "\"1\": the hand-out ran while the device "
+                        "worked) or nothing was (\"0\").").inc(
+                            h.chunks, model=self.name,
+                            overlapped="1" if overlapped else "0")
+                self._touch_gauges()
+                if self.flight is not None and h.iteration is not None:
+                    self._record_flight(h.iteration)
+
+    def _emit_host(self, h: _Handout, slot: int, toks: List[int]) -> None:
+        """Land emitted tokens in the slot's request, honoring the
         stop-token and max_new contracts exactly as the chunked path
         does (the stop token itself is never emitted; the slot retires
-        at the first hit or when the budget fills). Returns how many
-        tokens actually landed in the KV-valid prefix (the cursor
-        advance); retires the slot itself when done."""
+        at the first hit or when the budget fills); retires the slot
+        itself when done."""
         req = self._slots[slot]
-        landed = 0
+        fresh: List[int] = []
         done = False
         for t in toks:
             if req.stop >= 0 and t == req.stop:
                 done = True
                 break
-            req.tokens.append(int(t))
-            req._notify(int(t))
-            landed += 1
-            if len(req.tokens) >= req.max_new:
+            fresh.append(int(t))
+            if len(req.tokens) + len(fresh) >= req.max_new:
                 done = True
                 break
-        if landed and req.t_first == 0.0:
-            req.t_first = time.monotonic()
-            if self.flight is not None:
-                self.flight.event(req, "first_token")
-        if done:
-            self._slots[slot] = None
-            self._release_slot(slot)
-            req._finish()
-        return landed
+        self._land(h, slot, fresh, done)
 
-    def _spec_once(self) -> None:
+    def _spec_once(self) -> bool:
         """One speculative iteration: host-sample pending tokens for
         fresh admissions, budget the window's pages, dispatch the
         fused propose+verify+accept step, then apply the accept
-        verdicts to the per-slot bookkeeping."""
+        verdicts to the per-slot bookkeeping. False where no window
+        was dispatched."""
         import jax
 
         # Fresh admissions (and requeued preempts) have no pending
@@ -5067,23 +5180,21 @@ class DecodeEngine:
         if fresh:
             with self._phase("engine.device_wait"):
                 logbuf = np.asarray(self._logbuf)  # waits on the prefill
-            emitted0 = 0
-            with self._phase("engine.deliver"):
-                for s in fresh:
-                    req = self._slots[s]
-                    tok, self._rngs[s] = self._sample_host(
-                        logbuf[s], req, self._rngs[s])
-                    emitted0 += self._emit_host(s, [tok])
-                    if self._slots[s] is not None:
-                        self._pending[s] = tok
-            if emitted0:
-                self._reg().counter(
-                    "kfx_lm_generated_tokens_total",
-                    "Tokens generated since startup.").inc(
-                        emitted0, model=self.name)
+            # Speculation pays at once, here and below: the verify
+            # window's verdicts are read on the host before the next
+            # window can be enqueued, so there is nothing to run behind.
+            first = _Handout(None)
+            self._owed.append(first)
+            for s in fresh:
+                req = self._slots[s]
+                tok, self._rngs[s] = self._sample_host(
+                    logbuf[s], req, self._rngs[s])
+                self._emit_host(first, s, [tok])
+                if self._slots[s] is not None:
+                    self._pending[s] = tok
+            self._pay_owed(overlapped=False)
         if not self._active_count():
-            self._touch_gauges()
-            return
+            return False
         # Chaos: a full-rejection wave — every slot verifies as if its
         # draft proposed garbage. Throughput falls to the
         # non-speculative floor; outputs stay exact (the bonus token
@@ -5097,8 +5208,7 @@ class DecodeEngine:
                 wave_off = True
         self._ensure_spec_pages()
         if not self._active_count():
-            self._touch_gauges()
-            return
+            return False
         self._maybe_kv_quant_chaos()
         k = self.propose_tokens
         draft_live = self._spec_ok & self._active
@@ -5132,35 +5242,30 @@ class DecodeEngine:
         reg = self._reg()
         # The verify window IS spec mode's decode-chunk dispatch: one
         # family for "hot decode dispatches" in both engine modes.
-        reg.counter("kfx_lm_engine_chunks_total",
-                    "Decode-chunk / verify dispatches.").inc(
-                        1, model=self.name)
+        h = self._take_handout()
         proposed = int(np.sum(spec_on))
         accepted = 0
-        emitted = 0
-        with self._phase("engine.deliver"):
-            for slot in range(self.n_slots):
-                req = self._slots[slot]
-                if req is None or not self._active[slot]:
-                    continue
-                a = int(A[slot])
-                if spec_on[slot]:
-                    accepted += a
-                    # Per-request speculation attribution (spec_accept
-                    # in the flight-recorder breakdown).
-                    req.spec_prop += k
-                    req.spec_acc += a
-                toks = [int(t) for t in D[slot, :a]] \
-                    + [int(bonus[slot])]
-                landed = self._emit_host(slot, toks)
-                emitted += landed
-                if self._slots[slot] is not None:
-                    # Cursor advance = pending + accepted proposals now
-                    # in both pools; the bonus becomes the new pending
-                    # token.
-                    self._pos[slot] += a + 1
-                    self._loc[slot] += a + 1
-                    self._pending[slot] = int(bonus[slot])
+        for slot in range(self.n_slots):
+            req = self._slots[slot]
+            if req is None or not self._active[slot]:
+                continue
+            a = int(A[slot])
+            if spec_on[slot]:
+                accepted += a
+                # Per-request speculation attribution (spec_accept
+                # in the flight-recorder breakdown).
+                req.spec_prop += k
+                req.spec_acc += a
+            toks = [int(t) for t in D[slot, :a]] \
+                + [int(bonus[slot])]
+            self._emit_host(h, slot, toks)
+            if self._slots[slot] is not None:
+                # Cursor advance = pending + accepted proposals now
+                # in both pools; the bonus becomes the new pending
+                # token.
+                self._pos[slot] += a + 1
+                self._loc[slot] += a + 1
+                self._pending[slot] = int(bonus[slot])
         with self._phase("engine.bookkeeping"):
             if proposed:
                 self._spec_proposed += proposed * k
@@ -5176,18 +5281,28 @@ class DecodeEngine:
                     "kfx_lm_spec_accepted_total",
                     "Draft proposals the target model accepted."
                     ).inc(accepted, model=self.name)
-            if emitted:
-                reg.counter("kfx_lm_generated_tokens_total",
-                            "Tokens generated since startup.").inc(
-                                emitted, model=self.name)
-            self._touch_gauges()
+        self._pay_owed(overlapped=False)
+        return True
 
-    def _decode_once(self) -> None:
+    def _take_handout(self) -> _Handout:
+        """The hand-out of the dispatch whose outputs the host has just
+        read, owed from here on: it takes what the programs counted up
+        to that dispatch, which has all run (the counts of a dispatch
+        enqueued later would make the flush wait for it)."""
+        h = _Handout(self._iterations, 1, self._counts_pending)
+        self._counts_pending = []
+        self._owed.append(h)
+        return h
+
+    def _decode_once(self) -> bool:
+        """One decode dispatch (a chunk, or with a draft a verify
+        window) for every active row; False where none was made. What
+        the chunk BEFORE owes is paid right behind this one's enqueue."""
         if self.spec:
             return self._spec_once()
         self._ensure_chunk_pages()
         if not self._active_count():
-            return  # every slot preempted away
+            return False  # every slot preempted away
         self._maybe_kv_quant_chaos()
         oldest = min((r for r in self._slots if r is not None),
                      key=lambda r: r.t_enqueue)
@@ -5207,6 +5322,7 @@ class DecodeEngine:
                         np.ascontiguousarray(self._aids))
                 (self._cache, self._logbuf, pos, loc, active,
                  produced, rngs, toks, emits) = self._keep_counts(out, 9)
+                self._pay_owed(overlapped=True)
                 # The first host read blocks until the chunk (and any
                 # prefill enqueued before it) has run on the device.
                 with self._phase("engine.device_wait"):
@@ -5226,44 +5342,18 @@ class DecodeEngine:
                             int(slot), int(self._pos[slot]))
             else:
                 toks, emits = self._decode_grouped()
-        reg = self._reg()
-        reg.counter("kfx_lm_engine_chunks_total",
-                    "Decode-chunk dispatches.").inc(1, model=self.name)
-        with self._phase("engine.bookkeeping"):
-            # Before the tokens go out: a client that holds its last
-            # token finds the layers' counts of it in /metrics, and
-            # they grow with the chunk counter, not a delivery later.
-            self._flush_counts()
-        emitted = 0
-        with self._phase("engine.deliver"):
-            for slot, req in enumerate(self._slots):
-                if req is None or slot in self._prefilling:
-                    # A mid-prefill slot rides the dispatch fully
-                    # masked: inactive by design, not retired —
-                    # finishing it here would return an empty
-                    # completion.
-                    continue
-                hits = np.flatnonzero(emits[:, slot])
-                fresh = [int(t) for t in toks[hits, slot]]
-                req.tokens.extend(fresh)
-                if req.on_token is not None:
-                    for t in fresh:
-                        req._notify(t)
-                emitted += len(hits)
-                if len(hits) and req.t_first == 0.0:
-                    req.t_first = time.monotonic()
-                    if self.flight is not None:
-                        self.flight.event(req, "first_token")
-                if not self._active[slot]:
-                    self._slots[slot] = None
-                    self._release_slot(slot)
-                    req._finish()
-        with self._phase("engine.bookkeeping"):
-            if emitted:
-                reg.counter("kfx_lm_generated_tokens_total",
-                            "Tokens generated since startup.").inc(
-                                emitted, model=self.name)
-            self._touch_gauges()
+        h = self._take_handout()
+        for slot, req in enumerate(self._slots):
+            if req is None or slot in self._prefilling:
+                # A mid-prefill slot rides the dispatch fully
+                # masked: inactive by design, not retired —
+                # finishing it here would return an empty
+                # completion.
+                continue
+            hits = np.flatnonzero(emits[:, slot])
+            self._land(h, slot, toks[hits, slot].tolist(),
+                       not self._active[slot])
+        return True
 
     @property
     def _counted(self) -> Tuple[str, ...]:
@@ -5289,11 +5379,18 @@ class DecodeEngine:
             self._counts_pending.append(out[n:])
         return out[:n]
 
-    def _flush_counts(self) -> None:
-        """Into the registry, what the programs counted since the last
-        flush. Called once the decode chunk's outputs are on the host,
-        so every program enqueued before it has run and no read here
-        waits."""
+    def _flush_counts(self, pending: Sequence[Any], chunks: int) -> None:
+        """Into the registry, what the programs counted up to a decode
+        dispatch (``pending``, _take_handout's) and the dispatch itself
+        (``chunks``): counted together, so that a reader who holds the
+        layers' counts against the chunk counter between two scrapes
+        never sees a chunk on one side only. Called once that
+        dispatch's outputs are on the host, so every program in
+        ``pending`` has run and no read here waits."""
+        reg = self._reg()
+        reg.counter("kfx_lm_engine_chunks_total",
+                    "Decode-chunk / verify dispatches.").inc(
+                        chunks, model=self.name)
         sums = {"sparse": np.zeros(2, np.int64),
                 "moe": np.zeros(5, np.int64),
                 "ssm": np.zeros(3, np.int64),
@@ -5302,7 +5399,7 @@ class DecodeEngine:
         decode = {what: np.zeros_like(c) for what, c in sums.items()}
         # A prefill hands back its layers' counts, a decode chunk the
         # sampler's after them.
-        for counts in self._counts_pending:
+        for counts in pending:
             a_chunk = len(counts) > len(self._counted)
             for what, c in zip(self._counted + ("sample",), counts):
                 c = np.asarray(c, np.int64)
@@ -5310,8 +5407,6 @@ class DecodeEngine:
                 sums[what] += c
                 if a_chunk:
                     decode[what] += c
-        self._counts_pending.clear()
-        reg = self._reg()
         flat = lambda d: [v for c in d.values() for v in c]
         for (family, text), v, of_chunks in zip(
                 _COUNT_FAMILIES.items(), flat(sums), flat(decode)):
@@ -5358,6 +5453,7 @@ class DecodeEngine:
                     np.ascontiguousarray(self._aids))
             (self._cache, self._logbuf, pos, loc, active, produced,
              rngs, toks, emits) = self._keep_counts(out, 9)
+            self._pay_owed(overlapped=True)  # behind the first group's
             with self._phase("engine.device_wait"):
                 toks = np.asarray(toks)
                 emits = np.asarray(emits)
@@ -5377,6 +5473,10 @@ class DecodeEngine:
         return toks_all, emits_all
 
     def _fail_inflight(self, e: BaseException) -> None:
+        # Tokens that landed before the failure go out before it does;
+        # what a dispatch that died had counted is lost with it.
+        self._counts_pending = []
+        self._pay_owed(overlapped=False)
         for slot, req in enumerate(self._slots):
             if req is not None:
                 self._slots[slot] = None

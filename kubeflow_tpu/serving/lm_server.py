@@ -564,8 +564,12 @@ class LMPredictor(Predictor):
         if key:
             with self._resume_lock:
                 self._prune_resume_locked()
+                # What travelled with the pages (``meter_skip``), not
+                # ``len(req.tokens)``: the loop is already decoding, and
+                # a token it has landed reaches ``q`` when its chunk is
+                # handed out — counted here too it would stream twice.
                 self._resume[key] = {"req": req, "q": q,
-                                     "imported": len(req.tokens),
+                                     "imported": req.meter_skip,
                                      "t": time.monotonic()}
         self.metrics.counter(
             "kfx_lm_kv_migrations_total",

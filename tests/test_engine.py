@@ -1922,3 +1922,281 @@ class TestRequestPlaneServing:
             assert ei.value.code == 400
         finally:
             srv.stop()
+
+
+# -- the hand-out: what a chunk owes is paid behind the next enqueue ----------
+
+def _build_handout_engine(kind, tiny_lm, **kw):
+    """An engine of one of the three kinds of configuration the loop
+    serves: the dense block, recurrent state a slot beside pages
+    (benchmark/tests/tiny_granite.py), a second page class behind a
+    window (benchmark/tests/tiny_smallthinker.py)."""
+    from kubeflow_tpu.serving.engine import DecodeEngine
+
+    if kind == "dense":
+        cfg, params = tiny_lm
+        kw.setdefault("kv_page_size", 16)
+    elif kind == "slot-state":
+        from benchmark.tests import tiny_granite
+        cfg, params = tiny_granite.program(tiny_granite.config(), 5)
+        kw.setdefault("kv_page_size", 8)
+    else:
+        from benchmark.tests import tiny_smallthinker
+        cfg, params = tiny_smallthinker.program(
+            tiny_smallthinker.config(), 5)
+        kw.setdefault("kv_page_size", 8)
+        kw.setdefault("prefill_chunk_tokens", 16)
+    kw.setdefault("n_slots", 3)
+    return DecodeEngine(cfg, params, chunk_tokens=4,
+                        name=f"ho-{kind}", **kw)
+
+
+@pytest.fixture(scope="module", params=["dense", "slot-state", "window"])
+def handout_engine(request, tiny_lm):
+    eng = _build_handout_engine(request.param, tiny_lm)
+    yield eng
+    eng.close()
+
+
+def _handouts(eng):
+    reg = eng._reg()
+    paid = reg.counter("kfx_lm_engine_handouts_total")
+    return {"1": paid.value(model=eng.name, overlapped="1"),
+            "0": paid.value(model=eng.name, overlapped="0"),
+            "chunks": reg.counter("kfx_lm_engine_chunks_total").value(
+                model=eng.name)}
+
+
+def _streamed(sink, req):
+    """The sink saw the request's tokens in order, every one the
+    engine landed, then the end marker once and last."""
+    assert sink.count(None) == 1 and sink[-1] is None
+    assert sink[:-1] == req.tokens
+
+
+class _Gate:
+    """Holds the loop thread where it budgets a chunk's pages: between
+    two chunks, with what the last one owes still owed."""
+
+    def __init__(self, eng, monkeypatch):
+        self.at_gate = threading.Event()
+        self.open = threading.Event()
+        self.armed = False
+        real = eng._ensure_chunk_pages
+
+        def held():
+            if self.armed:
+                self.at_gate.set()
+                assert self.open.wait(30)
+            return real()
+
+        monkeypatch.setattr(eng, "_ensure_chunk_pages", held)
+
+
+class TestHandout:
+    """A decode chunk's tokens join their requests at once; the sinks'
+    tokens, the finishes, the counts and the gauges are paid behind the
+    NEXT chunk's enqueue (serving/engine.py ``_pay_owed``), or at once
+    where nothing will be enqueued."""
+
+    PROMPTS = [[5, 9, 11, 3, 7], [2], [1, 2, 3, 4, 5, 6, 7, 8, 9],
+               [13, 14], [21, 3, 8]]
+    # Whole chunks of 4, parts of one, under one.
+    BUDGETS = [12, 3, 9, 16, 6]
+
+    def test_sinks_see_tokens_in_order_then_one_end_marker(
+            self, handout_engine):
+        """...and the ids are those of a run with no sink, to the last
+        token: five requests over three slots, so slots are taken
+        again while others decode."""
+        eng = handout_engine
+        plain = [eng.submit(p, max_new_tokens=n)
+                 for p, n in zip(self.PROMPTS, self.BUDGETS)]
+        want = [r.result(120) for r in plain]
+        assert [len(w) for w in want] == self.BUDGETS
+        sinks = [[] for _ in self.PROMPTS]
+        reqs = [eng.submit(p, max_new_tokens=n, on_token=s.append)
+                for p, n, s in zip(self.PROMPTS, self.BUDGETS, sinks)]
+        assert [r.result(120) for r in reqs] == want
+        for sink, req in zip(sinks, reqs):
+            _streamed(sink, req)
+        assert not eng._owed
+
+    def test_a_lone_requests_last_tokens_need_no_further_submit(
+            self, handout_engine):
+        """The loop parks with nothing owed; and the counter's two
+        labels: the chunks in the middle were handed out with the next
+        one enqueued, the last with nothing behind it."""
+        eng = handout_engine
+        before = _handouts(eng)
+        sink, ended = [], threading.Event()
+
+        def on_token(t):
+            sink.append(t)
+            if t is None:
+                ended.set()
+
+        req = eng.submit([3, 1, 4, 1, 5], max_new_tokens=16,
+                         on_token=on_token)
+        assert ended.wait(60), "the last chunk's tokens stayed owed"
+        assert req.done() and len(req.tokens) == 16
+        _streamed(sink, req)
+        after = _handouts(eng)
+        chunks = after["chunks"] - before["chunks"]
+        assert chunks >= 4
+        assert after["1"] - before["1"] >= chunks - 2
+        assert after["0"] - before["0"] >= 1
+        assert (after["1"] - before["1"]) + (after["0"] - before["0"]) \
+            == chunks
+        # Parked, and nothing waits on it.
+        deadline = time.monotonic() + 10
+        while eng._owed and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert not eng._owed
+        hb = eng.heartbeat()
+        assert hb["busy"] is False and hb["wedged"] is False
+
+    def test_tokens_come_before_the_failure_that_ends_the_stream(
+            self, handout_engine, monkeypatch):
+        """``_fail_inflight``: the third chunk's enqueue fails with the
+        second chunk's tokens owed. They go out, then the error."""
+        eng = handout_engine
+        real = eng._decode
+        calls = {"n": 0}
+
+        def dies_on_third():
+            calls["n"] += 1
+            if calls["n"] == 3:
+                raise ValueError("dispatch died")
+            return real()
+
+        monkeypatch.setattr(eng, "_decode", dies_on_third)
+        sink = []
+        req = eng.submit([7, 8, 9], max_new_tokens=24,
+                         on_token=sink.append)
+        with pytest.raises(ValueError, match="dispatch died"):
+            req.result(60)
+        assert len(req.tokens) == 8
+        _streamed(sink, req)
+        assert not eng._owed
+        monkeypatch.undo()
+        # The loop is intact and the next request serves normally.
+        assert len(eng.generate([[5, 9, 11]], max_new_tokens=4)[0]) == 4
+
+    def test_a_preempted_rows_tokens_go_out_once_and_in_order(
+            self, handout_engine, tiny_lm, request):
+        """A pool too small for its rows: the youngest is preempted
+        with tokens owed, requeued and recomputed; every sink sees
+        each token once, and the ids are a roomy engine's."""
+        kind = request.node.callspec.params["handout_engine"]
+        # 43 tokens a row are 3 pages of 16 or 6 of 8; a pool may be no
+        # smaller than one row of max_seq_len.
+        rows, pool = {"dense": (4, dict(kv_pages=6, prefix_cache=False)),
+                      "slot-state": (4, dict(kv_pages=16)),
+                      "window": (8, dict(kv_pages=32))}[kind]
+        prompts = [[i + 1, i + 2, i + 3] for i in range(rows)]
+        want = handout_engine.generate(prompts, max_new_tokens=40)
+        eng = _build_handout_engine(kind, tiny_lm, n_slots=rows, **pool)
+        try:
+            sinks = [[] for _ in prompts]
+            reqs = [eng.submit(p, max_new_tokens=40, on_token=s.append)
+                    for p, s in zip(prompts, sinks)]
+            assert [r.result(120) for r in reqs] == want
+            for sink, req in zip(sinks, reqs):
+                _streamed(sink, req)
+            assert eng._reg().counter(
+                "kfx_lm_kv_preemptions_total").value(
+                    model=eng.name) >= 1
+        finally:
+            eng.close()
+
+    def test_close_returns_with_handouts_owed(self, handout_engine,
+                                              tiny_lm, request,
+                                              monkeypatch):
+        """The loop is stopped between two chunks with the last one's
+        hand-out owed: ``close()`` returns inside its join timeout, and
+        each stream gets the tokens it was owed before the end marker
+        that closes it."""
+        kind = request.node.callspec.params["handout_engine"]
+        eng = _build_handout_engine(kind, tiny_lm)
+        gate = _Gate(eng, monkeypatch)
+        try:
+            sinks = [[] for _ in range(3)]
+            reqs = [eng.submit([i + 1, i + 2], max_new_tokens=40,
+                               on_token=s.append)
+                    for i, s in enumerate(sinks)]
+            deadline = time.monotonic() + 60
+            while not all(len(s) >= 4 for s in sinks) \
+                    and time.monotonic() < deadline:
+                time.sleep(0.002)
+            gate.armed = True
+            assert gate.at_gate.wait(30)
+            assert eng._owed, "rows decoding and nothing owed"
+            closer = threading.Thread(target=eng.close)
+            t0 = time.monotonic()
+            closer.start()
+            while not eng._stopped:
+                time.sleep(0.001)
+            gate.open.set()
+            closer.join(30)
+            assert not closer.is_alive()
+            assert time.monotonic() - t0 < 10.0
+            assert not eng._owed
+            for sink, req in zip(sinks, reqs):
+                assert isinstance(req.error, RuntimeError)
+                assert 4 <= len(req.tokens) < 40
+                _streamed(sink, req)
+        finally:
+            gate.open.set()
+            eng.close()
+
+    def test_migration_sends_the_owed_tokens_before_it_ends_the_stream(
+            self, engine, monkeypatch):
+        """An export is a control job, and sees a quiesced boundary:
+        what the payload says the request had generated, its sink has
+        seen; then the local copy ends, once."""
+        from kubeflow_tpu.serving import kvtransfer
+        from kubeflow_tpu.serving.engine import RequestMigrated
+
+        gate = _Gate(engine, monkeypatch)
+        sink, exported = [], []
+        real = engine._export_slot
+
+        def export(slot):      # on the loop thread, inside the job
+            out = real(slot)
+            exported.append((kvtransfer.peek(out[1])["req"]["tokens"],
+                             list(sink)))
+            return out
+
+        monkeypatch.setattr(engine, "_export_slot", export)
+
+        req = engine.submit([4, 5, 6], max_new_tokens=40,
+                            on_token=sink.append)
+        try:
+            deadline = time.monotonic() + 60
+            while len(sink) < 4 and time.monotonic() < deadline:
+                time.sleep(0.002)
+            gate.armed = True
+            assert gate.at_gate.wait(30)
+            assert engine._owed
+            moved = []
+            mover = threading.Thread(
+                target=lambda: moved.append(engine.migrate_out(
+                    send=lambda payload: "peer-0", rids=[req.rid])))
+            mover.start()
+            while not engine._control and mover.is_alive():
+                time.sleep(0.001)
+            gate.armed = False
+            gate.open.set()
+            mover.join(60)
+            with pytest.raises(RequestMigrated):
+                req.result(60)
+        finally:
+            gate.armed = False
+            gate.open.set()
+        (travelled, seen), = exported
+        assert len(travelled) >= 8 and seen == travelled
+        assert moved[0]["moved"] == 1
+        _streamed(sink, req)
+        monkeypatch.undo()
+        assert len(engine.generate([[5, 9, 11]], max_new_tokens=4)[0]) == 4

@@ -125,6 +125,27 @@ def test_the_loop_thread_shows_iterations_with_their_phases(traced):
             assert not {e[0] for e in ev} & set(PHASES), line
 
 
+def test_a_chunks_hand_out_lies_between_the_next_enqueue_and_its_wait(
+        traced):
+    """engine.deliver of chunk n runs behind chunk n + 1's enqueue and
+    before the loop blocks on that chunk: the trace has iterations
+    with the three phases in that order."""
+    events = next(ev for ev in traced["lines"].values()
+                  if any(e[0] == "engine.iteration" for e in ev))
+    inside = lambda it, name: sorted(
+        (s, e) for n, s, e, _ in events
+        if n == name and it[1] - 1e3 <= s and e <= it[2] + 1e3)
+    overlapped = 0
+    for it in (e for e in events if e[0] == "engine.iteration"):
+        enq, out, wait = (inside(it, "engine." + n) for n in (
+            "decode.enqueue", "deliver", "device_wait"))
+        if not (enq and out and wait):
+            continue
+        if enq[0][1] <= out[0][0] and out[0][1] <= wait[-1][0]:
+            overlapped += 1
+    assert overlapped >= 2
+
+
 @pytest.mark.parametrize("program", ["kfx_decode_chunk", "kfx_prefill_8",
                                      "kfx_prefill_16"])
 def test_programs_carry_kfx_names_in_the_trace(traced, program):
